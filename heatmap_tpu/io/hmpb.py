@@ -136,7 +136,7 @@ class HMPBSource:
     #: views materialize (~28 B: f64 coords + i32 group + i64 stamp).
     #: Consulted by pipeline._auto_points_in_flight(fast=True) so a
     #: big HMPB file that fits single-shot is not demoted to the
-    #: chunked path by the 160 B string-ingest constant (ADVICE r3).
+    #: chunked path by the 160 B string-ingest constant.
     fast_host_bytes_per_point = 30
 
     def __init__(self, path: str):

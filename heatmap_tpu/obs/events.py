@@ -48,6 +48,11 @@ EVENT_SCHEMA = {
                          "optional": ("jit", "mesh", "merge", "n_emissions",
                                       "n_slots", "trace_id", "span_id",
                                       "partition", "dispatch")},
+    # The partitioned segment kernel (ops/sparse_partitioned.py), once
+    # per trace: the Pallas mode it is lowered in (``interpret`` false:
+    # the compiled Mosaic kernel) and its input length.
+    "pallas_kernel": {"required": ("kernel", "interpret"),
+                      "optional": ("n",)},
     # Morton-range partition plan for a cascade dispatch
     # (parallel/partition.plan_partition): the split codes, the sampled
     # evidence they were chosen from, and the post-resplit balance.

@@ -20,8 +20,7 @@ contributes nothing — branch-free masking.
 Cost: N*H*W MACs per N points — ideal for the blob-sized windows the
 pipeline actually uses (a 32x32 or 256x256 coarse-tile raster,
 reference heatmap.py:16,89 fan-in), where the MXU turns the whole
-histogram into a handful of matmul passes; measured 2.6-2.9x faster
-than XLA scatter on v5e (PERF_NOTES.md). For very large windows the
+histogram into a handful of matmul passes. For very large windows the
 one-hot cost grows past the scatter path; ops.histogram stays the
 default and callers opt in by calling ``bin_points_window_pallas`` /
 ``bin_rowcol_window_pallas`` directly.

@@ -2,9 +2,8 @@
 
 The whole-window Pallas histogram (pallas_kernels.py) pays H*W MACs per
 point, so it only wins for blob-sized windows; the XLA scatter path
-pays a flat ~10-30 ns *per update* on v5e (PERF_NOTES.md), which is the
-headline-bench bottleneck for big rasters (a z15 metro window is ~1.3M
-cells). This module restores MXU locality for big windows:
+pays a flat cost *per update*, which is the bottleneck for big rasters
+(a z15 metro window is ~1.3M cells). This module restores MXU locality for big windows:
 
 1. project to linear cell indices and **sort** (XLA's comparison sort
    is the one fast reshuffling primitive on this chip);
@@ -32,8 +31,8 @@ the requested dtype.
 
 Weighted binning (BASELINE.md config 3) rides the same machinery: the
 sort carries the weight as a ``lax.sort`` payload operand (XLA's sort
-permutes payloads in-pass — no separate gather, which costs as much as
-the scatter being avoided, PERF_NOTES.md), and the per-chunk matmul
+permutes payloads in-pass — no separate gather, which would cost as
+much as the scatter being avoided), and the per-chunk matmul
 scales the column one-hot by the weight, so each good chunk is
 ``row_onehot @ (col_onehot * w)``. Weighted sums accumulate in f32:
 bit-exact vs the scatter path for integer-valued weights with per-cell
@@ -53,32 +52,43 @@ from jax.experimental import pallas as pl
 from heatmap_tpu.ops.histogram import IMAP_ZERO, Window
 
 DEFAULT_CHUNK = 1024
-#: Independently sorted rows per call (1 = one flat sort). 8 is the
-#: measured on-chip default (sweep 2026-07-31, v5e-1, 33.5M points,
-#: headline window): streams=8/32 run the full binning in ~197 ms vs
-#: ~403 ms for the flat sort — 2.0x — and are bit-exact in all verify
-#: cases. The isolated sort-shape probe shows the row sort itself is
-#: only ~8% faster, so most of the win is the per-stream slab
-#: accumulation pipelining the pallas grid better than one giant
-#: visit-run sequence. streams=8 over 32: same speed, fewer slabs
-#: (less zero-padding and a smaller output-blocks buffer).
+#: Independently sorted rows per call (1 = one flat sort). 8 came from
+#: an earlier on-chip sweep (not re-measured on today's code): the
+#: per-stream slab accumulation pipelines the pallas grid better than
+#: one giant visit-run sequence, and 8 keeps fewer slabs than 32 (less
+#: zero-padding and a smaller output-blocks buffer).
 DEFAULT_STREAMS = 8
 
 #: Cap on the summed per-stream output-slab footprint (bytes). Each
 #: stream accumulates its own (n_blocks * block_cells) f32 slab, so
 #: streams multiplies output memory x8 by default; a window near the
 #: int32 cell-id cap (~8 GiB of cells) fits HBM at streams=1 but not
-#: x8. 4 GiB leaves the measured headline configs (z15 window, 256 MiB
-#: slab -> 16 streams allowed) untouched while clamping the giant-
+#: x8. 4 GiB leaves the z15 headline window (256 MiB slab -> 16
+#: streams allowed) untouched while clamping the giant-
 #: window tail down to what fits.
 STREAM_SLAB_BUDGET = 4 << 30
 #: Cells per aligned output block (a side x side one-hot factor pair).
 #: Smaller blocks cut the per-point one-hot construction (VPU, 2*side
 #: compares+casts per point) and the MXU MACs quadratically, at the
 #: price of a lower good-chunk rate on dispersed data (a chunk must
-#: land inside ONE aligned block). 2^16 = 256x256 is the round-1
-#: measured default; sweep block_cells on-chip before changing it.
+#: land inside ONE aligned block). 2^16 = 256x256 came from an earlier
+#: on-chip sweep; sweep block_cells on the chip before changing it.
 DEFAULT_BLOCK_CELLS = 1 << 16
+
+
+def default_interpret() -> bool:
+    """What ``interpret=None`` means for the Pallas kernels: interpret
+    mode on CPU (Pallas has no compiled CPU lowering), the compiled
+    Mosaic kernel on TPU. Any other platform is an error rather than a
+    silent interpreter run."""
+    platform = jax.devices()[0].platform
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(
+        f"the Pallas kernels run on tpu (compiled) or cpu (interpret "
+        f"mode); the default device is {platform!r}")
 
 
 def masked_local_rc(block_start, good, stream, block_cells, side):
@@ -90,8 +100,7 @@ def masked_local_rc(block_start, good, stream, block_cells, side):
     ``jax_enable_x64`` (the batch job's z21 precision policy) weak
     Python-int literals trace as int64 scalars inside Pallas kernels,
     and Mosaic's int64->int32 convert lowering recurses forever
-    (RecursionError caught by the on-chip verify tool 2026-07-31;
-    pinned by tests/test_lowering.py)."""
+    (pinned by tests/test_lowering.py)."""
     bc = jnp.int32(block_cells)
     sd = jnp.int32(side)
     local = stream - block_start * bc
@@ -332,13 +341,11 @@ def bin_rowcol_window_partitioned(
     < 2^24, within f32 rounding otherwise — the pair sort changes
     summation order). ``bad_frac``: the scatter tail is sized
     n/bad_frac points; distributions badder than that fall back to the
-    full scatter inside the same jit (lax.cond). The 128 default is
-    the round-5 on-chip sweep winner (151.2 ms vs 189.2 ms at bf=8 on
-    the z15 headline window, v5e-1 — 222.0 M pts/s; PERF_NOTES.md
-    round 5): the tail rarely fills, so a smaller bound frees HBM and
-    scatter work without changing results. ``interpret`` defaults
-    to True on CPU (pallas has no compiled CPU lowering), False on
-    accelerators. ``block_cells`` sets the aligned output-block size
+    full scatter inside the same jit (lax.cond). The 128 default came
+    from an earlier on-chip sweep: the tail rarely fills, so a smaller
+    bound frees HBM and scatter work without changing results.
+    ``interpret=None`` follows :func:`default_interpret`.
+    ``block_cells`` sets the aligned output-block size
     (must be an even power of two >= 2^12 so the side is a
     lane-friendly square; see DEFAULT_BLOCK_CELLS). ``streams`` splits
     the cell-id stream into that many independently sorted rows (one
@@ -354,7 +361,7 @@ def bin_rowcol_window_partitioned(
     backend="auto" routed here with the streams default.
     """
     if interpret is None:
-        interpret = jax.devices()[0].platform == "cpu"
+        interpret = default_interpret()
     if dtype is None:
         dtype = jnp.int32 if weights is None else jnp.float32
     streams = clamp_streams(streams, window, block_cells)
@@ -432,8 +439,8 @@ def _bin_partitioned_jit(
     # Unstable sort: for counts, cell ids are the only payload, so equal
     # keys are indistinguishable and stability would only cost time.
     # Weighted, the weight rides as a lax.sort payload operand — XLA
-    # permutes it in-pass, avoiding the separate gather that costs as
-    # much as the scatter being avoided (PERF_NOTES.md). With
+    # permutes it in-pass, avoiding a separate gather that would cost
+    # as much as the scatter being avoided. With
     # streams > 1 this is one batched row sort (axis -1).
     if weighted:
         s2, w2 = lax.sort(

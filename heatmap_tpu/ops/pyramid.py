@@ -6,8 +6,7 @@ reformulation (ops/sparse_partitioned.py) that reduces EVERY level
 from the original sorted point stream under ``key >> 2i`` — unit
 weights at every level, which is what keeps the slab-wise f32
 accumulation exact (re-aggregating a previous level's counts as
-weights would overflow the f32 slab bound). Pending on-chip
-measurement before any routing (PERF_NOTES.md).
+weights would overflow the f32 slab bound).
 
 The reference coarsens one zoom per Spark stage by round-tripping every
 aggregate through inverse+forward projection (reference heatmap.py:60-61,
@@ -94,8 +93,8 @@ def pyramid_sparse_morton(
     ``adaptive=True`` (EAGER callers only — it reads each level's true
     unique count from the device) shrinks every subsequent level's
     arrays to the next power of two above the previous level's unique
-    count. Per-level reductions cost two ~8-30 ns/element scatters on
-    TPU (PERF_NOTES.md), so on collapsing data this turns
+    count. Per-level reductions cost two scatters over the level's
+    capacity, so on collapsing data this turns
     ``levels * capacity`` scatter work into ~``2-3 * n_unique_0`` —
     results are identical (the dropped slots are sentinel padding).
     Under jit the counts are tracers and this flag must stay False.

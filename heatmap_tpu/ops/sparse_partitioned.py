@@ -1,12 +1,11 @@
 """Partitioned-MXU segment reduction for the cascade's sorted streams.
 
-``aggregate_sorted_keys`` (ops/sparse.py) costs two ~8-30 ns/element
-scatters per cascade level on v5e — the sums ``.at[seg].add`` and the
-unique-keys ``.at[seg].set`` — 32 scatters across a 16-level cascade,
-the dominant device cost of the batch job (PERF_NOTES.md). This module
-reformulates BOTH as one pass of the measured-2.2x sort-partitioned
-one-hot-matmul machinery (ops/partitioned.py), exploiting that the
-cascade's inputs are already sorted:
+``aggregate_sorted_keys`` (ops/sparse.py) costs two scatters per
+cascade level — the sums ``.at[seg].add`` and the unique-keys
+``.at[seg].set`` — 32 scatters across a 16-level cascade. This module
+reformulates BOTH as one pass of the sort-partitioned one-hot-matmul
+machinery (ops/partitioned.py), exploiting that the cascade's inputs
+are already sorted:
 
 - the segment index ``seg = cumsum(first) - 1`` is a sorted, dense
   cell id into [0, capacity) — exactly the stream shape the
@@ -35,18 +34,15 @@ zero-sum segments survive with their keys — bit-parity with the
 scatter path. FRACTIONAL weights genuinely cannot ride this kernel:
 f32 products of non-integer weights round before accumulation, and
 there is no slab size that restores exactness — those stay on the
-scatter path (the precise boundary VERDICT r4 #7 asked for). Keys
-must fit 60 bits (a caller contract; the cascade's composite keys do
-by the int64 packing check in pipeline/cascade.composite_keys).
+scatter path. Keys must fit 60 bits (a caller contract; the cascade's
+composite keys do by the int64 packing check in
+pipeline/cascade.composite_keys).
 
-STATUS: interpret-mode verified (tests/test_sparse_partitioned.py,
-bit-equal to aggregate_sorted_keys including multi-slab and fallback
-paths) AND compiled + bit-exact on v5e under real Mosaic lowering
-(2026-07-31, clustered 1M-key drive, after the x64 int32-constant
-fixes). The on-chip WIN measurement (cascade suite of
-tools/sweep_partitioned.py) decides whether
-BatchJobConfig.cascade_backend defaults here — nothing routes here by
-default yet.
+Interpret mode is pinned bit-equal to aggregate_sorted_keys, including
+the multi-slab and fallback paths (tests/test_sparse_partitioned.py);
+the compiled kernel is pinned by tests/test_lowering.py and compared
+with the scatter cascade on the chip by ``chip_smoke.py``.
+BatchJobConfig's "auto" cascade backend routes count jobs on TPU here.
 """
 
 from __future__ import annotations
@@ -58,8 +54,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from heatmap_tpu.obs import events as obs_events
 from heatmap_tpu.ops.histogram import IMAP_ZERO
-from heatmap_tpu.ops.partitioned import masked_local_rc
+from heatmap_tpu.ops.partitioned import default_interpret, masked_local_rc
 
 DEFAULT_CHUNK = 1024
 DEFAULT_BLOCK_CELLS = 1 << 16
@@ -135,9 +132,8 @@ def _channel_path(cells, chans, good, capacity, n_blocks, chunk,
     ``streams`` splits the (globally sorted) slab into that many
     contiguous sub-streams, each accumulating into its own slab of
     output blocks, summed at the end — the same grid-pipelining trick
-    the window kernel's streams=8 default bought 2.0x from
-    (PERF_NOTES 2026-07-31). Identical math: counts and key-piece
-    channels are linear, every segment's FIRST element lands in
+    as the window kernel's streams default. Identical math: counts and
+    key-piece channels are linear, every segment's FIRST element lands in
     exactly one sub-stream, and chunk boundaries are unchanged
     (sub-streams are whole runs of chunks), so the bad-chunk tail is
     untouched. The sub-slab sums stay f32-exact: each slab holds at
@@ -305,7 +301,10 @@ def aggregate_sorted_keys_partitioned(
     restores exactness): use the scatter path.
     """
     if interpret is None:
-        interpret = jax.devices()[0].platform == "cpu"
+        interpret = default_interpret()
+    # Runs at trace time: the mode this kernel is lowered in.
+    obs_events.emit("pallas_kernel", kernel="segment",
+                    interpret=bool(interpret), n=int(sorted_keys.shape[0]))
     keys = jnp.asarray(sorted_keys)
     if sentinel is None:
         sentinel = jnp.iinfo(keys.dtype).max

@@ -6,7 +6,7 @@ import os
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 # Canonical axis names. ``data`` shards points (the RDD-partition analog);
 # ``tile`` shards raster/tile space (the reducer-partition analog,
@@ -16,52 +16,25 @@ TILE_AXIS = "tile"
 
 
 def shard_map(body, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions.
-
-    jax promoted shard_map out of jax.experimental and renamed its
-    replication-check knob (``check_rep`` -> ``check_vma``) along the
-    way; every mesh kernel in this package routes through this shim so
-    the kernels run on both sides of that line unchanged.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    """``jax.shard_map`` with positional mesh/specs; every mesh kernel in
+    this package routes through it."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def named_sharding(mesh, *spec):
-    """``jax.sharding.NamedSharding(mesh, PartitionSpec(*spec))`` across
-    jax versions.
-
-    The GSPMD cascade (parallel/gspmd.py) annotates global-view arrays
-    with NamedSharding instead of entering shard_map; this shim is its
-    version seam, mirroring :func:`shard_map` above. jax < 0.4.20 spelt
-    the class ``MeshPspecSharding`` — fall back to it so the gspmd entry
-    points import (and run) on the same jax range the shard_map kernels
-    support.
-    """
-    from jax.sharding import PartitionSpec
-
-    cls = getattr(jax.sharding, "NamedSharding", None)
-    if cls is None:  # pragma: no cover - ancient jax only
-        cls = jax.sharding.MeshPspecSharding
-    return cls(mesh, PartitionSpec(*spec))
+    """``NamedSharding(mesh, PartitionSpec(*spec))`` — the annotation the
+    GSPMD cascade (parallel/gspmd.py) puts on its global-view arrays."""
+    return NamedSharding(mesh, PartitionSpec(*spec))
 
 
 def force_cpu_devices(n_devices: int) -> None:
     """Pin the process to an ``n_devices``-wide virtual CPU backend.
 
-    The canonical multi-chip-dry-run shim, now shared by every entry
-    point instead of living only next to the shard_map driver: newer
-    jax honors ``jax_num_cpu_devices``; jax < 0.5 lacks that config
-    knob (AttributeError), where the pre-init ``XLA_FLAGS``
-    host-platform device count set here is what the re-init after
-    ``clear_backends`` reads instead. Must run before (or while
-    clearing) backend initialization — XLA_FLAGS set post-start are
-    not re-read.
+    The multi-chip dry-run setup shared by every entry point. Must run
+    before (or while clearing) backend initialization: the
+    ``XLA_FLAGS`` host device count is read when the backend starts,
+    and ``jax_num_cpu_devices`` may not change while one is live.
     """
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
@@ -72,11 +45,7 @@ def force_cpu_devices(n_devices: int) -> None:
 
     _jb.clear_backends()
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", n_devices)
-    except AttributeError:
-        # jax < 0.5: no jax_num_cpu_devices; XLA_FLAGS above covers it.
-        pass
+    jax.config.update("jax_num_cpu_devices", n_devices)
 
 
 def make_mesh(data: int | None = None, tile: int = 1, devices=None) -> Mesh:

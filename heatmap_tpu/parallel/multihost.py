@@ -312,8 +312,8 @@ def _alltoall_bytes(dest_payloads: list[bytes],
     Default multi-process transport rides the same device fabric as
     the compute collectives (DCN across hosts — "How to Scale Your
     Model"'s host-transfer recipe, not a sidecar TCP mesh), SKEW-PROOF
-    by construction (VERDICT r3 weak #5 — the earlier dense
-    (k, global-max) frame let one hot pair pad every row):
+    by construction (a dense (k, global-max) frame would let one hot
+    pair pad every row):
 
     1. one small allgather publishes the k×k length matrix, so every
        process knows every pair's exact payload size;
@@ -406,8 +406,8 @@ def scatter_blobs(local_blobs: dict,
     fully-merged blobs it OWNS (blob_owner partition) — and nothing
     else. The scalable replacement for gather_blobs: total bytes moved
     equal the blob volume once, and per-host memory is the owned shard,
-    not the whole result (VERDICT r2 missing #3; reference analog:
-    distributed reducer writes, heatmap.py:149-150).
+    not the whole result (reference analog: distributed reducer
+    writes, heatmap.py:149-150).
 
     Single-process: returns ``local_blobs`` unchanged.
     """

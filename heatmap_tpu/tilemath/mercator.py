@@ -17,11 +17,9 @@ needs ~zoom+2 bits of mantissa for correct binning at zoom z. float32
 (24-bit mantissa) is safe through z≈15 away from tile boundaries and is
 the fast TPU path; float64 (requires ``jax_enable_x64``) reproduces the
 CPython-double reference semantics through z21 and is the default when
-x64 is enabled. Measured on v5e-1 (PERF_NOTES.md round 2): emulated
-f64 projection runs at 0.31 B pts/s (~1.8x the f32 cost) and is
-bit-identical to the CPython-double oracle at z21, while f32 misbins
-~86% of points at z21 — so detail-zoom device binning should always
-run under x64; no split-precision kernel is needed.
+x64 is enabled. The f64 projection is bit-identical to the
+CPython-double oracle at z21, while f32 misbins most points at z21 —
+so detail-zoom device binning always runs under x64.
 """
 
 from __future__ import annotations

@@ -3,8 +3,7 @@
 // The reference-format egress must turn ~tens of millions of
 // (row, col, value) aggregates into per-blob JSON documents
 // '{"z_r_c": v, ...}'. numpy's per-aggregate number->string formatting
-// is the measured floor of that path (~0.5 M aggregates/s,
-// PERF_NOTES.md round 2, GIL-bound so threads don't help Python).
+// is the floor of that path (GIL-bound, so threads don't help Python).
 // This formatter does the same work in C with integer formatting and
 // OS threads: the Python side passes the (already sorted) level
 // columns plus the blob-start mask, and receives ONE buffer of

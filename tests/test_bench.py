@@ -1,6 +1,5 @@
-"""bench.py is a driver contract: ONE JSON line with the headline
-metric. Pin its shape (including the CPU fallback fields) so refactors
-can't silently break the round artifact."""
+"""bench.py prints ONE JSON line with the headline metric; pin its shape,
+and pin that a failure is an error rather than a fallback."""
 
 import json
 import os
@@ -31,12 +30,20 @@ def test_bench_emits_one_json_line():
     assert out["bin_backend_resolved"] == "xla"  # auto on CPU
 
 
-def test_bench_backend_failure_falls_back():
-    # pallas has no compiled CPU lowering; the bench must degrade to
-    # the scatter path and say so, never emit value=0.
+def test_bench_backend_failure_exits_nonzero():
+    # pallas has no compiled CPU lowering: the bench must fail loudly,
+    # not swap in the scatter path or print a result.
     r = _run_bench("--bin-backend", "pallas")
-    assert r.returncode == 0, r.stderr
-    out = json.loads(r.stdout.strip().splitlines()[-1])
-    assert out["value"] > 0
-    assert out["bin_backend_resolved"] == "xla"
-    assert "fallback" in out["note_backend"]
+    assert r.returncode != 0
+    assert '"value"' not in r.stdout
+
+
+def test_bench_without_cpu_flag_needs_a_tpu():
+    r = subprocess.run(
+        [sys.executable, "bench.py", "--n", "4096", "--steps", "1"],
+        capture_output=True, text=True, timeout=120, cwd=REPO,
+        env=dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu"),
+    )
+    assert r.returncode != 0
+    assert "found no TPU" in r.stderr
+    assert '"value"' not in r.stdout

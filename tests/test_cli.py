@@ -66,6 +66,79 @@ class TestParser:
             cmd_tiles(args)
 
 
+class TestBackendTpu:
+    """``--backend tpu`` (the default) means a TPU; the suite's CPU-only
+    host must be refused, never quietly used."""
+
+    @pytest.fixture(autouse=True)
+    def _restore_cache_dir(self):
+        import jax
+
+        prev = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+    @pytest.mark.parametrize("argv", [
+        ["run", "--input", "synthetic:10", "--output", "memory:"],
+        ["tiles", "--input", "synthetic:10"],
+        ["info"],
+    ])
+    def test_refused_in_process(self, argv):
+        from heatmap_tpu.cli import main
+
+        with pytest.raises(SystemExit, match="found no TPU"):
+            main(argv)
+
+    def test_run_exits_nonzero(self):
+        r = _run_cli("run", "--backend", "tpu", "--input", "synthetic:10",
+                     "--output", "memory:")
+        assert r.returncode != 0
+        assert "found no TPU" in r.stderr
+
+
+class TestCompileCache:
+    @pytest.fixture(autouse=True)
+    def _restore_cache_dir(self):
+        import jax
+
+        prev = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+    def test_env_var_wins_and_nothing_else_is_set(self, monkeypatch,
+                                                  tmp_path):
+        import jax
+
+        from heatmap_tpu.utils.compile_cache import enable_compile_cache
+
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None
+
+    def test_fixed_checkout_path_when_unset(self, monkeypatch):
+        import jax
+
+        from heatmap_tpu.utils.compile_cache import enable_compile_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(REPO, ".jax_cache")
+        assert enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+
+    def test_cli_backend_init_places_the_cache(self, monkeypatch):
+        import jax
+
+        from heatmap_tpu.cli import _init_backend
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        jax.config.update("jax_compilation_cache_dir", None)
+        _init_backend(build_parser().parse_args(
+            ["info", "--backend", "cpu"]))
+        assert (jax.config.jax_compilation_cache_dir
+                == os.path.join(REPO, ".jax_cache"))
+
+
 class TestEndToEnd:
     def test_run_synthetic_to_jsonl(self, tmp_path):
         out = tmp_path / "blobs.jsonl"

@@ -375,13 +375,12 @@ def test_run_cascade_gspmd_marks_device_inputs():
         gspmd.ledger.clear()
 
 
-# -- mesh compat shim ------------------------------------------------------
+# -- virtual CPU mesh ------------------------------------------------------
 
 
-def test_force_cpu_devices_shim_imports_gspmd():
-    """jax<0.5 has no jax_num_cpu_devices config: force_cpu_devices
-    must fall back to XLA_FLAGS and still give the gspmd entry points a
-    multi-device CPU mesh (regression for the stale compat shim)."""
+def test_force_cpu_devices_gives_gspmd_a_mesh():
+    """force_cpu_devices in a fresh interpreter (no XLA_FLAGS) gives the
+    gspmd entry points a 4-device CPU mesh."""
     code = (
         "import os\n"
         "os.environ.pop('XLA_FLAGS', None)\n"
@@ -399,7 +398,7 @@ def test_force_cpu_devices_shim_imports_gspmd():
         "assert int(lv[0][2]) == 64\n"
         "s = mesh.named_sharding(m, mesh.DATA_AXIS)\n"
         "assert s.is_fully_addressable\n"
-        "print('SHIM-OK')\n"
+        "print('MESH-OK')\n"
     )
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
@@ -408,7 +407,7 @@ def test_force_cpu_devices_shim_imports_gspmd():
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=180)
     assert out.returncode == 0, out.stderr
-    assert "SHIM-OK" in out.stdout
+    assert "MESH-OK" in out.stdout
 
 
 # -- feeder ----------------------------------------------------------------

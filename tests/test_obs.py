@@ -29,6 +29,7 @@ _PAYLOADS = {
                          "reason": "non-tpu platform -> xla scatter"},
     "cascade_dispatch": {"backend": "scatter", "jit": True,
                          "n_emissions": 10},
+    "pallas_kernel": {"kernel": "segment", "interpret": False, "n": 2048},
     "partition_planned": {"n_shards": 4, "splits": [12, 90, 400],
                           "sampled_points": 4096, "balance_factor": 1.25,
                           "max_shard_mass": 0.27, "mean_shard_mass": 0.25,

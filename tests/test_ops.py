@@ -304,9 +304,7 @@ def test_window_from_bounds_rejects_impossible_alignment():
 
 def test_pick_backend_weighted_large_window_routes_partitioned(monkeypatch):
     """On TPU, auto routes large-window WEIGHTED binning to the
-    partitioned MXU path (340.6 ms vs 432.5 ms XLA scatter at the z15
-    headline window, k=8, v5e-1 round-5 sweep — PERF_NOTES.md). The
-    platform is faked: the routing decision is host-side and must not
+    partitioned MXU path. The platform is faked: the routing decision is host-side and must not
     need a chip to be testable."""
     import types
 

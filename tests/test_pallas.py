@@ -1,5 +1,5 @@
 """Pallas MXU histogram kernel tests (interpret mode on the CPU mesh;
-compiled-path parity and speed were measured on the real chip: PERF_NOTES.md).
+the compiled kernel is pinned by tests/test_lowering.py).
 
 Parity oracle: the XLA scatter path (ops.histogram), itself verified
 against the pure-Python reference oracle in test_ops.py.

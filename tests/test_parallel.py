@@ -580,9 +580,9 @@ def _collectives(fn, *args):
 
 
 def test_collective_placement_pinned_in_hlo(mesh, mesh2d):
-    """Structural pin for the three check_vma=False kernels (VERDICT r3
-    weak #3): the vma check cannot cover pallas-routing shard_maps, so
-    assert the compiled module's collective set directly —
+    """Structural pin for the three check_vma=False kernels: the vma
+    check cannot cover pallas-routing shard_maps, so assert the
+    compiled module's collective set directly —
 
     - replicated binning: exactly one psum family (all-reduce), and
       crucially NO all-to-all / reduce-scatter;

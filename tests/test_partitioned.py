@@ -291,3 +291,24 @@ def test_weighted_empty_and_zero_weights():
                          rng.integers(300, 500, n),
                          np.zeros(n, np.float32))
     assert out.sum() == 0
+
+
+@pytest.mark.parametrize("platform,want", [("cpu", True), ("tpu", False),
+                                           ("gpu", RuntimeError)])
+def test_default_interpret_follows_the_platform(monkeypatch, platform, want):
+    """interpret=None means the interpreter on CPU, the compiled kernel
+    on TPU, and an error anywhere else (never a silent interpreter run
+    on an accelerator)."""
+    import types
+
+    import jax
+
+    from heatmap_tpu.ops.partitioned import default_interpret
+
+    monkeypatch.setattr(jax, "devices",
+                        lambda: [types.SimpleNamespace(platform=platform)])
+    if want is RuntimeError:
+        with pytest.raises(RuntimeError, match="gpu"):
+            default_interpret()
+    else:
+        assert default_interpret() is want

@@ -154,7 +154,7 @@ def test_pyramid_partitioned_matches_scatter_pyramid():
         assert (np.asarray(gu)[m:] == SENTINEL).all(), lvl
 
 
-# -- bounded-integer weighted form (VERDICT r4 #7) --------------------------
+# -- bounded-integer weighted form -----------------------------------------
 
 
 def _diff_weighted(keys, weights, capacity, weight_bound, **kw):

@@ -124,8 +124,8 @@ checks["weighted_gather_equals_oracle"] = blobs_equal(got_w, want_w)
 
 # 1c) bounded slice ingest over the same transport: each process
 # streams its slice through the CHUNKED cascade + host merge
-# (max_points_in_flight now composes with multi-process runs, VERDICT
-# r3 missing #5) — ~700-point chunks force several chunks per slice,
+# (max_points_in_flight composes with multi-process runs) — ~700-point
+# chunks force several chunks per slice,
 # and blobs must still equal the unbounded oracle exactly.
 got_b = run_job_multihost(src, config=cfg, batch_size=batch,
                           egress="gather", max_points_in_flight=700)
