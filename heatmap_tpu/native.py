@@ -22,6 +22,7 @@ treat as "use the pure-Python path".
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
 import subprocess
 from typing import Iterator
@@ -41,34 +42,47 @@ def _lib_candidates():
 
 
 def build(quiet: bool = True) -> str | None:
-    """Build the native library via make; returns its path or None."""
+    """Build the native library via make; returns its path or None.
+
+    Processes that import this module at once (parallel test workers)
+    build one at a time, under an exclusive lock on a file in the build
+    directory: the first builds, the others find the library up to date.
+    The Makefile links into a temporary file and renames it into place,
+    so a reader never sees a half-written library.
+    """
     if not os.path.isdir(_NATIVE_DIR):
         return None
     out = subprocess.DEVNULL if quiet else None
+    build_dir = os.path.join(_NATIVE_DIR, "build")
     try:
-        rc = subprocess.call(["make", "-C", _NATIVE_DIR], stdout=out, stderr=out)
+        os.makedirs(build_dir, exist_ok=True)
+        with open(os.path.join(build_dir, ".build.lock"), "a") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)  # released when closed
+            rc = subprocess.call(["make", "-C", _NATIVE_DIR],
+                                 stdout=out, stderr=out)
     except OSError:
         return None
-    path = os.path.join(_NATIVE_DIR, "build", _LIB_NAME)
+    path = os.path.join(build_dir, _LIB_NAME)
     return path if rc == 0 and os.path.exists(path) else None
 
 
-def _load() -> ctypes.CDLL | None:
+def _open_first() -> ctypes.CDLL | None:
     for path in _lib_candidates():
         if os.path.exists(path):
             try:
                 return ctypes.CDLL(path)
             except OSError:
                 continue
-    if os.environ.get("HEATMAP_TPU_NO_NATIVE_BUILD"):
-        return None
-    path = build()
-    if path:
-        try:
-            return ctypes.CDLL(path)
-        except OSError:
-            return None
     return None
+
+
+def _load() -> ctypes.CDLL | None:
+    lib = _open_first()
+    if lib is not None or os.environ.get("HEATMAP_TPU_NO_NATIVE_BUILD"):
+        return lib
+    # Build (or wait for another process's build), then look again.
+    build()
+    return _open_first()
 
 
 _lib = _load()

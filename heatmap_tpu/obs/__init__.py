@@ -13,8 +13,8 @@ This module owns the shared metric handles (created once on the default
 registry — ``registry.reset()`` clears values but keeps these objects
 valid) and the convenience recorders instrumentation sites call. Every
 recorder is a no-op when neither the registry is enabled nor an event
-log installed, so the pipeline pays near-zero cost with telemetry off —
-the same discipline as ``utils.trace.stage_span``. Keep all timing and
+log installed, so the pipeline pays near-zero cost with telemetry off.
+Keep all timing and
 stdout inside this package (or utils/trace.py): a tier-1 test greps the
 instrumented modules for raw ``print(`` / ``time.perf_counter(``.
 """
@@ -133,6 +133,15 @@ ANOMALIES_TOTAL = _registry.counter(
     labelnames=("watch",))
 PROCESS_UPTIME = _registry.gauge(
     "process_uptime_seconds", "Seconds since this process imported obs")
+JAX_COMPILES = _registry.counter(
+    "jax_compiles_total",
+    "Programs JAX compiled while tracing was on (persistent-cache loads "
+    "excluded), by compiled function",
+    labelnames=("fun_name",))
+JAX_COMPILE_CACHE_HITS = _registry.counter(
+    "jax_compile_cache_hits_total",
+    "Programs loaded from the persistent compile cache while tracing "
+    "was on")
 BUILD_INFO = _registry.gauge(
     "heatmap_build_info", "Constant 1; the version label is the payload",
     labelnames=("version",))
@@ -169,17 +178,21 @@ class DispatchTimer:
     share. Construct at the start of the host phase, call
     :meth:`dispatched` when the program has been handed to the
     runtime, :meth:`finished` once outputs are ready (the caller
-    blocks on the result in between). Everything no-ops when telemetry
-    is off, so the production path pays two global reads. Lives here
-    because wall-clock reads are banned outside obs/ and utils/trace
-    (tests/test_obs.py grep guards).
+    blocks on the result in between). It is enabled while telemetry
+    or span tracing is on, and then the caller's ``cascade.device``
+    span closes when the device is done. Off, everything no-ops, so the
+    production path pays three global reads, nothing blocks, and
+    ``cascade.device`` measures the dispatch alone (the device time
+    then lands in the first span that reads the outputs,
+    ``cascade.decode.wait``). Lives here because wall-clock reads are
+    banned outside obs/ and utils/trace (tests/test_obs.py grep guards).
     """
 
     __slots__ = ("dispatch", "enabled", "_t0", "_t1")
 
     def __init__(self, dispatch: str):
         self.dispatch = dispatch
-        self.enabled = telemetry_enabled()
+        self.enabled = telemetry_enabled() or tracing.tracing_enabled()
         self._t0 = time.perf_counter() if self.enabled else 0.0
         self._t1 = None
 
@@ -190,7 +203,7 @@ class DispatchTimer:
 
     def finished(self, items=None):
         """Outputs ready; record both phases. Returns (host_s,
-        device_s) when telemetry is on, else None."""
+        device_s) when enabled, else None."""
         if not self.enabled or self._t1 is None:
             return None
         t2 = time.perf_counter()

@@ -1,6 +1,6 @@
 """Process-wide metrics registry: counters, gauges, fixed-bucket histograms.
 
-Same zero-cost discipline as ``utils.trace.stage_span``: every mutation
+Zero cost when off: every mutation
 checks the registry's ``enabled`` flag first and returns immediately when
 no sink is configured, so instrumented hot paths pay one attribute read
 and one boolean test when telemetry is off.

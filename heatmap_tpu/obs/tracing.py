@@ -30,9 +30,20 @@ Design points:
   spans as trace-event JSON (``ph: "X"`` complete events, microsecond
   ``ts``/``dur``) loadable in ``chrome://tracing`` / Perfetto and by
   ``tools/trace_analyze.py`` (critical path + self-time attribution).
+- **Compile spans.** While tracing is on, every JAX compile phase
+  (``trace``, ``lower``, ``backend``) becomes a ``jax.compile`` span
+  under the ambient span, with the compiled function's ``fun_name``;
+  the registry counts ``jax_compiles_total{fun_name}`` and
+  ``jax_compile_cache_hits_total``. A ``backend`` span that loaded its
+  program from the persistent cache carries ``cache_hit=True``.
+- **Profiler timeline.** While tracing is on, every span of the default
+  tracer (utils/trace.py) also enters a ``jax.profiler.TraceAnnotation``
+  of its name, so it shows on the host plane of a profiler trace.
 
 All timing goes through ``_now_s`` — the module's single sanctioned
-clock site (tests/test_obs.py greps this file).
+clock site (tests/test_obs.py greps this file). JAX stamps its compile
+events with the wall clock; they move onto ``_now_s``'s clock by an
+offset read once in :func:`enable_tracing`.
 """
 
 from __future__ import annotations
@@ -209,6 +220,10 @@ class TraceCollector:
 
 _on = False  # THE hot-path guard: one global read when tracing is off
 _collector: TraceCollector | None = None
+# Wall clock (time.time, JAX's compile-event clock) minus _now_s, read
+# once per enable_tracing.
+_wall_offset_s = 0.0
+_compile_listener_installed = False
 # Installed by obs.recorder.install: routes shadow spans (unsampled
 # trees captured at full fidelity) into the flight-recorder ring.
 _recorder = None
@@ -220,7 +235,11 @@ def enable_tracing(sample: float = 1.0,
                    seed: int | None = None) -> TraceCollector:
     """Install a collector and hook the tracer + event log onto the
     tree. Returns the collector (export/summary handle)."""
-    global _on, _collector
+    global _on, _collector, _wall_offset_s
+    import jax
+
+    _install_compile_listener()
+    _wall_offset_s = time.time() - _now_s()
     _collector = TraceCollector(sample=sample, seed=seed)
     _on = True
     from heatmap_tpu.obs import events, metrics
@@ -228,6 +247,7 @@ def enable_tracing(sample: float = 1.0,
 
     trace._tree_begin = begin_span
     trace._tree_end = end_span
+    trace._annotation = jax.profiler.TraceAnnotation
     events._trace_ids = current_ids
     metrics._exemplar_ids = current_ids
     return _collector
@@ -243,6 +263,7 @@ def disable_tracing():
 
     trace._tree_begin = None
     trace._tree_end = None
+    trace._annotation = None
     events._trace_ids = None
     metrics._exemplar_ids = None
 
@@ -330,6 +351,11 @@ def end_span(sp):
     if isinstance(sp, _NotSampled):
         return
     sp.dur_s = _now_s() - sp.start_s
+    _hand_off(sp)
+
+
+def _hand_off(sp: Span):
+    """A finished span to the collector and the flight recorder."""
     recorder = _recorder
     if sp.shadow:
         # Shadow spans never reach the collector directly; the ring
@@ -342,6 +368,77 @@ def end_span(sp):
         collector.add(sp)
     if recorder is not None:
         recorder.record_span(sp)
+
+
+# -- compile spans ---------------------------------------------------------
+
+COMPILE_SPAN = "jax.compile"
+#: JAX's compile events (jax/_src/dispatch.py) -> the span's ``phase``.
+COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_compile_state = threading.local()  # .cache_hit: the open backend phase's
+
+
+def _install_compile_listener():
+    """Register the compile listeners with jax.monitoring, once per
+    process (JAX has no way to list or dedupe them)."""
+    global _compile_listener_installed
+    if _compile_listener_installed:
+        return
+    import jax
+
+    jax.monitoring.register_event_time_span_listener(_on_compile_event)
+    jax.monitoring.register_event_listener(_on_cache_event)
+    _compile_listener_installed = True
+
+
+def _on_cache_event(event: str, **_):
+    if not _on or event != CACHE_HIT_EVENT:
+        return
+    # Fired inside the backend phase, on the compiling thread.
+    _compile_state.cache_hit = True
+    from heatmap_tpu import obs
+
+    obs.JAX_COMPILE_CACHE_HITS.inc()
+
+
+def _on_compile_event(event: str, start_time: float, end_time: float,
+                      fun_name: str = "", **_):
+    """One compile phase -> one ``jax.compile`` span under the ambient
+    span (a root when there is none; nothing under an unsampled root)."""
+    if not _on:
+        return
+    phase = COMPILE_PHASES.get(event)
+    collector = _collector
+    if phase is None or collector is None:
+        return
+    attrs = {"phase": phase, "fun_name": str(fun_name)}
+    if phase == "backend":
+        hit = getattr(_compile_state, "cache_hit", False)
+        _compile_state.cache_hit = False
+        if hit:
+            attrs["cache_hit"] = True
+        else:
+            from heatmap_tpu import obs
+
+            obs.JAX_COMPILES.inc(fun_name=str(fun_name))
+    parent = _current.get()
+    if isinstance(parent, _NotSampled):
+        return
+    if parent is None:
+        if not collector.sample_decision():
+            return
+        sp = Span(COMPILE_SPAN, uuid.uuid4().hex, None, attrs)
+    else:
+        sp = Span(COMPILE_SPAN, parent.trace_id, parent.span_id, attrs)
+        sp.shadow = parent.shadow
+    sp.start_s = start_time - _wall_offset_s
+    sp.dur_s = end_time - start_time
+    _hand_off(sp)
 
 
 @contextlib.contextmanager

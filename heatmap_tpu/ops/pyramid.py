@@ -25,10 +25,10 @@ in tests/test_keys.py::test_parent_equals_reference_center_reprojection.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from heatmap_tpu.ops import sparse as sparse_ops
-from heatmap_tpu.utils import trace
 
 
 def coarsen_raster(raster):
@@ -104,9 +104,11 @@ def pyramid_sparse_morton(
     caps = _level_caps(capacity, n, levels)
 
     out = []
-    uniq, sums, count = sparse_ops.aggregate_keys(
-        codes, weights=weights, valid=valid, capacity=caps[0], acc_dtype=acc_dtype
-    )
+    with jax.named_scope("cascade.level0"):
+        uniq, sums, count = sparse_ops.aggregate_keys(
+            codes, weights=weights, valid=valid, capacity=caps[0],
+            acc_dtype=acc_dtype,
+        )
     out.append((uniq, sums, count))
     sentinel = jnp.iinfo(codes.dtype).max
     for lvl in range(1, levels + 1):
@@ -126,18 +128,17 @@ def pyramid_sparse_morton(
                 if keep < uniq.shape[0]:
                     uniq = uniq[:keep]
                     sums = sums[:keep]
-        # Parent codes of the previous level's uniques; sentinel slots
-        # must stay sentinel (a plain shift would corrupt them into
-        # plausible-looking codes).
-        parents = jnp.where(uniq == sentinel, sentinel, uniq >> 2)
-        with trace.stage_span("cascade.segment-reduce",
-                              items=int(uniq.shape[0])):
-            uniq, sums, count = trace.stage_block(
-                sparse_ops.aggregate_sorted_keys(
+        with jax.named_scope(f"cascade.level{lvl}"):
+            # Parent codes of the previous level's uniques; sentinel
+            # slots must stay sentinel (a plain shift would corrupt them
+            # into plausible-looking codes).
+            parents = jnp.where(uniq == sentinel, sentinel, uniq >> 2)
+            with jax.named_scope("cascade.segment-reduce"):
+                uniq, sums, count = sparse_ops.aggregate_sorted_keys(
                     parents, sums, min(caps[lvl], uniq.shape[0]) if adaptive
                     else caps[lvl],
                     sentinel=sentinel,
-                ))
+                )
         out.append((uniq, sums, count))
     return out
 
@@ -183,26 +184,26 @@ def pyramid_sparse_morton_partitioned(
     caps = _level_caps(capacity, n, levels)
 
     sentinel = jnp.iinfo(jnp.int64).max
-    keys = codes if valid is None else jnp.where(valid, codes, sentinel)
-    with trace.stage_span("cascade.sort", items=n):
+    with jax.named_scope("cascade.sort"):
+        keys = codes if valid is None else jnp.where(valid, codes, sentinel)
         if weights is None:
-            skeys = trace.stage_block(jnp.sort(keys, stable=False))
+            skeys = jnp.sort(keys, stable=False)
             sw = None
         else:
             # Weights ride the same order as their keys (integer sums
             # are order-free, so the unstable argsort is fine).
             order = jnp.argsort(keys, stable=False)
             skeys = keys[order]
-            sw = trace.stage_block(jnp.asarray(weights)[order])
+            sw = jnp.asarray(weights)[order]
 
     out = []
     for lvl in range(levels + 1):
         # Right shifts preserve the sort; the shifted sentinel
         # (intmax >> 2*lvl) still exceeds every real (< 2^60) key at
         # the shifted width, so it keeps sorting last and masking out.
-        with trace.stage_span("cascade.segment-reduce", items=n):
-            uniq, sums, n_unique = trace.stage_block(
-                sp.aggregate_sorted_keys_partitioned(
+        with jax.named_scope(f"cascade.level{lvl}"):
+            with jax.named_scope("cascade.segment-reduce"):
+                uniq, sums, n_unique = sp.aggregate_sorted_keys_partitioned(
                     skeys >> (2 * lvl),
                     caps[lvl],
                     sentinel=sentinel >> (2 * lvl),
@@ -213,14 +214,14 @@ def pyramid_sparse_morton_partitioned(
                     streams=streams,
                     sorted_weights=sw,
                     weight_bound=weight_bound,
-                ))
-        # Normalize padding to the repo-wide int64-max sentinel (the
-        # per-level call pads with its SHIFTED sentinel, which a
-        # `uniq != intmax` consumer mask would let through as phantom
-        # zero-count cells). The kernel already sentinels zero-sum
-        # segments via its presence channel, so masking on the sums
-        # here would be wrong for weighted zero totals — mask on the
-        # SHIFTED sentinel instead.
-        uniq = jnp.where(uniq == (sentinel >> (2 * lvl)), sentinel, uniq)
+                )
+            # Normalize padding to the repo-wide int64-max sentinel (the
+            # per-level call pads with its SHIFTED sentinel, which a
+            # `uniq != intmax` consumer mask would let through as
+            # phantom zero-count cells). The kernel already sentinels
+            # zero-sum segments via its presence channel, so masking on
+            # the sums here would be wrong for weighted zero totals —
+            # mask on the SHIFTED sentinel instead.
+            uniq = jnp.where(uniq == (sentinel >> (2 * lvl)), sentinel, uniq)
         out.append((uniq, sums, n_unique))
     return out

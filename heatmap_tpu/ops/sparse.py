@@ -13,9 +13,8 @@ is the TPU-friendly shape of this computation.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
-
-from heatmap_tpu.utils import trace
 
 
 def _sentinel_for(dtype):
@@ -68,12 +67,11 @@ def aggregate_keys(keys, weights=None, valid=None, capacity=None, acc_dtype=None
     # Counts (uniform weights) are exact under any summation order, so
     # the sort can be unstable; float weights keep the stable order so
     # results are reproducible against host-order oracles bit-for-bit.
-    with trace.stage_span("cascade.sort", items=n):
+    with jax.named_scope("cascade.sort"):
         order = jnp.argsort(keys, stable=weights is not None)
-        sk, sw = trace.stage_block((keys[order], w[order]))
-    with trace.stage_span("cascade.segment-reduce", items=n):
-        return trace.stage_block(
-            aggregate_sorted_keys(sk, sw, capacity, sentinel=sentinel))
+        sk, sw = keys[order], w[order]
+    with jax.named_scope("cascade.segment-reduce"):
+        return aggregate_sorted_keys(sk, sw, capacity, sentinel=sentinel)
 
 
 def aggregate_sorted_keys(sorted_keys, sorted_weights, capacity, sentinel=None):

@@ -410,21 +410,28 @@ def aggregate_sorted_keys_partitioned(
         good_slab = _good_of(c_slab, chunk, block_cells, capacity)
         n_bad = (~good_slab).sum()
 
+        # Each branch opens its own named scope, so a device trace
+        # tells the kernel's slab from the full scatter fallback.
+        def channel(c_, ch_, g_):
+            with jax.named_scope("slab.channel"):
+                return _channel_path(
+                    c_, ch_, g_, capacity, n_blocks, chunk, bad_cap,
+                    interpret, block_cells, side, streams=streams,
+                    n_channels=n_channels,
+                )
+
         def scatter_all(c_, ch_, g_):
-            return jnp.stack([
-                jnp.zeros(capacity, jnp.float64)
-                .at[c_]
-                .add(ch_[ch].astype(jnp.float64), mode="drop")
-                for ch in range(n_channels)
-            ])
+            with jax.named_scope("slab.fallback"):
+                return jnp.stack([
+                    jnp.zeros(capacity, jnp.float64)
+                    .at[c_]
+                    .add(ch_[ch].astype(jnp.float64), mode="drop")
+                    for ch in range(n_channels)
+                ])
 
         slab_sums = lax.cond(
             n_bad <= bad_cap,
-            lambda c_, ch_, g_: _channel_path(
-                c_, ch_, g_, capacity, n_blocks, chunk, bad_cap,
-                interpret, block_cells, side, streams=streams,
-                n_channels=n_channels,
-            ),
+            channel,
             scatter_all,
             c_slab,
             ch_slab,

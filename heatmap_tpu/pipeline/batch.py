@@ -899,7 +899,8 @@ def ingest_columns(batches, config: BatchJobConfig):
     lats, lons, users, stamps, vals = [], [], [], [], []
     for batch in batches:
         with tracer.span("ingest.batch"):
-            cols = load_columns(batch)
+            with tracer.span("ingest.load_columns"):
+                cols = load_columns(batch)
             lats.append(cols["latitude"])
             lons.append(cols["longitude"])
             users.extend(cols["user_id"])
@@ -2047,7 +2048,8 @@ def run_job_resumable(source, checkpoint_dir: str, sink=None,
         if fault_injector is not None:
             fault_injector.check(i)
         with tracer.span("ingest.batch"):
-            cols = load_columns(batch)
+            with tracer.span("ingest.load_columns"):
+                cols = load_columns(batch)
             lats.append(cols["latitude"])
             lons.append(cols["longitude"])
             gids.append(vocab.group_ids(cols["user_id"]))
@@ -2188,12 +2190,9 @@ def _run_grouped(lat, lon, group_ids, timestamps, vocab,
     with tracer.span("cascade.device", backend=backend):
         import jax.numpy as jnp
 
-        from heatmap_tpu.utils.trace import stage_tracing_enabled
-
         acc_dtype = jnp.float64 if e_weights is not None else None
         capacity = config.capacity or len(e_codes)
-        jit = not stage_tracing_enabled()
-        if jit and not config.adaptive_capacity:
+        if not config.adaptive_capacity:
             # Mirror the jit cache key (shapes + every static arg of
             # _build_cascade_jit) so bucket hit/miss counters track
             # actual compiles without poking jax internals.
@@ -2244,10 +2243,6 @@ def _run_grouped(lat, lon, group_ids, timestamps, vocab,
             weight_bound=config.weight_bound,
             partition_splits=partition_splits,
             dispatch=dispatch or "shard_map",
-            # Stage tracing needs the cascade EAGER: under the fused jit
-            # the sort/segment-reduce spans would time tracing, not
-            # execution (utils/trace.py stage_span).
-            jit=jit,
         )
         timer.dispatched()
         if timer.enabled:

@@ -37,6 +37,7 @@ from heatmap_tpu.obs import events as obs_events
 from heatmap_tpu.obs import tracing
 from heatmap_tpu.ops import pyramid as pyramid_ops
 from heatmap_tpu.tilemath.morton import morton_decode_np
+from heatmap_tpu.utils import trace
 
 
 @dataclasses.dataclass(frozen=True)
@@ -219,7 +220,8 @@ def build_cascade(codes, slots, config: CascadeConfig, n_slots: int,
             )
     elif backend != "scatter":
         raise ValueError(f"unknown cascade backend {backend!r}")
-    ck = composite_keys(codes, slots, config.detail_zoom, n_slots)
+    with jax.named_scope("cascade.keys"):
+        ck = composite_keys(codes, slots, config.detail_zoom, n_slots)
     # Zoom-clamped per-level capacities: level l's key space is at most
     # n_slots * 4^(detail_zoom - l) — a STATIC bound that no data can
     # exceed — so coarse levels get small arrays instead of n-sized
@@ -473,16 +475,17 @@ def decode_levels(level_data, config: CascadeConfig):
     # serial np.asarray calls. On CPU the transfer is free and a device
     # slice would only add a copy, so slice host-side there.
     n_lvls = config.n_levels + 1
-    if any(_on_accelerator(level_data[lvl][2]) for lvl in range(n_lvls)):
-        import jax
-
-        # Batch the count scalars too: int() per level would block on
-        # one device round trip each before the main transfer.
-        counts = [int(c) for c in jax.device_get(
-            [level_data[lvl][2] for lvl in range(n_lvls)]
-        )]
-    else:
-        counts = [int(level_data[lvl][2]) for lvl in range(n_lvls)]
+    # The first read of the cascade's outputs: where nothing blocked
+    # before, the host waits here for the device to finish.
+    with trace.span("cascade.decode.wait"):
+        if any(_on_accelerator(level_data[lvl][2]) for lvl in range(n_lvls)):
+            # Batch the count scalars too: int() per level would block
+            # on one device round trip each before the main transfer.
+            counts = [int(c) for c in jax.device_get(
+                [level_data[lvl][2] for lvl in range(n_lvls)]
+            )]
+        else:
+            counts = [int(level_data[lvl][2]) for lvl in range(n_lvls)]
     for level, n in enumerate(counts):
         if n > level_data[level][0].shape[0]:
             raise ValueError(
@@ -492,55 +495,60 @@ def decode_levels(level_data, config: CascadeConfig):
             )
     if any(_on_accelerator(level_data[lvl][0])
            for lvl in range(config.n_levels + 1)):
-        import jax
-
-        host = jax.device_get(
-            [(level_data[lvl][0][:n], level_data[lvl][1][:n])
-             for lvl, n in enumerate(counts)]
-        )
+        # One slice program per distinct row count: a job's compiles
+        # land in this span.
+        with trace.span("cascade.decode.slice"):
+            sliced = [(level_data[lvl][0][:n], level_data[lvl][1][:n])
+                      for lvl, n in enumerate(counts)]
+        with trace.span("cascade.decode.transfer"):
+            host = jax.device_get(sliced)
     else:
-        host = [
-            (np.asarray(level_data[lvl][0])[:n],
-             np.asarray(level_data[lvl][1])[:n])
-            for lvl, n in enumerate(counts)
-        ]
+        with trace.span("cascade.decode.transfer"):
+            host = [
+                (np.asarray(level_data[lvl][0])[:n],
+                 np.asarray(level_data[lvl][1])[:n])
+                for lvl, n in enumerate(counts)
+            ]
 
     out = []
-    for level, (keys_arr, sums) in enumerate(host):
-        # Lazy import (native asserts against pipeline.timespan at
-        # load; module-level would be circular). One threaded C pass
-        # replaces the ~8 single-threaded numpy passes when available.
-        from heatmap_tpu import native as _native
+    with trace.span("cascade.decode.host"):
+        for level, (keys_arr, sums) in enumerate(host):
+            # Lazy import (native asserts against pipeline.timespan at
+            # load; module-level would be circular). One threaded C pass
+            # replaces the ~8 single-threaded numpy passes when
+            # available.
+            from heatmap_tpu import native as _native
 
-        code_bits = 2 * (config.detail_zoom - level)
-        # The native decoder returns int32 slots. Slot ids are bounded
-        # by key >> code_bits; with code_bits >= 33 they fit int32 by
-        # construction, below that check the actual max (one cheap
-        # pass) and fall back to the int64 numpy path if they don't.
-        native_ok = _native.decode_keys is not None and (
-            code_bits >= 33
-            or keys_arr.size == 0
-            or int(keys_arr.max()) >> code_bits < 2**31
-        )
-        if native_ok:
-            slot_ids, codes, rows, cols = _native.decode_keys(
-                keys_arr, code_bits
+            code_bits = 2 * (config.detail_zoom - level)
+            # The native decoder returns int32 slots. Slot ids are
+            # bounded by key >> code_bits; with code_bits >= 33 they fit
+            # int32 by construction, below that check the actual max
+            # (one cheap pass) and fall back to the int64 numpy path if
+            # they don't.
+            native_ok = _native.decode_keys is not None and (
+                code_bits >= 33
+                or keys_arr.size == 0
+                or int(keys_arr.max()) >> code_bits < 2**31
             )
-        else:
-            slot_ids, codes = decode_level_keys(
-                keys_arr, config.detail_zoom, level
+            if native_ok:
+                slot_ids, codes, rows, cols = _native.decode_keys(
+                    keys_arr, code_bits
+                )
+            else:
+                slot_ids, codes = decode_level_keys(
+                    keys_arr, config.detail_zoom, level
+                )
+                rows, cols = morton_decode_np(codes)
+            out.append(
+                {
+                    "zoom": config.detail_zoom - level,
+                    "slot": slot_ids,
+                    "code": codes,
+                    "row": rows,
+                    "col": cols,
+                    "value": sums.astype(np.float64),
+                }
             )
-            rows, cols = morton_decode_np(codes)
-        out.append(
-            {
-                "zoom": config.detail_zoom - level,
-                "slot": slot_ids,
-                "code": codes,
-                "row": rows,
-                "col": cols,
-                "value": sums.astype(np.float64),
-            }
-        )
     return out
 
 
@@ -573,23 +581,28 @@ def finalize_level_arrays(levels, config: CascadeConfig, slot_names):
     where full string columns are genuinely needed.
     """
     if config.amplify_all:
-        _patch_amplified(levels, slot_names)
-    n_slots = max(slot_names) + 1
-    users = np.array([slot_names.get(s, ("?", "?"))[0] for s in range(n_slots)])
-    tss = np.array([slot_names.get(s, ("?", "?"))[1] for s in range(n_slots)])
-    # Unique name tables + per-slot index maps (tiny: O(n_slots)).
-    user_names, slot_to_uidx = np.unique(users, return_inverse=True)
-    ts_names, slot_to_tidx = np.unique(tss, return_inverse=True)
-    slot_to_uidx = slot_to_uidx.astype(np.int32)
-    slot_to_tidx = slot_to_tidx.astype(np.int32)
-    for lvl in levels:
-        lvl["user_idx"] = slot_to_uidx[lvl["slot"]]
-        lvl["timespan_idx"] = slot_to_tidx[lvl["slot"]]
-        lvl["user_names"] = user_names
-        lvl["timespan_names"] = ts_names
-        lvl["coarse_zoom"] = lvl["zoom"] - config.result_delta
-        lvl["coarse_row"] = lvl["row"] >> config.result_delta
-        lvl["coarse_col"] = lvl["col"] >> config.result_delta
+        with trace.span("egress.amplify"):
+            _patch_amplified(levels, slot_names)
+    # Dictionary encoding, with the coarse tile coordinates.
+    with trace.span("egress.dictionary"):
+        n_slots = max(slot_names) + 1
+        users = np.array([slot_names.get(s, ("?", "?"))[0]
+                          for s in range(n_slots)])
+        tss = np.array([slot_names.get(s, ("?", "?"))[1]
+                        for s in range(n_slots)])
+        # Unique name tables + per-slot index maps (tiny: O(n_slots)).
+        user_names, slot_to_uidx = np.unique(users, return_inverse=True)
+        ts_names, slot_to_tidx = np.unique(tss, return_inverse=True)
+        slot_to_uidx = slot_to_uidx.astype(np.int32)
+        slot_to_tidx = slot_to_tidx.astype(np.int32)
+        for lvl in levels:
+            lvl["user_idx"] = slot_to_uidx[lvl["slot"]]
+            lvl["timespan_idx"] = slot_to_tidx[lvl["slot"]]
+            lvl["user_names"] = user_names
+            lvl["timespan_names"] = ts_names
+            lvl["coarse_zoom"] = lvl["zoom"] - config.result_delta
+            lvl["coarse_row"] = lvl["row"] >> config.result_delta
+            lvl["coarse_col"] = lvl["col"] >> config.result_delta
     return levels
 
 

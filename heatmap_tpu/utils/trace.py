@@ -15,7 +15,10 @@ greenfield replacement:
 
 Spans measure *host* wall-clock. For device work inside a span, call
 ``block_until_ready`` on the result before the span closes, or the
-span records only dispatch time (XLA is async).
+span records only dispatch time (XLA is async). Device work inside the
+one jitted cascade is named by ``jax.named_scope`` instead
+(pipeline/cascade.py, ops/pyramid.py): the scope path rides on each
+compiled operation into a profiler trace.
 
 Closed spans also feed the telemetry subsystem (heatmap_tpu/obs): a
 ``stage_duration_seconds`` histogram sample plus a ``stage_end`` event —
@@ -33,10 +36,14 @@ _obs = None  # lazily imported so importing trace never pulls in obs/jax
 
 # Span-tree hooks, installed by obs.tracing.enable_tracing (and removed
 # by disable_tracing). While set, every default-tracer span also opens a
-# node in the hierarchical trace (obs/tracing.py) — the aggregate API
-# here is unchanged, and with tracing off the cost is one global read.
+# node in the hierarchical trace (obs/tracing.py) and enters
+# ``_annotation(name)`` (jax.profiler.TraceAnnotation), so the span sits
+# on the host plane of any profiler trace taken meanwhile. The aggregate
+# API here is unchanged, and with tracing off the cost is one global
+# read.
 _tree_begin = None
 _tree_end = None
+_annotation = None
 
 
 def _obs_record(name: str, wall_s: float, items, attrs: dict):
@@ -79,13 +86,20 @@ class Tracer:
         """Extra keyword attrs (e.g. ``backend="partitioned"``) ride
         along on the stage_end event when an event log is installed."""
         begin = _tree_begin
-        tree_span = (begin(name, attrs or None)
-                     if begin is not None and self is _default else None)
+        tree_span = annotation = None
+        if begin is not None and self is _default:
+            tree_span = begin(name, attrs or None)
+            annotate = _annotation
+            if annotate is not None:
+                annotation = annotate(name)
+                annotation.__enter__()
         t0 = time.perf_counter()
         try:
             yield self
         finally:
             dt = time.perf_counter() - t0
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
             with self._lock:
                 s = self._stat(name)
                 s.count += 1
@@ -153,51 +167,6 @@ def get_tracer() -> Tracer:
 def span(name: str, items: int | None = None, **attrs):
     """Span on the default tracer: ``with span("binning", items=n): ...``"""
     return _default.span(name, items=items, **attrs)
-
-
-# -- per-stage cascade attribution (opt-in diagnostic) ---------------------
-#
-# The production cascade runs under ONE jit (pipeline/cascade.py
-# _build_cascade_jit), so host spans inside it would time tracing, not
-# execution. Stage tracing is a global opt-in (bench_job --trace-stages)
-# that (a) makes the pipeline run the cascade EAGERLY and (b) turns the
-# stage_span/stage_block call sites inside the kernels into real
-# blocked measurements (sort / segment-reduce / decode / host egress).
-# Off (the default) both helpers are free: a nullcontext and identity.
-
-_stage_tracing = False
-
-
-def enable_stage_tracing(on: bool = True):
-    global _stage_tracing
-    _stage_tracing = on
-
-
-def stage_tracing_enabled() -> bool:
-    return _stage_tracing
-
-
-def stage_span(name: str, items: int | None = None, **attrs):
-    """A tracer span only under stage tracing; nullcontext otherwise
-    (kernels call this on hot paths — it must cost nothing when off)."""
-    if not _stage_tracing:
-        return contextlib.nullcontext()
-    return _default.span(name, items=items, **attrs)
-
-
-def stage_block(x):
-    """block_until_ready under stage tracing (a span closing on an
-    unblocked async dispatch records ~0), identity otherwise. Safe on
-    tracers: if the value cannot block (a traced caller slipped
-    through), it is returned unchanged."""
-    if not _stage_tracing:
-        return x
-    try:
-        import jax
-
-        return jax.block_until_ready(x)
-    except Exception:  # noqa: BLE001 — tracing/abstract values
-        return x
 
 
 @contextlib.contextmanager

@@ -51,9 +51,9 @@ def devices():
 def _reset_telemetry():
     """Isolate process-wide telemetry state between tests: the default
     tracer, the metrics registry (values + enabled flag), the installed
-    event log, and the stage-tracing global — so a test that flips
-    ``enable_stage_tracing(True)`` (or enables metrics) cannot leak
-    instrumentation cost or state into later hot-path tests."""
+    event log and span tracing — so a test that enables any of them
+    cannot leak instrumentation cost or state into later hot-path
+    tests."""
     yield
     from heatmap_tpu import faults, obs
     from heatmap_tpu.delta import recover
@@ -62,7 +62,6 @@ def _reset_telemetry():
     from heatmap_tpu.utils import trace
 
     trace.get_tracer().reset()
-    trace.enable_stage_tracing(False)
     obs.enable_metrics(False)
     obs.get_registry().reset()
     log = obs.get_event_log()

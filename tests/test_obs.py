@@ -438,6 +438,30 @@ class TestRunTelemetry:
         assert "# TYPE stage_duration_seconds histogram" in prom
         assert 'source_rows_read_total{source="synthetic"} 2000' in prom
 
+    def test_blobs_identical_with_compile_spans(self, tmp_path, capsys):
+        """A traced run that compiles every program (caches cleared)
+        records the compiles as ``jax.compile`` spans and writes the
+        same bytes as an untraced run."""
+        import jax
+
+        from heatmap_tpu.cli import cmd_run
+
+        out_off = tmp_path / "off.jsonl"
+        assert cmd_run(_run_args(["--output", f"jsonl:{out_off}"])) == 0
+        jax.clear_caches()
+        out_traced = tmp_path / "traced.jsonl"
+        trace_out = tmp_path / "trace.json"
+        assert cmd_run(_run_args(["--output", f"jsonl:{out_traced}",
+                                  "--trace-out", str(trace_out)])) == 0
+        capsys.readouterr()
+        assert out_traced.read_bytes() == out_off.read_bytes()
+        compiles = [e for e in json.loads(trace_out.read_text())["traceEvents"]
+                    if e.get("name") == "jax.compile"]
+        assert {e["args"]["phase"] for e in compiles} == {
+            "trace", "lower", "backend"}
+        assert any(e["args"]["fun_name"] == "jit(build_cascade)"
+                   for e in compiles)
+
     def test_report_flag_prints_table_without_profile(self, tmp_path,
                                                       capsys):
         """Satellite: the span/throughput report under --report alone

@@ -362,3 +362,140 @@ class TestDeltaApplyTraceAndAnalysis:
             s["name"] for s in spans}
         # the formatted report renders without error
         assert "critical path" in trace_analyze.format_report(result)
+
+
+def _fresh_jit():
+    """A function JAX has never compiled in this process."""
+    import jax
+    import jax.numpy as jnp
+
+    salt = float(np.random.default_rng().random())
+
+    @jax.jit
+    def fresh_program(x):
+        return jnp.sin(x) * salt + 1.0
+
+    return fresh_program, jnp.arange(8.0)
+
+
+class TestCompileSpans:
+    def test_compile_phases_become_spans_under_the_ambient_span(self):
+        from heatmap_tpu.utils.trace import span
+
+        obs.enable_metrics(True)
+        collector = tracing.enable_tracing()
+        fn, x = _fresh_jit()
+        with span("unit.compiles"):
+            np.asarray(fn(x))
+        records = collector.spans()
+        [outer] = [r for r in records if r["name"] == "unit.compiles"]
+        compiles = [r for r in records if r["name"] == tracing.COMPILE_SPAN
+                    and "fresh_program" in r["attrs"]["fun_name"]]
+        assert {r["attrs"]["phase"] for r in compiles} >= {
+            "trace", "lower", "backend"}
+        for r in compiles:
+            assert r["parent_id"] == outer["span_id"]
+            assert r["trace_id"] == outer["trace_id"]
+            assert outer["start_s"] - 1e-3 <= r["start_s"]
+            assert (r["start_s"] + r["dur_s"]
+                    <= outer["start_s"] + outer["dur_s"] + 1e-3)
+        [backend] = [r for r in compiles if r["attrs"]["phase"] == "backend"]
+        assert "cache_hit" not in backend["attrs"]
+        assert obs.JAX_COMPILES.value(fun_name="jit(fresh_program)") == 1
+
+    def test_persistent_cache_load_is_a_hit_not_a_compile(self, tmp_path):
+        import jax
+        from jax._src import compilation_cache
+
+        options = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
+                   "jax_persistent_cache_min_compile_time_secs",
+                   "jax_persistent_cache_min_entry_size_bytes")
+        saved = {o: getattr(jax.config, o) for o in options}
+        try:
+            for option, value in zip(options, (True, str(tmp_path), 0, 0)):
+                jax.config.update(option, value)
+            compilation_cache.reset_cache()
+            obs.enable_metrics(True)
+            collector = tracing.enable_tracing()
+            fn, x = _fresh_jit()
+            np.asarray(fn(x))
+            jax.clear_caches()
+            np.asarray(fn(x))
+        finally:
+            for option, value in saved.items():
+                jax.config.update(option, value)
+            compilation_cache.reset_cache()
+        backends = [r["attrs"] for r in collector.spans()
+                    if r["attrs"].get("fun_name") == "jit(fresh_program)"
+                    and r["attrs"]["phase"] == "backend"]
+        assert [b.get("cache_hit", False) for b in backends] == [False, True]
+        assert obs.JAX_COMPILES.value(fun_name="jit(fresh_program)") == 1
+        assert obs.JAX_COMPILE_CACHE_HITS.value() >= 1
+
+    def test_tracing_off_records_nothing(self):
+        obs.enable_metrics(True)
+        fn, x = _fresh_jit()
+        collector = tracing.enable_tracing()
+        tracing.disable_tracing()
+        np.asarray(fn(x))
+        assert collector.spans() == []
+        assert obs.JAX_COMPILES.samples() == {}
+        assert obs.JAX_COMPILE_CACHE_HITS.samples() == {}
+
+
+class TestProfilerTimeline:
+    def test_program_spans_sit_on_the_host_plane(self, tmp_path):
+        import glob
+
+        import jax
+
+        from heatmap_tpu.utils.trace import span
+
+        tracing.enable_tracing()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with span("unit.outer"):
+                with span("unit.inner"):
+                    pass
+        finally:
+            jax.profiler.stop_trace()
+        [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                           recursive=True)
+        data = jax.profiler.ProfileData.from_file(path)
+        host = {e.name: (e.start_ns, e.start_ns + e.duration_ns)
+                for plane in data.planes if plane.name.startswith("/host:")
+                for line in plane.lines for e in line.events}
+        assert {"unit.outer", "unit.inner"} <= set(host)
+        (os_, oe), (is_, ie) = host["unit.outer"], host["unit.inner"]
+        assert os_ <= is_ <= ie <= oe
+
+    def test_no_annotation_while_tracing_is_off(self, tmp_path):
+        import glob
+
+        import jax
+
+        from heatmap_tpu.utils.trace import span
+
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with span("unit.untraced"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                           recursive=True)
+        data = jax.profiler.ProfileData.from_file(path)
+        assert not any(e.name == "unit.untraced" for plane in data.planes
+                       for line in plane.lines for e in line.events)
+
+
+class TestDispatchTimer:
+    def test_enabled_under_span_tracing_alone(self):
+        assert not obs.telemetry_enabled()
+        assert not obs.DispatchTimer("single").enabled
+        tracing.enable_tracing()
+        timer = obs.DispatchTimer("single")
+        assert timer.enabled
+        timer.dispatched()
+        host_s, device_s = timer.finished()
+        assert host_s >= 0 and device_s >= 0

@@ -13,11 +13,9 @@ process at a time).
 
     PYTHONPATH=. python tools/bench_job.py [--n 16777216]
         [--egress arrays|json|none] [--runs 1] [--cascade-backend both]
-        [--trace-stages]
 
 ``--cascade-backend both`` measures each backend on the same input
-file. ``--trace-stages`` adds sort / segment-reduce attribution to the
-stage report (runs the cascade eagerly — see utils/trace.py).
+file.
 """
 
 from __future__ import annotations
@@ -61,13 +59,11 @@ def measure(args, hmpb: str, run: int, backend: str | None) -> None:
     from heatmap_tpu.io.sinks import LevelArraysSink, MemorySink
     from heatmap_tpu.obs import tracing
     from heatmap_tpu.pipeline import BatchJobConfig, run_job_fast
-    from heatmap_tpu.utils.trace import enable_stage_tracing, get_tracer
+    from heatmap_tpu.utils.trace import get_tracer
 
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
     import trace_analyze
 
-    if args.trace_stages:
-        enable_stage_tracing(True)
     # Metrics ride along on every measurement (counters/gauges only —
     # no event log, so no per-span I/O in the timed region); the folded
     # run report lands in the bench record below.
@@ -422,10 +418,6 @@ def main() -> int:
                     choices=("scatter", "partitioned", "both"),
                     help="cascade reduction backend; 'both' measures each "
                     "backend on the same input file")
-    ap.add_argument("--trace-stages", action="store_true",
-                    help="per-stage cascade attribution (sort / "
-                    "segment-reduce / decode / host egress) in the "
-                    "stage report; runs the cascade eagerly")
     ap.add_argument("--partition-sweep", nargs="?",
                     const="BENCH_partition.json", default=None,
                     metavar="OUT.json",
