@@ -28,9 +28,9 @@ The whole run must finish well inside 20 minutes with nothing compiled
 beforehand. Two measured costs on a v5e shaped it: a full-size chunked
 run (``--max-points-in-flight 4194304``) took 645 s (its cross-chunk
 host merge and its own compile), so the chunked path is checked on the
-oracle slice instead; and the partitioned kernel pads every call to its
-2^24-element slab, so a four-chunk oracle slice on it took 475 s, which
-is why the slice runs on the scatter cascade.
+oracle slice instead; and the partitioned kernel then padded every call
+to its 2^24-element slab, so a four-chunk oracle slice on it took 475 s,
+which is why the slice runs on the scatter cascade.
 
 ``--chips 4`` runs only the four-chip phase: ``run_job`` on 2^20
 points of the same source, on the scatter cascade, with

@@ -162,7 +162,10 @@ def pyramid_sparse_morton_partitioned(
     int64-max sentinel padding, per-level capacities), but every level
     is reduced from the ORIGINAL sorted stream shifted by ``2*level``
     — one sort, then ``levels+1`` kernel passes that replace the 2
-    scatters per level (ops/sparse_partitioned.py rationale). Counts
+    scatters per level (ops/sparse_partitioned.py rationale). Every
+    pass reads the same ``n``-lane stream, padded to whole chunk rows:
+    ``slab`` caps the lanes one f32 accumulation sees, it is not a
+    size every pass pads to. Counts
     (``weights=None``, int32 sums) or bounded-integer weights
     (``weights`` + static ``weight_bound``: integers in
     [0, weight_bound], f64 sums, exactness via the shrunk slab;

@@ -13,7 +13,17 @@ are already sorted:
 - counts stay exact at any fan-in by processing the stream in SLABS
   of at most 2^24 elements: per-slab f32 accumulation cannot round
   (every partial sum is an integer < 2^24), and slabs combine in f64
-  on the way out;
+  on the way out. The work follows the input: a stream pads only to
+  whole chunk rows, so its last (often only) slab is as short as what
+  remains of it;
+- every chunk of a slab goes to exactly one place. A chunk whose
+  in-range lanes (cell < capacity) sit inside one output block rides
+  the kernel, which masks its drop lanes lane by lane; a chunk whose
+  in-range lanes straddle a block boundary goes to a float64 scatter
+  tail; a chunk with no in-range lane adds nothing. Cell ids are
+  non-decreasing along a sorted stream (drops sort last), so at most
+  ``n_blocks - 1`` chunks of a slab straddle, and that bound sizes the
+  tail statically — no data-dependent fallback exists;
 - the unique key of a segment is reconstructed through three extra
   matmul CHANNELS: the segment's FIRST element contributes its key
   split into 20-bit pieces (each < 2^20, exactly one contribution per
@@ -39,7 +49,8 @@ composite keys do by the int64 packing check in
 pipeline/cascade.composite_keys).
 
 Interpret mode is pinned bit-equal to aggregate_sorted_keys, including
-the multi-slab and fallback paths (tests/test_sparse_partitioned.py);
+the multi-slab combine and the straddle tail, and the tail's bound is
+property-tested (tests/test_sparse_partitioned.py);
 the compiled kernel is pinned by tests/test_lowering.py and compared
 with the scatter cascade on the chip by ``chip_smoke.py``.
 BatchJobConfig's "auto" cascade backend routes count jobs on TPU here.
@@ -71,7 +82,7 @@ N_CHANNELS_WEIGHTED = 5  # weighted sums + presence + 3 key pieces
 
 def _segment_kernel(base_ref, good_ref, first_v_ref, last_v_ref,
                     s_ref, w_ref, zeros_ref, out_ref, acc_ref, *,
-                    chunk, block_cells, side, n_blocks,
+                    chunk, block_cells, side, n_blocks, capacity,
                     n_channels=N_CHANNELS):
     """Multi-channel twin of partitioned._partition_kernel: one shared
     one-hot pair per chunk, ``n_channels`` weighted matmuls into a
@@ -87,10 +98,13 @@ def _segment_kernel(base_ref, good_ref, first_v_ref, last_v_ref,
     # (streams=1 makes this the plain block id); the cell offset
     # depends only on the block part — same scheme as the window
     # kernel (ops/partitioned.py).
+    cells = s_ref[0, 0, :]
     rloc, cloc = masked_local_rc(
-        base_ref[i] % jnp.int32(n_blocks), good_ref[i], s_ref[0, 0, :],
+        base_ref[i] % jnp.int32(n_blocks), good_ref[i], cells,
         block_cells, side,
     )
+    # Drop lanes (sentinel padding, segments past capacity) match no row.
+    rloc = jnp.where(cells < jnp.int32(capacity), rloc, jnp.int32(-1))
 
     r_ids = lax.broadcasted_iota(jnp.int32, (side, chunk), 0)
     c_ids = lax.broadcasted_iota(jnp.int32, (chunk, side), 1)
@@ -113,21 +127,61 @@ def _segment_kernel(base_ref, good_ref, first_v_ref, last_v_ref,
         out_ref[:] = acc_ref[:]
 
 
-def _good_of(cells, chunk, block_cells, capacity):
-    """Per-chunk goodness: fully inside one aligned block AND free of
-    dropped lanes (cell id == capacity)."""
+def _chunk_kinds(cells, chunk, block_cells, capacity):
+    """Per-chunk (kernel, straddle) masks of one slab of cell ids.
+
+    Cell ids run non-decreasing along a slab: dense segment ids, then
+    the drop id ``capacity`` for sentinel lanes (segments past
+    capacity keep ids >= capacity). With every id clamped into the last
+    block, a chunk's first and last lane therefore bound the blocks its
+    in-range lanes touch. A chunk straddles when they differ (the one
+    chunk where in-range lanes end may count so through the clamp);
+    it rides the kernel when they agree and its first lane is in range;
+    a chunk whose first lane is already out of range holds no in-range
+    lane and is neither. The clamped block ids climb from 0 to at most
+    ``n_blocks - 1`` over the slab, and every straddling chunk climbs
+    at least one, so at most ``n_blocks - 1`` chunks straddle
+    (_tail_chunks)."""
     first = cells[::chunk]
-    last = cells[chunk - 1 :: chunk]
-    return (first // block_cells == last // block_cells) & (last < capacity)
+    top = capacity - 1
+    lo = jnp.minimum(first, top) // block_cells
+    hi = jnp.minimum(cells[chunk - 1 :: chunk], top) // block_cells
+    straddle = lo != hi
+    return (first < capacity) & ~straddle, straddle
+
+
+def _tail_chunks(n_chunks, capacity, block_cells):
+    """Static size of a slab's straddle tail: the most chunks that can
+    straddle a block boundary (_chunk_kinds)."""
+    return min(n_chunks, -(-capacity // block_cells) - 1)
+
+
+def _tail_path(cells, chans, straddle, capacity, chunk, size, n_channels):
+    """The straddling chunks of a slab, scattered in f64 (exact: every
+    channel is integer-valued below 2^52). ``size`` is the static
+    bound on their number; drop lanes fall out through mode="drop"."""
+    nck = cells.shape[0] // chunk
+    idx = jnp.nonzero(straddle, size=size, fill_value=nck)[0]
+    tail_cells = jnp.take(cells.reshape(nck, chunk), idx, axis=0,
+                          mode="fill", fill_value=capacity).reshape(-1)
+    tails = []
+    for ch in range(n_channels):
+        w = jnp.take(chans[ch].reshape(nck, chunk), idx, axis=0,
+                     mode="fill", fill_value=0.0).reshape(-1)
+        tails.append(
+            jnp.zeros(capacity, jnp.float64)
+            .at[tail_cells]
+            .add(w.astype(jnp.float64), mode="drop")
+        )
+    return jnp.stack(tails)
 
 
 def _channel_path(cells, chans, good, capacity, n_blocks, chunk,
-                  bad_cap_chunks, interpret, block_cells, side,
-                  streams=1, n_channels=N_CHANNELS):
-    """Good chunks -> multi-channel pallas blocks; bad chunks ->
-    bounded f64 scatter tails (exact: every channel is integer-valued
-    below 2^52). ``good`` is the caller's per-chunk mask — the same
-    one that sized the bounded tail.
+                  interpret, block_cells, side, streams=1,
+                  n_channels=N_CHANNELS):
+    """Kernel chunks (``good``, from _chunk_kinds) -> multi-channel
+    pallas blocks, as f64 (n_channels, capacity) sums. Every other
+    chunk is visited with all lanes masked.
 
     ``streams`` splits the (globally sorted) slab into that many
     contiguous sub-streams, each accumulating into its own slab of
@@ -135,16 +189,16 @@ def _channel_path(cells, chans, good, capacity, n_blocks, chunk,
     as the window kernel's streams default. Identical math: counts and
     key-piece channels are linear, every segment's FIRST element lands in
     exactly one sub-stream, and chunk boundaries are unchanged
-    (sub-streams are whole runs of chunks), so the bad-chunk tail is
+    (sub-streams are whole runs of chunks), so the straddle tail is
     untouched. The sub-slab sums stay f32-exact: each slab holds at
     most 2^24 elements total, so every per-cell partial and the
     cross-stream integer sum are <= 2^24."""
     L = cells.shape[0]
     nck = L // chunk
-    # Forward-fill bad chunks with the last good block id per
-    # sub-stream (each sub-stream is a contiguous slice of the sorted
-    # slab, so good block ids are non-decreasing within it); leading
-    # bads clamp to block 0, fully masked.
+    # Forward-fill the other chunks with the last kernel chunk's block
+    # id per sub-stream (each sub-stream is a contiguous slice of the
+    # sorted slab, so those ids are non-decreasing within it); leading
+    # ones clamp to block 0, fully masked.
     first2 = cells.reshape(streams, L // streams)[:, ::chunk]
     good2 = good.reshape(streams, nck // streams)
     base2 = jnp.maximum(
@@ -197,7 +251,8 @@ def _channel_path(cells, chans, good, capacity, n_blocks, chunk,
         return pl.pallas_call(
             functools.partial(_segment_kernel, chunk=chunk,
                               block_cells=block_cells, side=side,
-                              n_blocks=n_blocks, n_channels=n_channels),
+                              n_blocks=n_blocks, capacity=capacity,
+                              n_channels=n_channels),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(
                 (streams * n_blocks, n_channels, side, side), jnp.float32
@@ -240,26 +295,13 @@ def _channel_path(cells, chans, good, capacity, n_blocks, chunk,
     dense = blocks.transpose(1, 0, 2, 3).reshape(
         n_channels, n_blocks * block_cells
     )[:, :capacity]
-
-    bad_idx = jnp.nonzero(~good, size=bad_cap_chunks, fill_value=nck)[0]
-    bad_cells = jnp.take(cells.reshape(nck, chunk), bad_idx, axis=0,
-                         mode="fill", fill_value=capacity).reshape(-1)
-    tails = []
-    for ch in range(n_channels):
-        bad_w = jnp.take(chans[ch].reshape(nck, chunk), bad_idx, axis=0,
-                         mode="fill", fill_value=0.0).reshape(-1)
-        tails.append(
-            jnp.zeros(capacity, jnp.float64)
-            .at[bad_cells]
-            .add(bad_w.astype(jnp.float64), mode="drop")
-        )
-    return dense.astype(jnp.float64) + jnp.stack(tails)
+    return dense.astype(jnp.float64)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("capacity", "chunk", "block_cells", "bad_frac",
-                     "slab", "interpret", "streams", "weight_bound"),
+    static_argnames=("capacity", "chunk", "block_cells", "slab",
+                     "interpret", "streams", "weight_bound"),
 )
 def aggregate_sorted_keys_partitioned(
     sorted_keys,
@@ -267,7 +309,6 @@ def aggregate_sorted_keys_partitioned(
     sentinel=None,
     chunk: int = DEFAULT_CHUNK,
     block_cells: int = DEFAULT_BLOCK_CELLS,
-    bad_frac: int = 8,
     slab: int = DEFAULT_SLAB,
     interpret: bool | None = None,
     streams: int = 1,
@@ -280,9 +321,15 @@ def aggregate_sorted_keys_partitioned(
     (unique[capacity] int64, sums[capacity], n_unique); slots past
     n_unique hold sentinel/zero; exact at ANY per-key fan-in
     (slab-wise f32 accumulation, f64 combine). With the default unit
-    weights, sums are int32 counts. ``slab`` is a parameter so tests
-    can exercise the multi-slab combine at small sizes; it must be a
-    multiple of ``streams * chunk``. ``streams`` splits each slab into
+    weights, sums are int32 counts. ``slab`` is the most lanes one
+    slab may hold (a parameter so tests can exercise the multi-slab
+    combine at small sizes); it must be a multiple of
+    ``streams * chunk``. The stream pads only to a whole number of
+    ``streams * chunk`` lanes, so every slab but the last holds
+    ``slab`` lanes and the last holds what remains. Each slab's
+    straddling chunks go to a scatter tail of at most
+    ``ceil(capacity / block_cells) - 1`` chunks, a bound the sorted
+    stream guarantees (_chunk_kinds). ``streams`` splits each slab into
     contiguous sub-streams with per-stream output slabs (see
     _channel_path; bit-identical results, measured for grid pipelining
     on-chip before any default flips — costs ``streams`` x the
@@ -389,9 +436,10 @@ def aggregate_sorted_keys_partitioned(
         chans = jnp.stack([is_real.astype(jnp.float32)] + pieces)
         n_channels = N_CHANNELS
 
-    # Pad to whole slabs of whole chunks.
-    n_slabs = max(1, -(-max(n, 1) // slab))
-    n_pad = n_slabs * slab
+    # Pad to whole chunk rows only: the last slab is as long as what
+    # remains of the stream, not a whole ``slab``.
+    unit = streams * chunk
+    n_pad = max(unit, -(-n // unit) * unit)
     if n_pad != n:
         cells = jnp.concatenate(
             [cells, jnp.full(n_pad - n, capacity, cells.dtype)]
@@ -402,42 +450,21 @@ def aggregate_sorted_keys_partitioned(
 
     n_blocks = -(-capacity // block_cells)
     sums = jnp.zeros((n_channels, capacity), jnp.float64)
-    for s in range(n_slabs):  # static unroll: ~n/2^24 iterations
-        c_slab = cells[s * slab : (s + 1) * slab]
-        ch_slab = chans[:, s * slab : (s + 1) * slab]
-        nck = slab // chunk
-        bad_cap = max(2, nck // bad_frac)
-        good_slab = _good_of(c_slab, chunk, block_cells, capacity)
-        n_bad = (~good_slab).sum()
-
-        # Each branch opens its own named scope, so a device trace
-        # tells the kernel's slab from the full scatter fallback.
-        def channel(c_, ch_, g_):
-            with jax.named_scope("slab.channel"):
-                return _channel_path(
-                    c_, ch_, g_, capacity, n_blocks, chunk, bad_cap,
-                    interpret, block_cells, side, streams=streams,
-                    n_channels=n_channels,
-                )
-
-        def scatter_all(c_, ch_, g_):
-            with jax.named_scope("slab.fallback"):
-                return jnp.stack([
-                    jnp.zeros(capacity, jnp.float64)
-                    .at[c_]
-                    .add(ch_[ch].astype(jnp.float64), mode="drop")
-                    for ch in range(n_channels)
-                ])
-
-        slab_sums = lax.cond(
-            n_bad <= bad_cap,
-            channel,
-            scatter_all,
-            c_slab,
-            ch_slab,
-            good_slab,
-        )
-        sums = sums + slab_sums
+    for start in range(0, n_pad, slab):  # static unroll: ~n/2^24 slabs
+        c_slab = cells[start : start + slab]
+        ch_slab = chans[:, start : start + slab]
+        good, straddle = _chunk_kinds(c_slab, chunk, block_cells, capacity)
+        with jax.named_scope("slab.channel"):
+            sums = sums + _channel_path(
+                c_slab, ch_slab, good, capacity, n_blocks, chunk,
+                interpret, block_cells, side, streams=streams,
+                n_channels=n_channels,
+            )
+        size = _tail_chunks(straddle.shape[0], capacity, block_cells)
+        if size:  # one block straddles nothing
+            with jax.named_scope("slab.tail"):
+                sums = sums + _tail_path(c_slab, ch_slab, straddle,
+                                         capacity, chunk, size, n_channels)
 
     pc = 1 if weighted else 0  # presence channel index
     present = jnp.round(sums[pc]) > 0

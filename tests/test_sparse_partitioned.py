@@ -1,12 +1,18 @@
 """Multi-channel partitioned segment reduction (ops.sparse_partitioned),
 interpret mode: bit-equal to ops.sparse.aggregate_sorted_keys on every
-path — good-chunk matmuls, bounded bad tails, the full-scatter
-fallback, and the multi-slab exactness combine."""
+path — kernel-chunk matmuls, the bounded straddle tail, and the
+multi-slab exactness combine — with the tail's static bound held on
+random sorted streams."""
 
+import functools
+
+import jax
+import jax.extend.core as jex
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
+from heatmap_tpu.ops import sparse_partitioned as sp
 from heatmap_tpu.ops.sparse import aggregate_sorted_keys
 from heatmap_tpu.ops.sparse_partitioned import (
     aggregate_sorted_keys_partitioned,
@@ -36,7 +42,6 @@ def _diff(sorted_keys, capacity, **kw):
     return int(want_n)
 
 
-@pytest.mark.slow
 def test_clustered_runs_good_chunks():
     """Long runs (few segments per chunk) take the matmul path."""
     rng = np.random.default_rng(0)
@@ -45,7 +50,6 @@ def test_clustered_runs_good_chunks():
     assert _diff(keys, capacity=1 << 12) == 40
 
 
-@pytest.mark.slow
 def test_mostly_unique_keys():
     """Run length ~1: every chunk spans many segments, but segments are
     dense so chunks still land inside blocks."""
@@ -54,7 +58,6 @@ def test_mostly_unique_keys():
     _diff(keys, capacity=30_000)
 
 
-@pytest.mark.slow
 def test_sentinel_padding_and_drop():
     rng = np.random.default_rng(2)
     keys = np.concatenate([
@@ -64,7 +67,6 @@ def test_sentinel_padding_and_drop():
     _diff(keys, capacity=8192)
 
 
-@pytest.mark.slow
 def test_multi_slab_combine_exact():
     """slab smaller than the stream: per-slab partials must combine to
     the global counts, including segments straddling slab boundaries
@@ -76,7 +78,6 @@ def test_multi_slab_combine_exact():
     assert n == 13
 
 
-@pytest.mark.slow
 def test_single_hot_key_fanin_beyond_slab():
     """One segment larger than several slabs: counts must stay exact
     (the f32-per-slab / f64-combine design point)."""
@@ -89,7 +90,6 @@ def test_single_hot_key_fanin_beyond_slab():
     assert int(got_u[0]) == 123456789
 
 
-@pytest.mark.slow
 def test_58_bit_keys_reconstruct():
     """Cascade-scale composite keys (58 bits) round-trip through the
     three 20-bit channels."""
@@ -98,26 +98,127 @@ def test_58_bit_keys_reconstruct():
     _diff(keys, capacity=4096)
 
 
-@pytest.mark.slow
-def test_hostile_distribution_falls_back():
-    """capacity-spanning sparse segments make most chunks straddle
-    blocks -> the lax.cond scatter fallback must match too."""
+def test_hostile_distribution_through_bounded_tail():
+    """Capacity-spanning sparse segments with a tiny block size: the
+    input that once forced the full-scatter fallback is bit-equal, its
+    last real lanes riding the bounded tail (their chunk ends in drop
+    lanes, whose ids clamp into the last of 64 blocks)."""
     rng = np.random.default_rng(5)
-    # Unique keys + big capacity: segments land far apart in cell space
-    # relative to block_cells, so chunks straddle constantly with a
-    # tiny block size.
     keys = rng.choice(1 << 45, 20_000, replace=False)
     _diff(keys, capacity=1 << 18, block_cells=1 << 12)
+    assert _straddles(keys, 1 << 18, 1 << 12) == [1]
 
 
-@pytest.mark.slow
+def _straddles(keys, capacity, block_cells, chunk=sp.DEFAULT_CHUNK,
+               slab=sp.DEFAULT_SLAB, streams=1):
+    """Straddling chunks per slab, as the kernel lays the stream out
+    (dense segment ids, drop lanes at ``capacity``, padded to whole
+    ``streams * chunk`` rows), each checked against the static tail."""
+    k = np.sort(np.asarray(keys, np.int64))
+    real = k != SENTINEL
+    first = np.concatenate([[True], k[1:] != k[:-1]])[: k.size] & real
+    cells = np.where(real, np.cumsum(first) - 1, capacity)
+    unit = streams * chunk
+    n_pad = max(unit, -(-k.size // unit) * unit)
+    cells = np.concatenate([cells, np.full(n_pad - k.size, capacity)])
+    out = []
+    for start in range(0, n_pad, slab):
+        part = jnp.asarray(cells[start : start + slab], jnp.int32)
+        good, straddle = sp._chunk_kinds(part, chunk, block_cells, capacity)
+        got = int(straddle.sum())
+        assert got <= sp._tail_chunks(straddle.shape[0], capacity,
+                                      block_cells)
+        assert not bool((good & straddle).any())
+        out.append(got)
+    return out
+
+
+def _sorted_stream(seed, n, n_unique, n_sentinel):
+    """Sorted keys with random run lengths (segments start mid-chunk)
+    and a sentinel tail."""
+    rng = np.random.default_rng(seed)
+    uniq = rng.choice(1 << 50, n_unique, replace=False)
+    keys = uniq[rng.integers(0, n_unique, n - n_sentinel)]
+    return np.concatenate([keys, np.full(n_sentinel, SENTINEL)])
+
+
+@pytest.mark.parametrize(
+    "n,n_unique,n_sentinel,capacity,streams,slab,overflow", [
+        (20_000, 9_000, 3_000, 9_000, 1, sp.DEFAULT_SLAB, False),
+        (20_000, 15_000, 500, 9_000, 1, sp.DEFAULT_SLAB, True),
+        (30_000, 20_000, 1_000, 20_000, 2, sp.DEFAULT_SLAB, False),
+        (30_000, 20_000, 1_000, 20_000, 4, sp.DEFAULT_SLAB, False),
+        (25_000, 16_000, 2_000, 16_384, 1, 4_096, False),
+        (25_000, 16_000, 2_000, 10_000, 2, 2_048, True),
+    ])
+def test_straddle_tail_bound_holds(n, n_unique, n_sentinel, capacity,
+                                   streams, slab, overflow):
+    """Random sorted streams at the smallest block, with sentinel tails,
+    several slabs or streams, and more segments than capacity: the
+    straddling chunks of every slab fit the static tail, and the result
+    is bit-equal to the scatter reduction."""
+    keys = _sorted_stream(n + capacity + streams, n, n_unique, n_sentinel)
+    block_cells, chunk = 1 << 12, 128
+    per_slab = _straddles(keys, capacity, block_cells, chunk=chunk,
+                          slab=slab, streams=streams)
+    assert len(per_slab) == -(-n // slab)
+    assert sum(per_slab) > 0  # the tail carries data in every case
+    n_segments = _diff(keys, capacity, chunk=chunk, block_cells=block_cells,
+                       slab=slab, streams=streams)
+    assert (n_segments > capacity) == overflow
+
+
+def test_straddle_tail_full_to_its_bound():
+    """One repeated first key shifts every block boundary off the chunk
+    grid: every boundary straddles, the tail is exactly full, and the
+    result is still exact."""
+    capacity, block_cells, chunk = 5 * 4096, 4096, 128
+    keys = np.concatenate([[0], np.arange(capacity)])
+    assert _straddles(keys, capacity, block_cells, chunk=chunk) == [
+        sp._tail_chunks(-(-keys.size // chunk), capacity, block_cells)
+    ] == [4]
+    _diff(keys, capacity, chunk=chunk, block_cells=block_cells)
+
+
+def _primitives(jaxpr):
+    """Primitive names of a jaxpr and its sub-jaxprs, Pallas kernel
+    bodies excepted (their ``pl.when`` steps are the grid's own)."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jex.ClosedJaxpr):
+                    yield from _primitives(sub.jaxpr)
+                elif isinstance(sub, jex.Jaxpr):
+                    yield from _primitives(sub)
+
+
+@pytest.mark.parametrize("weighted", [False, True])
+def test_no_data_dependent_branch(weighted):
+    """The work is fixed by static shapes: no ``cond`` picks a path."""
+    keys = jnp.asarray(np.sort(np.random.default_rng(8).integers(
+        0, 1 << 40, 5000)))
+    f = functools.partial(aggregate_sorted_keys_partitioned,
+                          capacity=20_000, block_cells=1 << 12, chunk=128,
+                          interpret=True)
+    if weighted:
+        jaxpr = jax.make_jaxpr(lambda k, w: f(
+            k, sorted_weights=w, weight_bound=8))(keys, jnp.ones(5000))
+    else:
+        jaxpr = jax.make_jaxpr(f)(keys)
+    names = set(_primitives(jaxpr.jaxpr))
+    assert "pallas_call" in names and "scatter-add" in names
+    assert "cond" not in names
+
+
 def test_empty_and_tiny():
     _diff(np.empty(0, np.int64), capacity=64)
     _diff(np.asarray([7]), capacity=64)
     _diff(np.asarray([7, 7, 8]), capacity=64)
 
 
-@pytest.mark.slow
 def test_pyramid_partitioned_matches_scatter_pyramid():
     """The full count pyramid: kernel variant == scatter variant at
     every level, including invalid lanes and per-level capacities."""
@@ -179,7 +280,6 @@ def _diff_weighted(keys, weights, capacity, weight_bound, **kw):
     assert (np.asarray(got_s)[n:] == 0).all()
 
 
-@pytest.mark.slow
 def test_weighted_integer_bit_exact():
     """Clustered integer weights: bit-equal to the f64 scatter path."""
     rng = np.random.default_rng(11)
@@ -189,7 +289,6 @@ def test_weighted_integer_bit_exact():
     _diff_weighted(keys, w, capacity=1 << 12, weight_bound=1000)
 
 
-@pytest.mark.slow
 def test_weighted_zero_sum_segment_survives():
     """A segment whose weights all sum to zero must keep its key (the
     presence channel exists exactly for this)."""
@@ -198,7 +297,6 @@ def test_weighted_zero_sum_segment_survives():
     _diff_weighted(keys, w, capacity=64, weight_bound=8)
 
 
-@pytest.mark.slow
 def test_weighted_slab_shrinks_and_fanin_exact():
     """Fan-in far past the shrunk slab: per-slab integer partials
     combine exactly in f64 (weight_bound scales the slab down; force a
@@ -214,7 +312,6 @@ def test_weighted_slab_shrinks_and_fanin_exact():
     assert int(got_u[0]) == 987654321
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("bad_w", [2.5, -1.0, 2000.0])
 def test_weighted_contract_violation_is_loud(bad_w):
     """A fractional, negative, or over-bound weight poisons n_unique
@@ -256,7 +353,6 @@ def test_weighted_bound_too_large_for_exactness_refused():
     assert int(n) == 1 and float(s[0]) == 2048 * 20_000.0
 
 
-@pytest.mark.slow
 def test_pyramid_partitioned_weighted_matches_scatter():
     """The weighted pyramid: kernel variant == scatter variant at every
     level (f64 integer sums, invalid lanes, zero weights mixed in)."""
@@ -319,7 +415,6 @@ def test_matches_cascade_shift_reaggregation():
                                   np.asarray(want[1])[:nw])
 
 
-@pytest.mark.slow
 def test_streams_variant_bit_equal():
     """streams>1 (per-sub-stream output slabs, summed) must be
     bit-identical to streams=1 and to the scatter contract — the
