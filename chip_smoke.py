@@ -36,10 +36,11 @@ which is why the slice runs on the scatter cascade.
 points of the same source, on the scatter cascade, with
 ``data_parallel=True, dispatch="gspmd"`` and
 ``spatial_partition`` "morton" then "off", each byte-compared with
-``data_parallel=False`` on device 0. It also reports whether the
-cascade's inputs arrived spread over the chips and whether the cascade
-consumed (donated) its input buffers; buffer donation engages only on
-this path (a jitted gspmd cascade over a mesh), never on one chip.
+``data_parallel=False`` on device 0. It also checks that the
+cascade's inputs arrived spread over the chips, and reports whether the
+cascade consumed (donated) its input buffers; buffer donation engages
+only on this path (a jitted gspmd cascade over a mesh), never on one
+chip.
 
 Every phase prints one JSON line (wall seconds, compile seconds, peak
 device bytes, ...). The last line of standard output is the result:
@@ -66,17 +67,11 @@ import time
 import numpy as np
 
 N_POINTS = 1 << 24  # -> 2^25 cascade emissions (the point + its 'all' slot)
-#: The four-chip phase runs 2^20 points on the scatter cascade. Compiled
-#: for a v5e 2x2, the gspmd Morton program at 2^24 points needs 20.3 GB
-#: per chip (its replicate-and-mask routing holds the whole batch on
-#: every chip), more than a chip's 16 GB. Below that, the phase's time
+#: The four-chip phase runs 2^20 points on the scatter cascade: its time
 #: is compilation, which barely depends on size, while the host work at
-#: 2^20 points is seconds. At 2^20 the Morton, uniform and single-device
-#: programs compile for the described chip in 167, 132 and 53 s on
-#: scatter and 223, 92 and 78 s on the partitioned kernel; a v5e host
-#: compiled the 2^24 scatter program in 0.75x its described-chip time
-#: and the partitioned one in about 2x. The partitioned kernel's
-#: agreement with scatter is checked on one chip.
+#: 2^20 points is seconds. The Morton program at full size is the
+#: benchmark's planet cell (BENCHMARK.json), on the partitioned kernel;
+#: the kernel's agreement with scatter is checked on one chip.
 FOUR_CHIP_POINTS = 1 << 20
 FOUR_CHIP_BACKEND = "scatter"
 SEED = 7
@@ -439,12 +434,12 @@ def run_four_chips(work: str, clock: PhaseClock, n: int = FOUR_CHIP_POINTS,
                       "a data-parallel cascade ran without its mesh")
                 check(all(p["out"] == [n_devices] for p in seen),
                       f"cascade outputs not spread over the mesh: {seen}")
-                # Reported, not asserted: projection and emission
-                # assembly run on the default device, so the cascade's
-                # inputs arrive whole on device 0 and the program
-                # reshards them.
+                # run_job places the gspmd cascade's inputs in per-chip
+                # blocks before the program: no chip receives the batch.
                 out["inputs_spread"] = all(p["in"] == [n_devices]
                                            for p in seen)
+                check(out["inputs_spread"],
+                      f"cascade inputs not spread over the mesh: {seen}")
         return levels_dir
 
     def compare(partition, got, single):

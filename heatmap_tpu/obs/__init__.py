@@ -100,6 +100,22 @@ BOUNDARY_TILES = _registry.counter(
     "cascade_boundary_tiles_total",
     "Straddling parent tiles cross-merged by range-sharded cascades "
     "(the entire cross-shard merge volume of the Morton path)")
+MESH_ROUTE_LANES = _registry.counter(
+    "mesh_route_lanes_total",
+    "Emission lanes each range shard owns in the gspmd owner-routed "
+    "exchange, from the plan's exact counts (parallel/partition)",
+    labelnames=("shard",))
+MESH_ROUTE_REMOTE = _registry.counter(
+    "mesh_route_remote_lanes_total",
+    "Owner-routed lanes that leave the chip they start on")
+MESH_ROUTE_CAPACITY = _registry.gauge(
+    "mesh_route_capacity_lanes",
+    "Static receive width of one shard in the last owner-routed "
+    "exchange (the largest exact owned count, bucketed)")
+MESH_ROUTE_OVERFLOW = _registry.counter(
+    "mesh_route_overflow_lanes_total",
+    "Owner-routed lanes past the exchange's static widths (0 whenever "
+    "the widths come from the plan's exact counts)")
 SPECULATIVE_LAUNCHES = _registry.counter(
     "speculative_launches_total",
     "Speculative duplicate shard executions by race outcome",
@@ -423,6 +439,24 @@ def record_partition_planned(plan, boundary_tiles=None):
          **fields)
 
 
+def record_mesh_route(route):
+    """The exact lane counts of one owner-routed exchange
+    (parallel.partition.RouteCounts) for a gspmd range dispatch."""
+    if not telemetry_enabled():
+        return
+    owned = route.owned
+    if _registry.enabled:
+        for shard, lanes in enumerate(owned):
+            MESH_ROUTE_LANES.inc(lanes, shard=str(shard))
+        MESH_ROUTE_REMOTE.inc(route.remote_lanes)
+        MESH_ROUTE_CAPACITY.set(route.recv_capacity)
+        MESH_ROUTE_OVERFLOW.inc(route.overflow_lanes)
+    emit("mesh_route", lanes=list(owned), remote_lanes=route.remote_lanes,
+         pair_capacity=route.pair_capacity,
+         recv_capacity=route.recv_capacity,
+         overflow_lanes=route.overflow_lanes)
+
+
 def record_speculative_launch(shard, host, runtime_s=None,
                               threshold_s=None):
     """A duplicate execution of a straggling shard was launched on an
@@ -471,7 +505,8 @@ __all__ = [
     "heartbeat_ages", "incident", "install_specs", "metrics",
     "metrics_enabled",
     "parse_slo_spec", "parse_traceparent", "read_events", "record_fault",
-    "record_io_retry", "record_partition_planned", "record_recovery",
+    "record_io_retry", "record_mesh_route", "record_partition_planned",
+    "record_recovery",
     "record_retry",
     "record_shard_orphaned", "record_shard_reassigned",
     "record_speculative_launch", "record_speculative_result",

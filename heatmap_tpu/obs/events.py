@@ -61,6 +61,13 @@ EVENT_SCHEMA = {
                                        "max_shard_mass", "mean_shard_mass"),
                           "optional": ("skew_ratio", "resplits", "degenerate",
                                        "fingerprint", "boundary_tiles")},
+    # Exact lane counts of one gspmd owner-routed exchange
+    # (parallel/partition.route_counts): lanes each range shard owns,
+    # lanes that leave their source chip, the static send-row and
+    # receive widths, and lanes past those widths (0 by construction).
+    "mesh_route": {"required": ("lanes", "remote_lanes", "pair_capacity",
+                                "recv_capacity", "overflow_lanes"),
+                   "optional": ()},
     # jax.local_devices()[i].memory_stats() snapshot (empty on CPU).
     "device_memory": {"required": ("samples",), "optional": ()},
     # utils/recovery.py shard retry loop.

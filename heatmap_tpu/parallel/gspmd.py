@@ -5,39 +5,29 @@ pads and routes emissions before the kernel (parallel/partition.
 route_emissions), and per-shard buffer widths are derived from the
 routed segment length. This module re-expresses the same two cascade
 pyramids — uniform data-parallel and Morton-range partitioned — as
-*global-view* jax programs annotated with ``NamedSharding`` constraints
-(mesh.named_sharding), so the WHOLE cascade — emission routing,
+programs that take the emission stream spread over the mesh
+(:func:`point_sharding`), so the WHOLE cascade — emission routing,
 range-local rollup, boundary merge, and canonical egress ordering —
 is one compiled program with no host round-trips between stages:
 
-- routing happens on-device against a TRACED splits array
-  (``searchsorted`` on the detail code bits) instead of a host numpy
-  scatter, which is also what lets ``adaptive_capacity`` compose with
-  Morton partitioning (the host router is shape-static; the traced
-  router is not);
-- every per-shard stage is a ``vmap`` over a leading ``(n_shards,)``
-  axis pinned to the mesh's point axes, so XLA's SPMD partitioner
-  places each row's compute on its owning device;
-- the final canonical-order argsort (sorted uniques, sentinel pad)
-  runs on-device inside the same program, byte-identical to the
-  post-shard_map egress of parallel/sharded.py.
+- the uniform program is global view: every per-shard stage is a
+  ``vmap`` over a leading ``(n_shards,)`` axis pinned to the mesh's
+  point axes, reducing contiguous 1/n_shards slices exactly like the
+  shard_map body;
+- the range program routes on-device against a TRACED splits array:
+  each lane goes to the shard that owns its detail code through one
+  ``all_to_all`` whose static widths come from the plan's exact lane
+  counts (partition.route_counts), so each chip holds and scans only
+  its own lanes. Traced splits are also what let ``adaptive_capacity``
+  compose with Morton partitioning (the host router is shape-static).
+  Its boundary merge and canonical egress run in the same shard_map
+  body, and its only sort is the detail stage's own.
 
 Byte identity with the shard_map kernels is the contract (pinned by
 tests/test_gspmd.py and the chaos ``dispatch`` phase): counts and
 bounded-integer weighted sums are exact in any summation order, and
 float64 weighted sums accumulate per key in original lane order on
-both paths (stable sorts; masked lanes carry sentinel keys that sort
-past every real run, so they never interleave a segment).
-
-Routing layout note: the range program replicates the batch across the
-point axes and masks each shard to its owned lanes (``dest == k``) —
-per-device memory O(n), same as the host router's input, and the
-detail reduce scans the full batch per shard. That redundancy buys
-zero host routing, zero host<->device round-trips, and a traced (plan-
-agnostic) program; the dispatch-overhead bench (tools/bench_job.py
---dispatch-sweep) measures the trade. The uniform program has no
-redundancy: it reduces contiguous 1/n_shards slices exactly like the
-shard_map body.
+both paths (stable sorts, and the exchange keeps lane order).
 
 Donation: `donating_jit` adds ``donate_argnums`` where the platform
 supports in-place donation (TPU/GPU) and drops it where it does not
@@ -56,7 +46,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh
 
-from heatmap_tpu.ops import pyramid as pyramid_ops, sparse as sparse_ops
+from heatmap_tpu.ops import pyramid as pyramid_ops
 from heatmap_tpu.parallel.mesh import (
     DATA_AXIS,
     TILE_AXIS,
@@ -77,6 +67,9 @@ __all__ = [
     "ledger",
     "pyramid_gspmd_range",
     "pyramid_gspmd_uniform",
+    "patch_boundary",
+    "place_canonical",
+    "point_sharding",
     "route_on_device",
 ]
 
@@ -92,6 +85,13 @@ def _point_spec(mesh: Mesh):
     else the leading axis flattens over both)."""
     axes, ndev = _shard_axes(mesh)
     return (axes[0] if len(axes) == 1 else tuple(axes)), ndev
+
+
+def point_sharding(mesh: Mesh):
+    """The input layout of both programs: a point stream spread over
+    the mesh's point axes in contiguous per-chip blocks."""
+    spec, _ = _point_spec(mesh)
+    return named_sharding(mesh, spec)
 
 
 def _mapped_stage(stage, mesh: Mesh, spec, backend: str):
@@ -143,26 +143,171 @@ def _constrain(x, mesh: Mesh, *spec):
 # on-device routing
 
 
-def route_on_device(keys, splits, *, code_bits: int, n_shards: int,
-                    valid=None):
-    """Owning-shard mask per emission lane, from a traced splits array.
+def route_on_device(keys, valid, splits, weights=None, *, code_bits: int,
+                    axes, n_shards: int, pair_capacity: int,
+                    recv_capacity: int):
+    """One chip's side of the owner-routed exchange, inside a shard_map
+    body over the point ``axes``.
 
-    ``keys`` are composite cascade keys (slot bits above ``code_bits``
-    detail-code bits); routing is by the detail Morton code alone,
-    mirroring the host router (partition.route_emissions →
-    shard_of_codes: ``shard = #{splits <= code}``). Returns a
-    ``(n_shards, n)`` bool mask whose row ``k`` is True exactly on the
-    valid lanes shard ``k`` owns — the replicate-and-mask layout the
-    range program reduces over.
+    Each local lane goes to the shard that owns its detail code (the
+    low ``code_bits`` bits of its key): ``#{splits <= code}`` against
+    the traced splits, the host router's convention
+    (partition.PartitionPlan.shard_of_codes). The chip packs the
+    lanes bound for shard ``d`` into row ``d`` of a fixed
+    ``pair_capacity``-wide send buffer, in lane order (a running count
+    per owner places each lane: no sort), and one ``all_to_all``
+    delivers row ``d`` to shard ``d``. The received rows are packed
+    back to back into ``recv_capacity`` lanes. Both widths are static
+    and come from the plan's exact lane counts
+    (partition.route_counts), so no lane is dropped; if a plan
+    understates them anyway, the returned overflow flag is True and the
+    caller's loud-overflow contract reports it.
+
+    Returns ``(keys, weights, overflow)``: the lanes this shard owns, in
+    global lane order (source chip, then lane), padded with sentinel
+    keys (the key dtype's max) and zero weights. ``weights`` is None
+    for a count job, which ships keys alone.
     """
-    keys = jnp.asarray(keys)
+    sentinel = jnp.iinfo(keys.dtype).max
     code = keys & ((1 << code_bits) - 1)
-    dest = jnp.searchsorted(jnp.asarray(splits, keys.dtype), code,
-                            side="right")
-    owned = dest[None, :] == jnp.arange(n_shards)[:, None]
-    if valid is not None:
-        owned = owned & jnp.asarray(valid, bool)[None, :]
-    return owned
+    owner = jnp.searchsorted(splits.astype(keys.dtype), code, side="right")
+    dest = jnp.where(valid, owner, n_shards)
+    rank = jnp.zeros(dest.shape, jnp.int32)
+    sizes = []
+    for d in range(n_shards):
+        mine = dest == d
+        seen = jnp.cumsum(mine, dtype=jnp.int32)
+        rank = jnp.where(mine, seen - 1, rank)
+        sizes.append(seen[-1])
+    sizes = jnp.stack(sizes)
+    fits = (dest < n_shards) & (rank < pair_capacity)
+    at = jnp.where(fits, dest * pair_capacity + rank,
+                   n_shards * pair_capacity)
+    cols = [(keys, sentinel)] + ([] if weights is None else [(weights, 0)])
+    recv_sizes = lax.all_to_all(sizes, axes, 0, 0)
+    offsets = jnp.cumsum(recv_sizes) - recv_sizes
+    packed = []
+    for col, pad in cols:
+        send = jnp.full((n_shards * pair_capacity,), pad, col.dtype).at[
+            at].set(col, mode="drop", unique_indices=True)
+        rows = lax.all_to_all(send.reshape(n_shards, pair_capacity),
+                              axes, 0, 0)
+        # Row s lands after rows 0..s-1; its padded tail is overwritten
+        # by row s+1, and the last tail stays past the end.
+        buf = jnp.full((recv_capacity + pair_capacity,), pad, col.dtype)
+        for src in range(n_shards):
+            buf = lax.dynamic_update_slice(buf, rows[src], (offsets[src],))
+        packed.append(buf[:recv_capacity])
+    overflow = (jnp.any(sizes > pair_capacity)
+                | (jnp.sum(recv_sizes) > recv_capacity))
+    return packed[0], (packed[1] if weights is not None else None), overflow
+
+
+def _edge_codes(splits, me, lvl: int, n_shards: int, code_space: int):
+    """The level-``lvl`` tiles that hold this shard's two range ends,
+    where a split is not tile-aligned there: the only tiles another
+    shard can also hold. Returns ``(codes (2,), valid (2,))``."""
+    if n_shards == 1:  # one range: no split, no shared tile
+        return jnp.zeros((2,), splits.dtype), jnp.zeros((2,), bool)
+    lo = jnp.where(me > 0, splits[jnp.maximum(me - 1, 0)], 0)
+    hi = jnp.where(me < n_shards - 1,
+                   splits[jnp.minimum(me, n_shards - 2)], code_space)
+    blk = (1 << (2 * lvl)) - 1
+    lo_ok = (me > 0) & ((lo & blk) != 0)
+    hi_ok = (me < n_shards - 1) & ((hi & blk) != 0)
+    codes = jnp.stack([lo >> (2 * lvl), hi >> (2 * lvl)])
+    # A range inside one straddling tile has one edge tile, not two.
+    hi_ok = hi_ok & ~(lo_ok & (codes[1] == codes[0]))
+    return codes, jnp.stack([lo_ok, hi_ok])
+
+
+def patch_boundary(u, s, splits, lvl: int, *, code_bits: int,
+                   slot_bound: int, axes, me, n_shards: int):
+    """Merge one level's boundary tiles across range shards, inside a
+    shard_map body over ``axes``.
+
+    ``u``/``s`` are this shard's sorted level-``lvl`` keys and sums
+    (sentinel-padded). A tile can have partials on several shards only
+    if it straddles a split, and then it holds an end of each such
+    shard's range (:func:`_edge_codes`); so each shard looks up its
+    ``2 * slot_bound`` candidate keys (each slot at each edge tile),
+    and every shard gathers all candidates. The FIRST holder (lowest
+    shard holding the key) takes the cross-shard total; the others drop
+    their row. Totals are plain sums of the holders' partials, exact in
+    any order for counts and bounded-integer weighted sums.
+
+    Returns ``(sums, kept)``: sums with the totals written in, and the
+    rows this shard keeps (real and not dropped). Keys stay in place,
+    so ``u`` stays sorted.
+    """
+    sentinel = jnp.iinfo(u.dtype).max
+    shift = code_bits - 2 * lvl
+    codes, ok = _edge_codes(splits.astype(u.dtype), me, lvl, n_shards,
+                            1 << code_bits)
+    slot = jnp.arange(slot_bound, dtype=u.dtype)
+    cand = (slot[:, None] << shift) | codes[None, :]      # (slots, 2)
+    pos = jnp.clip(jnp.searchsorted(u, cand.reshape(-1)), 0,
+                   u.shape[0] - 1).reshape(cand.shape)
+    hit = ok[None, :] & (u[pos] == cand)
+    val = jnp.where(hit, s[pos], jnp.zeros((), s.dtype))
+    g_key = lax.all_gather(jnp.where(hit, cand, sentinel), axes)
+    g_val = lax.all_gather(val, axes)                     # (ndev, slots, 2)
+    # held[k, i, j]: shard k holds my candidate (i, j), under either of
+    # its own edge tiles.
+    match = g_key[:, :, None, :] == cand[None, :, :, None]
+    held = jnp.any(match, axis=3) & hit[None]
+    total = jnp.sum(jnp.where(match, g_val[:, :, None, :],
+                              jnp.zeros((), s.dtype)), axis=(0, 3),
+                    dtype=s.dtype)
+    first = jnp.argmax(held, axis=0) == me
+    at = lambda m: jnp.where(m, pos, u.shape[0]).reshape(-1)  # noqa: E731
+    s = s.at[at(hit & first)].set(total.reshape(-1), mode="drop")
+    kept = (u != sentinel).at[at(hit & ~first)].set(False, mode="drop")
+    return s, kept
+
+
+def place_canonical(u, s, kept, *, shift: int, slot_bound: int, cap: int,
+                    axes, me, n_shards: int):
+    """One level's canonical egress, inside a shard_map body: this
+    shard's slice of the level's ``(keys, sums)`` in sorted order.
+
+    Shards own disjoint code ranges in shard order, so within one slot
+    every row of shard ``k`` sorts before every row of shard ``k + 1``:
+    a kept row's global rank is the rows of earlier slots, plus the
+    rows of its slot on earlier shards, plus its rank within its slot
+    here. Each shard scatters its rows to their ranks in a
+    ``cap``-wide buffer (``cap`` a multiple of the shard count), one
+    ``all_to_all`` brings every shard the pieces of its own slice, and
+    since each rank has one writer, their sum is the slice. No sort.
+
+    Returns ``(keys, sums, n)``: the slice, sentinel past the level's
+    ``n`` real rows, and ``n`` itself.
+    """
+    sentinel = jnp.iinfo(u.dtype).max
+    before = jnp.cumsum(kept, dtype=jnp.int32) - kept
+    before = jnp.concatenate([before, jnp.sum(kept, dtype=jnp.int32)[None]])
+    bounds = jnp.arange(slot_bound + 1, dtype=u.dtype) << shift
+    starts = jnp.searchsorted(u, bounds)                  # (slots + 1,)
+    count = before[starts[1:]] - before[starts[:-1]]      # (slots,)
+    g_count = lax.all_gather(count, axes)                 # (ndev, slots)
+    per_slot = jnp.sum(g_count, axis=0)
+    earlier = jnp.sum(jnp.where(jnp.arange(n_shards)[:, None] < me,
+                                g_count, 0), axis=0)
+    offset = jnp.cumsum(per_slot) - per_slot + earlier
+    slot = jnp.clip(u >> shift, 0, slot_bound - 1)
+    rank = offset[slot] + before[:-1] - before[starts[slot]]
+    at = jnp.where(kept & (rank < cap), rank, cap)
+    chunk = cap // n_shards
+    out = []
+    for col in (u, s):
+        buf = jnp.zeros((cap,), col.dtype).at[at].set(
+            jnp.where(kept, col, jnp.zeros((), col.dtype)), mode="drop",
+            unique_indices=True)
+        pieces = lax.all_to_all(buf.reshape(n_shards, chunk), axes, 0, 0)
+        out.append(jnp.sum(pieces, axis=0, dtype=col.dtype))
+    n = jnp.sum(per_slot)
+    real = me * chunk + jnp.arange(chunk) < n
+    return jnp.where(real, out[0], sentinel), out[1], n
 
 
 # ---------------------------------------------------------------------------
@@ -247,7 +392,7 @@ def pyramid_gspmd_uniform(
 
 
 # ---------------------------------------------------------------------------
-# Morton-range partitioned program (on-device routing)
+# Morton-range partitioned program (owner-routed)
 
 
 def pyramid_gspmd_range(
@@ -257,6 +402,8 @@ def pyramid_gspmd_range(
     *,
     code_bits: int,
     slot_bound: int,
+    pair_capacity: int,
+    recv_capacity: int,
     weights=None,
     valid=None,
     levels: int = 0,
@@ -266,162 +413,132 @@ def pyramid_gspmd_range(
     weight_bound: int | None = None,
     adaptive: bool = False,
 ):
-    """Range-partitioned sparse pyramid with ON-DEVICE routing.
+    """Range-partitioned sparse pyramid with ON-DEVICE owner routing.
 
-    Input is UNROUTED — the full emission stream plus the traced
-    ``(n_shards - 1,)`` split codes; :func:`route_on_device` assigns
-    lanes to shards inside the program (replicate-and-mask layout, see
-    module docstring), replacing the host scatter the shard_map path
-    requires. Every stage after routing mirrors
-    :func:`parallel.sharded.pyramid_sparse_morton_range_sharded`
-    verbatim, with the shard axis as an explicit vmapped leading
-    dimension and the cross-shard boundary exchange written as plain
-    array ops over that axis (the SPMD partitioner lowers them to the
-    same all_gather):
+    Input is UNROUTED: the emission stream sharded over the point axes
+    in contiguous blocks (``n`` a multiple of the shard count), plus
+    the traced ``(n_shards - 1,)`` split codes. One shard_map body per
+    chip then runs, with no sort but the detail stage's own:
 
-    - detail reduce per shard (routing is by detail code, so shards
-      never share a detail key and the boundary set is empty);
-    - per coarse level: local parent rollup, boundary-tile extraction
-      against the traced splits, fixed-width exchange, first-holder
-      patch (cross-shard total lands on the lowest-indexed holder,
-      every other holder drops its row), local reorder;
-    - canonical egress: global argsort of the sentinel-padded shard
-      blocks, truncated/padded to the level capacity — byte-identical
-      to the shard_map path's host-graph egress.
+    - ``mesh.route``: :func:`route_on_device` sends every lane to the
+      shard that owns its detail code (one ``all_to_all``); a shard then
+      holds only its own lanes, at most ``recv_capacity`` of them;
+    - ``mesh.detail``: the single-device pyramid of ``backend`` on those
+      lanes: every level's local partials, from the partitioned kernel
+      (each level reduced from the owned stream) or the scatter rollup;
+    - ``mesh.boundary``: per coarse level, :func:`patch_boundary`. Each
+      level is patched on its own: a shard's level-``l`` partial of a
+      tile sums that shard's lanes under it, so the holders' partials
+      add up to the tile's total whatever the level below holds.
+      Routing is by detail code, so the detail level has no boundary;
+    - ``mesh.egress``: :func:`place_canonical` puts every level in the
+      canonical sorted order, each chip holding a slice.
 
-    The loud-overflow contract holds: any shard-local buffer overflow
-    forces every level's count past capacity. Because the replicated
-    layout sizes per-shard buffers by the FULL level capacity rather
-    than the routed segment length, some shapes that overflow a
-    narrow routed segment do not overflow here; non-overflow shapes
-    (the contract everything downstream serves) are byte-identical.
+    Counts and bounded-integer weighted sums come out byte-identical to
+    the single-device cascade. ``pair_capacity`` and ``recv_capacity``
+    are static widths from the plan's exact counts
+    (partition.route_counts). The loud-overflow contract holds: lanes
+    past either width, or a level past its capacity, force every
+    level's count past capacity.
+
+    ``adaptive`` (EAGER callers only) shrinks each returned level to the
+    next power of two above its real count, as the single-device
+    adaptive pyramid does; the shard-local work keeps static widths.
     """
+    from jax.sharding import PartitionSpec as P
+
     spec, ndev = _point_spec(mesh)
+    axes, _ = _shard_axes(mesh)
     keys = jnp.asarray(keys)
     n = keys.shape[0]
+    if n % ndev != 0:
+        raise ValueError(
+            f"gspmd range cascade needs n % n_shards == 0, got "
+            f"{n} % {ndev} (pad with valid=False lanes)")
     splits = jnp.asarray(splits)
     if splits.shape != (ndev - 1,):
         raise ValueError(
             f"need {ndev - 1} split codes for {ndev} shards, got "
             f"shape {splits.shape}")
     caps = pyramid_ops._level_caps(capacity, n, levels)
-    lcaps = [max(1, caps[lvl]) for lvl in range(levels + 1)]
-    bcaps = [max(1, min(lcaps[lvl], 2 * slot_bound))
+    # A shard's uniques at any level are at most its owned lanes.
+    lcaps = [max(1, min(caps[lvl], recv_capacity))
              for lvl in range(levels + 1)]
+    pcaps = [-(-cap // ndev) * ndev for cap in caps]
     if acc_dtype is None:
         acc_dtype = jnp.int32 if weights is None else jnp.float32
     counts_only = weights is None
-    w = _ones_like_weights(weights, n, acc_dtype)
     v = jnp.ones((n,), bool) if valid is None else jnp.asarray(valid, bool)
     sentinel = jnp.iinfo(keys.dtype).max
-    stage = _local_detail_stage(backend, counts_only, lcaps[0],
-                                acc_dtype, sentinel,
-                                weight_bound=weight_bound)
 
-    owned = route_on_device(keys, splits, code_bits=code_bits,
-                            n_shards=ndev, valid=v)
-    bk = _constrain(jnp.broadcast_to(keys, (ndev, n)), mesh, spec, None)
-    bw = _constrain(jnp.broadcast_to(w, (ndev, n)), mesh, spec, None)
-    bv = _constrain(owned, mesh, spec, None)
+    def local_pyramid(k, w):
+        real = k != sentinel
+        if backend == "partitioned":
+            return pyramid_ops.pyramid_sparse_morton_partitioned(
+                k, valid=real, levels=levels, capacity=lcaps, weights=w,
+                weight_bound=weight_bound)
+        return pyramid_ops.pyramid_sparse_morton(
+            k, weights=w, valid=real, levels=levels, capacity=lcaps,
+            acc_dtype=acc_dtype)
 
-    u, s, ln = _mapped_stage(stage, mesh, spec, backend)(bk, bw, bv)
-    over = ln > lcaps[0]
-    u = _constrain(u, mesh, spec, None)
-    s = _constrain(s, mesh, spec, None)
+    def body(k, v, spl, *w):
+        me = lax.axis_index(axes[0])
+        for ax in axes[1:]:
+            me = me * mesh.shape[ax] + lax.axis_index(ax)
+        with jax.named_scope("mesh.route"):
+            rk, rw, over = route_on_device(
+                k, v, spl, w[0] if w else None, code_bits=code_bits,
+                axes=axes, n_shards=ndev, pair_capacity=pair_capacity,
+                recv_capacity=recv_capacity)
+        with jax.named_scope("mesh.detail"):
+            per_level = local_pyramid(rk, rw)
+        outs = []
+        for lvl, (u, s, ln) in enumerate(per_level):
+            s = s.astype(acc_dtype)
+            over = over | (ln > lcaps[lvl])
+            if lvl:
+                with jax.named_scope("mesh.boundary"):
+                    s, kept = patch_boundary(
+                        u, s, spl, lvl, code_bits=code_bits,
+                        slot_bound=slot_bound, axes=axes, me=me,
+                        n_shards=ndev)
+            else:
+                kept = u != sentinel
+            with jax.named_scope("mesh.egress"):
+                outs.extend(place_canonical(
+                    u, s, kept, shift=code_bits - 2 * lvl,
+                    slot_bound=slot_bound, cap=pcaps[lvl], axes=axes,
+                    me=me, n_shards=ndev))
+            outs[-1] = outs[-1][None]
+        any_over = lax.psum(over.astype(jnp.int32), axes) > 0
+        return (*outs, any_over[None])
 
-    me = jnp.arange(ndev)
-    spl = splits.astype(keys.dtype)
-    per_level = [(u, s, jnp.sum(u != sentinel, axis=1))]
-    cur_u, cur_s = u, s
-    for lvl in range(1, levels + 1):
-        if adaptive:
-            # EAGER callers only (counts are concrete): shrink the
-            # per-shard columns to the next power of two above the
-            # widest shard's real unique count before the next rollup —
-            # the ops.pyramid adaptive trick applied per shard. Rows
-            # are sorted with sentinels last, so the dropped columns
-            # are pure padding; never slice below any shard's n_real
-            # (overflow detection relies on the true counts). This is
-            # the composition the host-routed shard_map path cannot
-            # express: its widths are baked into static body specs,
-            # while the traced router leaves the rollup global-view.
-            n_real = int(jnp.max(per_level[-1][2]))
-            if n_real <= cur_u.shape[1]:
-                keep = max(64, 1 << max(0, n_real - 1).bit_length())
-                if keep < cur_u.shape[1]:
-                    cur_u = cur_u[:, :keep]
-                    cur_s = cur_s[:, :keep]
-        parents = jnp.where(cur_u == sentinel, sentinel, cur_u >> 2)
-        out_cap = (min(lcaps[lvl], cur_u.shape[1]) if adaptive
-                   else lcaps[lvl])
-        pu, ps, pn = jax.vmap(
-            lambda p, ps_: sparse_ops.aggregate_sorted_keys(
-                p, ps_, out_cap, sentinel=sentinel))(parents, cur_s)
-        over = over | (pn > out_cap)
-        # Boundary codes at this level, from the traced splits: the
-        # split's ancestor, unless the split is tile-aligned.
-        blk = (1 << (2 * lvl)) - 1
-        b = jnp.where((spl & blk) != 0, spl >> (2 * lvl), sentinel)
-        code_mask = (1 << (code_bits - 2 * lvl)) - 1
-        is_b = (pu != sentinel) & jnp.any(
-            (pu & code_mask)[:, :, None] == b[None, None, :], axis=2)
-        cb = min(bcaps[lvl], pu.shape[1])
-        over = over | (jnp.sum(is_b, axis=1) > cb)
-        # Boundary rows to the front (sentinel-masked argsort), fixed
-        # cb-wide send buffers — the all_gather payload of the
-        # shard_map body, here simply the stacked (ndev, cb) arrays.
-        bkey = jnp.where(is_b, pu, sentinel)
-        border = jnp.argsort(bkey, axis=1)[:, :cb]
-        send_u = jnp.take_along_axis(bkey, border, axis=1)
-        send_s = jnp.take_along_axis(
-            jnp.where(is_b, ps, jnp.zeros((), ps.dtype)), border, axis=1)
-
-        def lookup(bu, bs, pu_k):
-            pos = jnp.clip(jnp.searchsorted(bu, pu_k), 0, cb - 1)
-            hit = (bu[pos] == pu_k) & (pu_k != sentinel)
-            return jnp.where(hit, bs[pos], jnp.zeros((), bs.dtype)), hit
-
-        # vals[k, j]: shard k's boundary keys looked up in shard j's
-        # gathered block — (ndev, ndev, lcap); summed over j in block
-        # order, exactly the shard_map body's gathered-axis sum.
-        vals, hits = jax.vmap(
-            lambda pu_k: jax.vmap(lookup, in_axes=(0, 0, None))(
-                send_u, send_s, pu_k))(pu)
-        total = jnp.sum(vals, axis=1)
-        holder = me[jnp.argmax(hits, axis=1)]
-        keep = ~is_b | (holder == me[:, None])
-        new_u = jnp.where(keep, pu, sentinel)
-        new_s = jnp.where(keep & is_b, total, ps)
-        new_s = jnp.where(keep, new_s, jnp.zeros((), ps.dtype))
-        reorder = jnp.argsort(new_u, axis=1)
-        cur_u = jnp.take_along_axis(new_u, reorder, axis=1)
-        cur_s = jnp.take_along_axis(new_s, reorder, axis=1)
-        cur_u = _constrain(cur_u, mesh, spec, None)
-        cur_s = _constrain(cur_s, mesh, spec, None)
-        per_level.append((cur_u, cur_s, jnp.sum(cur_u != sentinel, axis=1)))
-
-    any_over = over.any()
+    row = P(spec)
+    args = (keys, v, splits) + (
+        () if counts_only else (jnp.asarray(weights, acc_dtype),))
+    # jit: one program eagerly too (adaptive callers), not one dispatch
+    # per operation of the body.
+    fn = jax.jit(shard_map(
+        body, mesh,
+        in_specs=(row, row, P()) + (() if counts_only else (row,)),
+        out_specs=(row,) * (3 * (levels + 1) + 1),
+        check_vma=False))
+    parts = fn(*args)
+    any_over = parts[-1][0]
     out = []
-    for lvl in range(levels + 1):
-        cu, cs, cn = per_level[lvl]
-        cap = caps[lvl]
-        gu, gs = cu.reshape(-1), cs.reshape(-1)
-        # Keys are globally disjoint post-patch, so a global argsort of
-        # the sentinel-padded shard blocks IS the canonical merged
-        # order (sentinels sort last, their sums are zero) — the same
-        # egress the shard_map path runs, now inside the program.
-        order = jnp.argsort(gu)
-        su, ss = gu[order], gs[order]
-        if su.shape[0] >= cap:
-            su, ss = su[:cap], ss[:cap]
-        else:
-            su = jnp.concatenate(
-                [su, jnp.full((cap - su.shape[0],), sentinel, su.dtype)])
-            ss = jnp.concatenate(
-                [ss, jnp.zeros((cap - ss.shape[0],), ss.dtype)])
-        ln = cn.sum()
-        out.append((su, ss,
-                    jnp.where(any_over, jnp.maximum(ln, cap + 1), ln)))
+    for lvl, cap in enumerate(caps):
+        su, ss, ln = parts[3 * lvl][:cap], parts[3 * lvl + 1][:cap], \
+            parts[3 * lvl + 2][0]
+        if adaptive:
+            # Eager: the counts are concrete. Rows are sorted with
+            # sentinels last, so the dropped tail is padding; an
+            # overflowed level keeps its width.
+            n_real = int(ln)
+            keep = max(64, 1 << max(0, n_real - 1).bit_length())
+            if n_real <= su.shape[0] and keep < su.shape[0]:
+                su, ss = su[:keep], ss[:keep]
+        out.append((su, ss, jnp.where(any_over,
+                                      jnp.maximum(ln, su.shape[0] + 1), ln)))
     return out
 
 

@@ -244,6 +244,70 @@ def split_range_median(codes, weights, lo: int, hi: int):
     return med
 
 
+@dataclasses.dataclass(frozen=True)
+class RouteCounts:
+    """Exact lane counts of one owner-routed exchange (the gspmd range
+    program's ``mesh.route``), and the static widths they fix.
+
+    ``pairs[s][d]`` counts the valid lanes that start on source shard
+    ``s`` (the ``s``-th contiguous block of the padded stream) and that
+    shard ``d`` owns. ``pair_capacity`` is the width of one send row and
+    ``recv_capacity`` that of one shard's owned lanes; each is the
+    largest exact count, bucketed, so no data of the plan exceeds it.
+    """
+
+    pairs: tuple
+    pair_capacity: int
+    recv_capacity: int
+
+    @property
+    def owned(self) -> tuple:
+        """Valid lanes each shard owns."""
+        return tuple(int(x) for x in np.asarray(self.pairs).sum(axis=0))
+
+    @property
+    def remote_lanes(self) -> int:
+        """Lanes that leave their source shard."""
+        p = np.asarray(self.pairs)
+        return int(p.sum() - np.trace(p))
+
+    @property
+    def overflow_lanes(self) -> int:
+        """Lanes past the static widths: 0 for widths from this count."""
+        p = np.asarray(self.pairs)
+        return int(np.maximum(p - self.pair_capacity, 0).sum()
+                   + np.maximum(p.sum(axis=0) - self.recv_capacity, 0).sum())
+
+
+def route_counts(plan: PartitionPlan, codes, valid=None,
+                 bucket=None) -> RouteCounts:
+    """Exact per-pair lane counts of the owner-routed exchange.
+
+    ``codes`` is the emission stream's detail codes and ``valid`` its
+    mask. The stream is padded to whole blocks of ``ceil(n / n_shards)``
+    lanes (pad lanes invalid) and shard ``s`` holds the ``s``-th block.
+    ``bucket`` rounds each width up (a step of a fixed ladder), so jobs
+    of one size share one compiled program; the widths are never below
+    the exact maxima.
+    """
+    codes = np.asarray(codes, np.int64)
+    P = plan.n_shards
+    src = np.arange(len(codes)) // max(-(-len(codes) // P), 1)
+    owner = plan.shard_of_codes(codes)
+    if valid is not None:
+        v = np.asarray(valid, bool)
+        src, owner = src[v], owner[v]
+    pairs = np.bincount(src * P + owner, minlength=P * P).reshape(P, P)
+    widest_pair = max(int(pairs.max()), 1)
+    widest_owner = max(int(pairs.sum(axis=0).max()), 1)
+    if bucket is not None:
+        widest_pair = max(int(bucket(widest_pair)), widest_pair)
+        widest_owner = max(int(bucket(widest_owner)), widest_owner)
+    return RouteCounts(pairs=tuple(tuple(int(x) for x in r) for r in pairs),
+                       pair_capacity=widest_pair,
+                       recv_capacity=widest_owner)
+
+
 def route_emissions(plan: PartitionPlan, codes, slots, valid=None,
                     weights=None, bucket=None):
     """Scatter emission lanes into per-shard contiguous segments.

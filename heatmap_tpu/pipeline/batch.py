@@ -2139,7 +2139,7 @@ def _run_grouped(lat, lon, group_ids, timestamps, vocab,
                     e_codes, e_slots, e_valid, e_weights, target))
             n_slots = bucketing_mod.bucket_slots(n_slots)
     mesh0 = _dp_mesh(config)
-    plan = None
+    plan = route = None
     if mesh0 is not None and config.spatial_partition != "off":
         from heatmap_tpu.parallel import partition as partition_mod
         from heatmap_tpu.parallel.sharded import _shard_axes
@@ -2154,17 +2154,26 @@ def _run_grouped(lat, lon, group_ids, timestamps, vocab,
         # "morton" forces the plan whenever a mesh engages.
         if ndev >= 2 and (config.spatial_partition == "morton"
                           or len(e_codes) >= threshold):
-            with tracer.span("cascade.partition_plan",
-                             items=len(e_codes)):
+            with tracer.span("mesh.plan", items=len(e_codes)):
+                host_codes = np.asarray(e_codes)
+                host_valid = (None if e_valid is None
+                              else np.asarray(e_valid))
                 plan = partition_mod.plan_partition(
-                    np.asarray(e_codes), ndev,
-                    detail_zoom=config.detail_zoom,
-                    valid=None if e_valid is None
-                    else np.asarray(e_valid),
+                    host_codes, ndev, detail_zoom=config.detail_zoom,
+                    valid=host_valid,
                     n_levels=config.cascade_config().n_levels)
+                if (not plan.degenerate
+                        and config.resolved_dispatch == "gspmd"):
+                    # The owner-routed program's static exchange widths,
+                    # on a fixed 1.25x ladder so that jobs of one size
+                    # compile once.
+                    route = partition_mod.route_counts(
+                        plan, host_codes, host_valid,
+                        bucket=functools.partial(
+                            bucketing_mod.bucket_size, mode="geometric"))
     dp_mesh = _dp_mesh_for(mesh0, config, len(e_codes), plan=plan)
     if plan is not None and (dp_mesh is None or plan.degenerate):
-        plan = None  # fallback recorded by _dp_mesh_for
+        plan = route = None  # fallback recorded by _dp_mesh_for
     dispatch = config.resolved_dispatch if dp_mesh is not None else None
     timer = obs.DispatchTimer(dispatch or "single")
     if plan is not None and dispatch != "gspmd":
@@ -2185,6 +2194,23 @@ def _run_grouped(lat, lon, group_ids, timestamps, vocab,
                 partition_mod.route_emissions(
                     plan, e_codes, e_slots, e_valid, e_weights,
                     bucket=bucket))
+    if route is not None:
+        obs.record_mesh_route(route)
+    if dispatch == "gspmd":
+        # The gspmd programs take the stream in contiguous per-chip
+        # blocks: pad to whole blocks (pad lanes valid=False) and place
+        # each block on its chip, so no chip receives the whole batch.
+        from heatmap_tpu.parallel.gspmd import point_sharding
+        from heatmap_tpu.parallel.sharded import _shard_axes
+
+        _, ndev = _shard_axes(dp_mesh)
+        e_codes, e_slots, e_valid, e_weights = bucketing_mod.pad_emissions(
+            e_codes, e_slots, e_valid, e_weights,
+            -(-len(e_codes) // ndev) * ndev)
+        sharding = point_sharding(dp_mesh)
+        e_codes, e_slots, e_valid, e_weights = (
+            None if a is None else jax.device_put(a, sharding)
+            for a in (e_codes, e_slots, e_valid, e_weights))
     backend = _resolve_backend(config, n_emissions=len(e_codes),
                                data_parallel=dp_mesh is not None)
     with tracer.span("cascade.device", backend=backend):
@@ -2219,6 +2245,8 @@ def _run_grouped(lat, lon, group_ids, timestamps, vocab,
                     # Dispatch term: the gspmd and shard_map programs
                     # are distinct traces of the same math.
                     dispatch,
+                    None if route is None
+                    else (route.pair_capacity, route.recv_capacity),
                 ),
                 config.pad_bucketing,
             )
@@ -2243,6 +2271,8 @@ def _run_grouped(lat, lon, group_ids, timestamps, vocab,
             weight_bound=config.weight_bound,
             partition_splits=partition_splits,
             dispatch=dispatch or "shard_map",
+            route_capacity=None if route is None
+            else (route.pair_capacity, route.recv_capacity),
         )
         timer.dispatched()
         if timer.enabled:

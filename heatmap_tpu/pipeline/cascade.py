@@ -101,7 +101,8 @@ def build_cascade(codes, slots, config: CascadeConfig, n_slots: int,
                   adaptive: bool = False, backend: str = "scatter",
                   mesh=None, merge: str = "replicated",
                   weight_bound: int | None = None,
-                  partition_splits=None, dispatch: str = "shard_map"):
+                  partition_splits=None, dispatch: str = "shard_map",
+                  route_capacity=None):
     """Device-side cascade: per-level (composite key, sum) aggregates.
 
     Args:
@@ -164,6 +165,11 @@ def build_cascade(codes, slots, config: CascadeConfig, n_slots: int,
     routes ON-DEVICE, so emissions arrive UNROUTED, and
     ``adaptive`` composes with the mesh). Byte-identical outputs
     either way (tests/test_gspmd.py).
+
+    ``route_capacity``: the gspmd range program's static exchange
+    widths ``(pair_capacity, recv_capacity)``, from the plan's exact
+    lane counts (parallel.partition.route_counts); required with
+    ``partition_splits`` under ``dispatch="gspmd"``.
     """
     if dispatch not in ("shard_map", "gspmd"):
         raise ValueError(
@@ -246,6 +252,7 @@ def build_cascade(codes, slots, config: CascadeConfig, n_slots: int,
             weight_bound=weight_bound if weights is not None else None,
             partition_splits=partition_splits, n_slots=n_slots,
             dispatch=dispatch, adaptive=adaptive,
+            route_capacity=route_capacity,
         )
     if backend == "partitioned":
         return pyramid_ops.pyramid_sparse_morton_partitioned(
@@ -274,7 +281,7 @@ def _build_cascade_sharded(ck, config: CascadeConfig, mesh,
                            weight_bound: int | None = None,
                            partition_splits=None, n_slots: int = 1,
                            dispatch: str = "shard_map",
-                           adaptive: bool = False):
+                           adaptive: bool = False, route_capacity=None):
     """Pad composite keys to the mesh shard count and run the sharded
     pyramid (see build_cascade's ``mesh`` doc). Pad lanes carry
     valid=False, the masking path every kernel already drops.
@@ -282,8 +289,8 @@ def _build_cascade_sharded(ck, config: CascadeConfig, mesh,
     ``dispatch="gspmd"`` swaps each shard_map kernel for its
     global-view NamedSharding twin (parallel/gspmd.py): same padding,
     byte-identical outputs; with ``partition_splits`` the emissions
-    arrive UNROUTED and are routed on-device, so no segment-divisibility
-    requirement applies there.
+    arrive UNROUTED, in contiguous per-shard blocks, and are routed
+    on-device to their owners (``route_capacity`` sizes the exchange).
     """
     # Lazy import: parallel.sharded pulls in the pallas histogram stack,
     # which cascade-only consumers (spark_adapter, tools) never need.
@@ -299,20 +306,7 @@ def _build_cascade_sharded(ck, config: CascadeConfig, mesh,
             ck, weights=weights, valid=valid, levels=config.n_levels,
             capacity=capacity, acc_dtype=acc_dtype,
         )
-    if partition_splits is not None:
-        if dispatch == "gspmd":
-            from heatmap_tpu.parallel import gspmd as gspmd_kernels
-
-            # UNROUTED emissions + traced splits: routing happens
-            # inside the program (route_on_device), replacing the host
-            # scatter of partition.route_emissions.
-            return gspmd_kernels.pyramid_gspmd_range(
-                ck, mesh, partition_splits,
-                code_bits=2 * config.detail_zoom, slot_bound=n_slots,
-                weights=weights, valid=valid, levels=config.n_levels,
-                capacity=capacity, acc_dtype=acc_dtype, backend=backend,
-                weight_bound=weight_bound, adaptive=adaptive,
-            )
+    if partition_splits is not None and dispatch != "gspmd":
         # Emissions arrive pre-routed into per-shard contiguous range
         # segments of equal length (partition.route_emissions) — no
         # tail pad here, a pad would shift lanes across segment
@@ -335,6 +329,22 @@ def _build_cascade_sharded(ck, config: CascadeConfig, mesh,
             weights = jnp.concatenate(
                 [weights, jnp.zeros((pad,), weights.dtype)]
             )
+    if partition_splits is not None:
+        from heatmap_tpu.parallel import gspmd as gspmd_kernels
+
+        if route_capacity is None:
+            raise ValueError(
+                "the gspmd range program needs route_capacity "
+                "(pair, recv) from partition.route_counts")
+        pair_capacity, recv_capacity = route_capacity
+        return gspmd_kernels.pyramid_gspmd_range(
+            ck, mesh, partition_splits,
+            code_bits=2 * config.detail_zoom, slot_bound=n_slots,
+            pair_capacity=pair_capacity, recv_capacity=recv_capacity,
+            weights=weights, valid=v, levels=config.n_levels,
+            capacity=capacity, acc_dtype=acc_dtype, backend=backend,
+            weight_bound=weight_bound, adaptive=adaptive,
+        )
     if dispatch == "gspmd":
         from heatmap_tpu.parallel import gspmd as gspmd_kernels
 
@@ -361,7 +371,7 @@ _build_cascade_jit = functools.partial(
     jax.jit,
     static_argnames=("config", "n_slots", "capacity", "acc_dtype",
                      "backend", "mesh", "merge", "weight_bound",
-                     "dispatch"),
+                     "dispatch", "route_capacity"),
 )(build_cascade)
 
 #: Lazily-built donating twin of _build_cascade_jit for the gspmd
@@ -386,7 +396,7 @@ def _get_donating_cascade_jit():
             donate_argnames=("weights", "valid"),
             static_argnames=("config", "n_slots", "capacity",
                              "acc_dtype", "backend", "mesh", "merge",
-                             "weight_bound", "dispatch"),
+                             "weight_bound", "dispatch", "route_capacity"),
         )
     return _donating_cascade_jit
 
@@ -397,7 +407,8 @@ def run_cascade(codes, slots, config: CascadeConfig, n_slots: int,
                 backend: str = "scatter", mesh=None,
                 merge: str = "replicated",
                 weight_bound: int | None = None,
-                partition_splits=None, dispatch: str = "shard_map"):
+                partition_splits=None, dispatch: str = "shard_map",
+                route_capacity=None):
     """The production cascade entry: jitted whole, unless ``adaptive``
     (which must read concrete per-level unique counts and therefore
     runs eagerly — see ops.pyramid.pyramid_sparse_morton) or
@@ -430,6 +441,7 @@ def run_cascade(codes, slots, config: CascadeConfig, n_slots: int,
                 backend=backend, mesh=mesh, merge=merge,
                 weight_bound=weight_bound,
                 partition_splits=partition_splits, dispatch=dispatch,
+                route_capacity=route_capacity,
             )
         if isinstance(capacity, list):
             capacity = tuple(capacity)  # static args must be hashable
@@ -447,6 +459,7 @@ def run_cascade(codes, slots, config: CascadeConfig, n_slots: int,
             backend=backend, mesh=mesh, merge=merge,
             weight_bound=weight_bound,
             partition_splits=partition_splits, dispatch=dispatch,
+            route_capacity=route_capacity,
         )
     finally:
         tracing.end_span(tsp)

@@ -155,5 +155,5 @@ def test_four_chip_phase_on_four_virtual_devices(tmp_path):
         assert phases[name]["mesh_devices"] == 4
         assert len(phases[name]["placements"]) == 1
         assert phases[name]["placements"][0]["out"] == [4]
-        # Projection and emission assembly run on one device.
-        assert phases[name]["inputs_spread"] is False
+        # run_job places the gspmd cascade's inputs in per-chip blocks.
+        assert phases[name]["inputs_spread"] is True

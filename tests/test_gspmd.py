@@ -22,6 +22,7 @@ under ``mesh.force_cpu_devices`` must yield a working multi-device CPU
 mesh (satellite of the same PR).
 """
 
+import json
 import os
 import subprocess
 import sys
@@ -126,20 +127,216 @@ def test_gspmd_uniform_eager_equals_jit():
     _levels_equal(eager, jitted)
 
 
+def _plan(splits, ndev):
+    from heatmap_tpu.parallel.partition import PartitionPlan
+
+    return PartitionPlan(
+        detail_zoom=DZ, n_shards=ndev, splits=tuple(int(x) for x in splits),
+        sampled_points=0, balance_factor=1.25, shard_mass=(), resplits=0,
+        fingerprint="")
+
+
+def _shard_route(mesh, ck, valid, splits, counts, weights=None):
+    """route_on_device on every shard of ``mesh``: (ndev, recv) keys,
+    weights (or None) and the per-shard overflow flags."""
+    from jax.sharding import PartitionSpec as P
+
+    from heatmap_tpu.parallel.mesh import shard_map
+
+    ndev = mesh.devices.size
+
+    def body(k, v, spl, *w):
+        rk, rw, over = gspmd.route_on_device(
+            k, v, spl, w[0] if w else None, code_bits=2 * DZ,
+            axes=("data",), n_shards=ndev,
+            pair_capacity=counts.pair_capacity,
+            recv_capacity=counts.recv_capacity)
+        return rk[None], (rk if rw is None else rw)[None], over[None]
+
+    extra = () if weights is None else (weights,)
+    fn = jax.jit(shard_map(body, mesh,
+                           in_specs=(P("data"), P("data"), P())
+                           + (P("data"),) * len(extra),
+                           out_specs=(P("data"),) * 3, check_vma=False))
+    rk, rw, over = fn(ck, valid, jnp.asarray(splits), *extra)
+    return (np.asarray(rk), None if weights is None else np.asarray(rw),
+            np.asarray(over))
+
+
 def test_route_on_device_matches_host_router():
-    """On-device ownership mask == the host searchsorted convention
-    (shard = #{splits <= code}, side='right')."""
+    """Every valid lane reaches the shard the host convention names
+    (shard = #{splits <= code}, side='right'), sentinel-padded to the
+    receive width; with weights, in global lane order, each weight
+    beside its key. The widths from the exact host counts hold every
+    lane."""
+    from heatmap_tpu.parallel import partition
+
+    mesh = make_mesh()
     rng = np.random.default_rng(11)
     n = 2048
     code = rng.integers(0, SPACE, n)
     ck = jnp.asarray((rng.integers(0, 8, n) << np.int64(2 * DZ)) | code)
+    valid = rng.random(n) > 0.2
     splits = np.sort(rng.integers(1, SPACE, 7))
-    owned = np.asarray(gspmd.route_on_device(
-        ck, jnp.asarray(splits), code_bits=2 * DZ, n_shards=8))
-    want = np.searchsorted(splits, code, side="right")
-    assert owned.shape == (8, n)
-    assert np.array_equal(np.argmax(owned, axis=0), want)
-    assert np.array_equal(owned.sum(axis=0), np.ones(n))  # exactly one owner
+    counts = partition.route_counts(_plan(splits, 8), code, valid)
+    weights = np.arange(n, dtype=np.float64)
+    got, _, over = _shard_route(mesh, ck, jnp.asarray(valid), splits, counts)
+    got_w, w_of, over_w = _shard_route(mesh, ck, jnp.asarray(valid), splits,
+                                       counts, jnp.asarray(weights))
+    owner = np.searchsorted(splits, code, side="right")
+    sentinel = np.iinfo(np.int64).max
+    assert got.shape == (8, counts.recv_capacity)
+    assert not over.any() and not over_w.any()
+    for d in range(8):
+        mine = valid & (owner == d)
+        want = np.asarray(ck)[mine]
+        assert np.array_equal(np.sort(got[d][:want.size]), np.sort(want))
+        assert np.array_equal(got_w[d][:want.size], want)
+        assert np.array_equal(w_of[d][:want.size], weights[mine])
+        assert (got[d][want.size:] == sentinel).all()
+        assert (w_of[d][want.size:] == 0).all()
+        assert counts.owned[d] == want.size
+    src = np.arange(n) // (n // 8)
+    assert counts.remote_lanes == int((valid & (owner != src)).sum())
+    assert counts.overflow_lanes == 0
+
+
+def _walk_codes(n, seed):
+    """Codes of seeded random walks: runs of neighbouring tiles."""
+    from heatmap_tpu.tilemath.morton import morton_encode_np
+
+    rng = np.random.default_rng(seed)
+    walks, steps = 16, n // 16
+    start = rng.integers(0, 1 << DZ, (walks, 2))
+    path = start[:, None, :] + np.cumsum(
+        rng.integers(-1, 2, (walks, steps, 2)), axis=1)
+    path = np.clip(path, 0, (1 << DZ) - 1).reshape(-1, 2)
+    return morton_encode_np(path[:, 0], path[:, 1]).astype(np.int64)
+
+
+def _range_case(case, ndev, n=4096):
+    """(codes, valid, splits) of one routing case."""
+    from heatmap_tpu.parallel import partition
+
+    rng = np.random.default_rng(ndev)
+    code = _walk_codes(n, 5)
+    valid = rng.random(n) > 0.05
+    splits = np.asarray(partition.plan_partition(
+        code, ndev, detail_zoom=DZ, valid=valid).splits)
+    if case == "one_owner":
+        splits = np.full(ndev - 1, SPACE - 1)
+        code = code % (SPACE - 1)
+    elif case == "one_source":
+        valid[n // ndev:] = False
+    elif case == "empty_shards":
+        splits = np.sort(np.concatenate(
+            [splits[:2], np.full(ndev - 3, splits[1])]))
+    return code, valid, splits
+
+
+RANGE_CASES = ["traces", "one_owner", "one_source", "empty_shards",
+               "exactly_full"]
+
+
+@pytest.mark.parametrize("ndev", [4, 8])
+@pytest.mark.parametrize("case", RANGE_CASES)
+def test_gspmd_range_matches_single_device(case, ndev):
+    """The owner-routed range program is byte-identical to the
+    single-device cascade on every routing case, counts and integer
+    weights alike; the widths come from the exact counts, bucketed as
+    run_job buckets them ("exactly_full": unbucketed, so the widest
+    owner fills its receive buffer exactly)."""
+    from functools import partial
+
+    from heatmap_tpu.ops import pyramid
+    from heatmap_tpu.parallel import partition
+    from heatmap_tpu.pipeline import bucketing
+
+    mesh = make_mesh(devices=jax.devices()[:ndev])
+    code, valid, splits = _range_case(case, ndev)
+    n = len(code)
+    slot = np.random.default_rng(3).integers(0, 20, n)
+    ck = jnp.asarray((slot << np.int64(2 * DZ)) | code)
+    bucket = (None if case == "exactly_full"
+              else partial(bucketing.bucket_size, mode="geometric",
+                           min_bucket=64))
+    counts = partition.route_counts(_plan(splits, ndev), code, valid,
+                                    bucket=bucket)
+    if case == "exactly_full":
+        assert max(counts.owned) == counts.recv_capacity
+    w = jnp.asarray(np.random.default_rng(4).integers(1, 9, n), jnp.float64)
+    for weights in (None, w):
+        acc = None if weights is None else jnp.float64
+        got = jax.jit(lambda k, v, s, wt: gspmd.pyramid_gspmd_range(
+            k, mesh, s, code_bits=2 * DZ, slot_bound=20,
+            pair_capacity=counts.pair_capacity,
+            recv_capacity=counts.recv_capacity, weights=wt, valid=v,
+            levels=6, capacity=n, acc_dtype=acc))(
+                ck, jnp.asarray(valid), jnp.asarray(splits), weights)
+        want = pyramid.pyramid_sparse_morton(
+            ck, weights=weights, valid=jnp.asarray(valid), levels=6,
+            capacity=n, acc_dtype=acc)
+        _levels_equal(got, want)
+        assert int(got[0][2]) == len(np.unique(np.asarray(ck)[valid]))
+
+
+def test_gspmd_range_one_shard_matches():
+    """A one-device mesh: one range, no split, no boundary tile."""
+    from heatmap_tpu.ops import pyramid
+
+    mesh = make_mesh(devices=jax.devices()[:1])
+    code, valid, _ = _range_case("traces", 4, n=2048)
+    ck = jnp.asarray(code)
+    got = jax.jit(lambda k, v: gspmd.pyramid_gspmd_range(
+        k, mesh, jnp.zeros((0,), jnp.int64), code_bits=2 * DZ,
+        slot_bound=1, pair_capacity=2048, recv_capacity=2048, valid=v,
+        levels=4, capacity=2048))(ck, jnp.asarray(valid))
+    want = pyramid.pyramid_sparse_morton(ck, valid=jnp.asarray(valid),
+                                         levels=4, capacity=2048)
+    _levels_equal(got, want)
+
+
+def test_gspmd_range_partitioned_backend_matches():
+    """The per-shard detail stage on the partitioned segment kernel
+    (interpret mode here) gives the scatter program's bytes."""
+    from heatmap_tpu.ops import pyramid
+    from heatmap_tpu.parallel import partition
+
+    mesh = make_mesh(devices=jax.devices()[:4])
+    code, valid, splits = _range_case("traces", 4, n=2048)
+    ck = jnp.asarray((np.int64(3) << np.int64(2 * DZ)) | code)
+    counts = partition.route_counts(_plan(splits, 4), code, valid)
+    got = jax.jit(lambda k, v, spl: gspmd.pyramid_gspmd_range(
+        k, mesh, spl, code_bits=2 * DZ, slot_bound=4,
+        pair_capacity=counts.pair_capacity,
+        recv_capacity=counts.recv_capacity, valid=v, levels=4,
+        capacity=2048, backend="partitioned"))(
+            ck, jnp.asarray(valid), jnp.asarray(splits))
+    want = pyramid.pyramid_sparse_morton(ck, valid=jnp.asarray(valid),
+                                         levels=4, capacity=2048)
+    _levels_equal(got, want)
+
+
+def test_gspmd_range_overflow_is_loud():
+    """Widths below the exact counts drop lanes, and the program says
+    so: every level's count is forced past its capacity, and the
+    counts' overflow_lanes reports the lanes past the widths."""
+    from heatmap_tpu.parallel import partition
+
+    mesh = make_mesh(devices=jax.devices()[:4])
+    code, valid, splits = _range_case("traces", 4, n=2048)
+    exact = partition.route_counts(_plan(splits, 4), code, valid)
+    for pair, recv in ((exact.pair_capacity - 1, exact.recv_capacity),
+                       (exact.pair_capacity, exact.recv_capacity - 1)):
+        forced = partition.RouteCounts(pairs=exact.pairs, pair_capacity=pair,
+                                       recv_capacity=recv)
+        assert forced.overflow_lanes > 0
+        out = jax.jit(lambda k, v, spl: gspmd.pyramid_gspmd_range(
+            k, mesh, spl, code_bits=2 * DZ, slot_bound=1,
+            pair_capacity=pair, recv_capacity=recv, valid=v, levels=3,
+            capacity=2048))(jnp.asarray(code), jnp.asarray(valid),
+                            jnp.asarray(splits))
+        assert all(int(n) > 2048 for _, _, n in out)
 
 
 # -- end-to-end byte identity ----------------------------------------------
@@ -173,6 +370,67 @@ def test_run_job_gspmd_weighted_byte_identical():
 def test_run_job_gspmd_pad_bucketing_byte_identical():
     _ab(_rows(n=1500, seed=5), pad_bucketing="pow2",
         spatial_partition="morton")
+
+
+def _walk_rows(n=800, seed=31, users=("alice", "bob", "rt-bus7", "xscout",
+                                      "carol")):
+    """Rows along seeded GPS-like walks: neighbouring fixes share tiles."""
+    rng = np.random.default_rng(seed)
+    walks = 8
+    start = np.column_stack([rng.uniform(40.0, 55.0, walks),
+                             rng.uniform(-5.0, 15.0, walks)])
+    steps = rng.normal(0.0, 2e-3, (walks, n // walks, 2))
+    path = (start[:, None, :] + np.cumsum(steps, axis=1)).reshape(-1, 2)
+    who = np.repeat(rng.integers(0, len(users), walks), n // walks)
+    return [{"latitude": float(lat), "longitude": float(lon),
+             "user_id": users[int(u)],
+             "timestamp": 1_500_000_000_000 + i}
+            for i, ((lat, lon), u) in enumerate(zip(path, who))]
+
+
+@pytest.mark.parametrize("ndev", [4, 8])
+def test_run_job_routed_matches_single_device_and_oracle(ndev, monkeypatch,
+                                                         tmp_path):
+    """run_job on a count job with data_parallel=True runs the owner-
+    routed program with its inputs spread over the mesh, and writes the
+    single-device cascade's blobs, which are the oracle's; the exchange
+    records its exact counts and no lane past its widths."""
+    import oracle
+    from heatmap_tpu.pipeline import cascade as cascade_mod
+
+    devs = jax.devices()[:ndev]
+    monkeypatch.setattr(jax, "local_devices", lambda *a, **k: devs)
+    real, seen = cascade_mod.run_cascade, []
+
+    def recording(codes, slots, *args, **kwargs):
+        seen.append((len(codes.sharding.device_set),
+                     kwargs.get("route_capacity")))
+        return real(codes, slots, *args, **kwargs)
+
+    monkeypatch.setattr(cascade_mod, "run_cascade", recording)
+    rows = _walk_rows()
+    path = str(tmp_path / "events.jsonl")
+    log = obs.EventLog(path)
+    obs.set_event_log(log)
+    try:
+        got = run_job(_ColSource(rows), config=_cfg(
+            spatial_partition="morton"))
+    finally:
+        obs.set_event_log(None)
+        log.close()
+    single = run_job(_ColSource(rows), config=_cfg(data_parallel=False))
+    want = oracle.run_job(rows, detail_zoom=DZ, min_detail_zoom=6,
+                          amplify_all=False)
+    assert got == single and len(got) > 0
+    assert got.keys() == want.keys()
+    assert all(json.loads(got[k]) == want[k] for k in want)
+    assert seen[0][0] == ndev and seen[0][1] is not None
+    route = [e for e in obs.read_events(path) if e["event"] == "mesh_route"]
+    assert len(route) == 1 and len(route[0]["lanes"]) == ndev
+    excluded = sum(r["user_id"].startswith("x") for r in rows)
+    assert sum(route[0]["lanes"]) == 2 * len(rows) - excluded
+    assert route[0]["overflow_lanes"] == 0
+    assert route[0]["recv_capacity"] >= max(route[0]["lanes"])
 
 
 def test_run_job_gspmd_morton_adaptive_composes():

@@ -225,3 +225,42 @@ def test_z21_cascade_compiles_for_v5e(one_chip, monkeypatch):
             capacity=n, backend="partitioned")
     finally:
         jax.clear_caches()
+
+
+#: The planet cell's job: 2^24 points, 2^25 emissions over a 2x2 v5e,
+#: 20,001 groups, the exchange widths its plans bucket to.
+MESH_POINTS = 1 << 24
+
+
+def test_routed_range_cascade_compiles_for_v5e_2x2(topo, one_chip,
+                                                   monkeypatch):
+    """The owner-routed Morton range program at 2^24 points, compiled
+    for four described v5e chips: it fits a chip's 16 GB and exchanges
+    lanes with an all-to-all (no replicated (ndev, n) operand)."""
+    from heatmap_tpu.ops import sparse_partitioned
+    from heatmap_tpu.parallel.mesh import DATA_AXIS, make_mesh, named_sharding
+    from heatmap_tpu.pipeline import bucketing
+    from heatmap_tpu.pipeline import cascade as cascade_mod
+
+    monkeypatch.setattr(sparse_partitioned, "default_interpret",
+                        lambda: False)
+    jax.clear_caches()
+    mesh = make_mesh(devices=topo.devices)
+    n = 2 * MESH_POINTS
+    rows = named_sharding(mesh, DATA_AXIS)
+    route = tuple(bucketing.bucket_size(m, "geometric")
+                  for m in (n // 16 + n // 400, n // 4 + n // 400))
+    ccfg = cascade_mod.CascadeConfig(detail_zoom=21, min_detail_zoom=5,
+                                     result_delta=5)
+    try:
+        compiled = _compile_for_chip(
+            cascade_mod._build_cascade_jit,
+            _shape(rows, n, jnp.int64), _shape(rows, n, jnp.int64),
+            config=ccfg, n_slots=20001, valid=_shape(rows, n, jnp.bool_),
+            capacity=n, backend="partitioned", mesh=mesh,
+            partition_splits=jax.ShapeDtypeStruct(
+                (3,), jnp.int64, sharding=named_sharding(mesh)),
+            dispatch="gspmd", route_capacity=route)
+        assert "all-to-all" in compiled.as_text()
+    finally:
+        jax.clear_caches()

@@ -36,6 +36,9 @@ _PAYLOADS = {
                           "skew_ratio": 1.08, "resplits": 0,
                           "degenerate": False, "fingerprint": "sha256:00",
                           "boundary_tiles": 6},
+    "mesh_route": {"lanes": [40, 38, 41, 39], "remote_lanes": 117,
+                   "pair_capacity": 4096, "recv_capacity": 4096,
+                   "overflow_lanes": 0},
     "device_memory": {"samples": []},
     "retry": {"shard": 3, "attempt": 1, "error": "RuntimeError('x')"},
     "recovery": {"shard": 3, "attempts": 2},
