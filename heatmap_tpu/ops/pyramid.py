@@ -1,6 +1,7 @@
 """Zoom-pyramid rollups: reshape-sums (dense) and Morton shifts (sparse).
 
-``pyramid_sparse_morton`` is the scatter-based production path;
+``pyramid_sparse_morton`` reduces each level from the previous one by a
+segmented scan and a compaction (ops/sparse.py, the "scatter" backend);
 ``pyramid_sparse_morton_partitioned`` is the count-only MXU
 reformulation (ops/sparse_partitioned.py) that reduces EVERY level
 from the original sorted point stream under ``key >> 2i`` — unit
@@ -93,10 +94,10 @@ def pyramid_sparse_morton(
     ``adaptive=True`` (EAGER callers only — it reads each level's true
     unique count from the device) shrinks every subsequent level's
     arrays to the next power of two above the previous level's unique
-    count. Per-level reductions cost two scatters over the level's
-    capacity, so on collapsing data this turns
-    ``levels * capacity`` scatter work into ~``2-3 * n_unique_0`` —
-    results are identical (the dropped slots are sentinel padding).
+    count. Each level's reduction is a scan and a compaction over its
+    input width, so on collapsing data this turns ``levels * capacity``
+    lanes of work into ~``2-3 * n_unique_0`` — results are identical
+    (the dropped slots are sentinel padding).
     Under jit the counts are tracers and this flag must stay False.
     """
     codes = jnp.asarray(codes)
@@ -161,8 +162,8 @@ def pyramid_sparse_morton_partitioned(
     Same contract as :func:`pyramid_sparse_morton` (keys int64 with
     int64-max sentinel padding, per-level capacities), but every level
     is reduced from the ORIGINAL sorted stream shifted by ``2*level``
-    — one sort, then ``levels+1`` kernel passes that replace the 2
-    scatters per level (ops/sparse_partitioned.py rationale). Every
+    — one sort, then ``levels+1`` kernel passes in place of a scan and
+    a compaction per level (ops/sparse_partitioned.py rationale). Every
     pass reads the same ``n``-lane stream, padded to whole chunk rows:
     ``slab`` caps the lanes one f32 accumulation sees, it is not a
     size every pass pads to. Counts

@@ -1,11 +1,12 @@
 """Partitioned-MXU segment reduction for the cascade's sorted streams.
 
-``aggregate_sorted_keys`` (ops/sparse.py) costs two scatters per
-cascade level — the sums ``.at[seg].add`` and the unique-keys
-``.at[seg].set`` — 32 scatters across a 16-level cascade. This module
-reformulates BOTH as one pass of the sort-partitioned one-hot-matmul
-machinery (ops/partitioned.py), exploiting that the cascade's inputs
-are already sorted:
+``aggregate_sorted_keys`` (ops/sparse.py) reduces each cascade level
+from the previous level's aggregates with a segmented scan and a
+compaction, in the weights' dtype, which the TPU emulates for
+float64. This module reduces EVERY level from the original sorted
+stream in one pass of the sort-partitioned one-hot-matmul machinery
+(ops/partitioned.py), on the MXU in f32, exploiting that the cascade's
+inputs are already sorted:
 
 - the segment index ``seg = cumsum(first) - 1`` is a sorted, dense
   cell id into [0, capacity) — exactly the stream shape the

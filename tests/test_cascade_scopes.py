@@ -1,8 +1,10 @@
 """The jitted cascade names its stages with ``jax.named_scope``: the scope
 path reaches every level's operations in the compiled program, down to
-the segment kernel's ``slab.channel`` and ``slab.tail``, so a device
-trace can split the cascade's time by stage."""
+the segment kernel's ``slab.channel`` and ``slab.tail`` and the scatter
+backend's ``segment.scan`` and ``segment.compact``, so a device trace
+can split the cascade's time by stage."""
 
+import functools
 import re
 
 import jax
@@ -16,7 +18,8 @@ CONFIG = cascade.CascadeConfig(detail_zoom=8, min_detail_zoom=2,
 N = 4096
 
 
-def compiled_op_names(backend: str, weighted: bool, capacity: int = N) -> set:
+@functools.lru_cache(maxsize=None)
+def compiled_text(backend: str, weighted: bool, capacity: int = N) -> str:
     spec = jax.ShapeDtypeStruct
     weights = spec((N,), jnp.float64) if weighted else None
     lowered = cascade._build_cascade_jit.lower(
@@ -24,7 +27,12 @@ def compiled_op_names(backend: str, weighted: bool, capacity: int = N) -> set:
         n_slots=4, valid=spec((N,), jnp.bool_), weights=weights,
         acc_dtype=jnp.float64 if weighted else None, capacity=capacity,
         backend=backend)
-    return set(re.findall(r'op_name="([^"]*)"', lowered.compile().as_text()))
+    return lowered.compile().as_text()
+
+
+def compiled_op_names(backend: str, weighted: bool, capacity: int = N) -> set:
+    return set(re.findall(r'op_name="([^"]*)"',
+                          compiled_text(backend, weighted, capacity)))
 
 
 def holds(names: set, path: str) -> bool:
@@ -55,3 +63,18 @@ def test_slab_scopes_sit_inside_the_cond_branches():
                             r"(jit\(\w+\)/)?" + re.escape(scope) + "/")
         assert any(inside.search(n) for n in names)
     assert not any("cond/branch" in n for n in names)
+
+
+@pytest.mark.parametrize("weighted", [True, False])
+def test_scatter_backend_reduces_each_level_without_a_scatter(weighted):
+    """Every level's segment reduce is a segmented scan and a compaction;
+    no scatter instruction sits under any of them."""
+    names = compiled_op_names("scatter", weighted)
+    for level in range(CONFIG.n_levels + 1):
+        reduce = f"cascade.level{level}/cascade.segment-reduce/"
+        assert holds(names, reduce + "segment.scan/")
+        assert holds(names, reduce + "segment.compact/")
+    scatters = [line for line in compiled_text("scatter", weighted).splitlines()
+                if re.search(r"\sscatter\(", line)
+                and "cascade.segment-reduce" in line]
+    assert not scatters

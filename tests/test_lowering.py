@@ -26,6 +26,7 @@ interpret-mode tests and by ``chip_smoke.py``'s byte comparisons.
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.export
@@ -36,6 +37,7 @@ import pytest
 from heatmap_tpu.ops.histogram import Window
 from heatmap_tpu.ops.pallas_kernels import bin_rowcol_window_pallas
 from heatmap_tpu.ops.partitioned import bin_rowcol_window_partitioned
+from heatmap_tpu.ops.sparse import aggregate_sorted_keys
 from heatmap_tpu.ops.sparse_partitioned import (
     aggregate_sorted_keys_partitioned,
 )
@@ -144,12 +146,13 @@ def _shape(sharding, n, dtype):
     return jax.ShapeDtypeStruct((n,), dtype, sharding=sharding)
 
 
-def _compile_for_chip(fn, *args, **kwargs):
+def _compile_for_chip(fn, *args, kernel: bool = True, **kwargs):
     """Compile ``fn`` for the described chip and check what the chip's
-    compiler made of it: a Mosaic kernel, within the device's memory."""
+    compiler made of it: a Mosaic kernel (where ``kernel``), within the
+    device's memory."""
     jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
     compiled = jitted.lower(*args, **kwargs).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert ("tpu_custom_call" in compiled.as_text()) == kernel
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
@@ -201,6 +204,18 @@ def test_segment_kernel_compiles_for_v5e(one_chip, variant):
                               capacity=n, interpret=False,
                               streams=4 if variant == "streams" else 1)
         _compile_for_chip(f, keys)
+
+
+def test_weighted_segment_reduce_compiles_for_v5e(one_chip):
+    """One level of the weighted (float64) cascade at the benchmark's
+    width, as the chip compiles it: a scan and a compaction with no
+    scatter, within the device's memory."""
+    n = BENCH_EMISSIONS
+    compiled = _compile_for_chip(
+        functools.partial(aggregate_sorted_keys, capacity=n),
+        _shape(one_chip, n, jnp.int64), _shape(one_chip, n, jnp.float64),
+        kernel=False)
+    assert not re.search(r"\sscatter\(", compiled.as_text())
 
 
 def test_z21_cascade_compiles_for_v5e(one_chip, monkeypatch):

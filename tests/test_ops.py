@@ -1,6 +1,7 @@
 """Tests for aggregation ops: dense histograms, sparse reduce, pyramids."""
 
 import collections
+import math
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,7 @@ from heatmap_tpu.ops import (
     pyramid_sparse_morton,
     window_from_bounds,
     aggregate_keys,
+    aggregate_sorted_keys,
 )
 from heatmap_tpu.tilemath import mercator, morton
 import oracle
@@ -208,6 +210,76 @@ def test_aggregate_keys_jit():
     fn = jax.jit(lambda k: aggregate_keys(k, capacity=8))
     uniq, sums, n = fn(jnp.asarray(np.array([2, 2, 7], np.int32)))
     assert int(n) == 2
+
+
+SEGMENT_CASES = ("fractional", "counts", "sentinel_lanes", "all_invalid",
+                 "one_segment", "n1", "capacity_below", "capacity_above")
+
+
+def _segment_case(case):
+    """-> (keys, weights or None, valid, capacity) for one contract case."""
+    rng = np.random.default_rng(sorted(SEGMENT_CASES).index(case))
+    n, distinct, capacity, dtype = 3000, 400, None, np.int64
+    if case == "counts":
+        dtype = np.int32
+    elif case == "one_segment":
+        distinct = 1
+    elif case == "n1":
+        n = 1
+    elif case == "capacity_below":
+        capacity = 100
+    elif case == "capacity_above":
+        capacity = 2 * n
+    keys = rng.integers(0, distinct, n).astype(dtype) * 7 + 3
+    # Day-scale dwell seconds: fractional, spread over four decades.
+    weights = None if case == "counts" else rng.exponential(30.0, n) * 10.0 ** (
+        rng.integers(-2, 2, n))
+    valid = np.ones(n, bool)
+    if case in ("sentinel_lanes", "counts"):
+        valid = rng.random(n) < 0.8
+    elif case == "all_invalid":
+        valid[:] = False
+    return keys, weights, valid, n if capacity is None else capacity
+
+
+@pytest.mark.parametrize("entry", ["aggregate_keys", "aggregate_sorted_keys"])
+@pytest.mark.parametrize("case", SEGMENT_CASES)
+def test_segment_reduce_contract(case, entry):
+    """Both entry points against a numpy oracle that sums each segment
+    with ``math.fsum``: ascending uniques, sentinel key and zero sum past
+    ``n_unique``, the first ``capacity`` segments kept when there are
+    more, ``n_unique`` the true count, and masked lanes adding nothing."""
+    keys, weights, valid, capacity = _segment_case(case)
+    sentinel = np.iinfo(keys.dtype).max
+    acc = np.int32 if weights is None else np.float64
+    w = np.ones(len(keys), acc) if weights is None else weights
+    if entry == "aggregate_keys":
+        uniq, sums, n = aggregate_keys(
+            keys, weights=weights, valid=valid, capacity=capacity,
+            acc_dtype=acc)
+    else:
+        # Masked lanes become sentinel keys carrying a weight they must
+        # not add anywhere.
+        order = np.argsort(np.where(valid, keys, sentinel), kind="stable")
+        sk = np.where(valid, keys, sentinel)[order]
+        sw = np.where(valid, w, 1000)[order]
+        uniq, sums, n = aggregate_sorted_keys(
+            jnp.asarray(sk), jnp.asarray(sw.astype(acc)), capacity)
+    uniq, sums = np.asarray(uniq), np.asarray(sums)
+
+    real = keys[valid]
+    want_u = np.unique(real)
+    want_s = [math.fsum(w[valid][real == u]) for u in want_u[:capacity]]
+    kept = min(len(want_u), capacity)
+    assert int(n) == len(want_u)
+    assert uniq.shape == sums.shape == (capacity,)
+    assert sums.dtype == acc
+    np.testing.assert_array_equal(uniq[:kept], want_u[:capacity])
+    assert (uniq[kept:] == sentinel).all() and (sums[kept:] == 0).all()
+    if weights is None:
+        np.testing.assert_array_equal(sums[:kept], want_s)
+    else:
+        np.testing.assert_allclose(sums[:kept], want_s, rtol=1e-12, atol=0)
 
 
 # -- sparse morton pyramid -------------------------------------------------
