@@ -34,8 +34,8 @@ which is why the slice runs on the scatter cascade.
 
 ``--chips 4`` runs only the four-chip phase: ``run_job`` on 2^20
 points of the same source, on the scatter cascade, with
-``data_parallel=True, dispatch="gspmd"`` and
-``spatial_partition`` "morton" then "off", each byte-compared with
+``data_parallel=True`` and ``spatial_partition`` "morton" then "off"
+(the gspmd range and uniform programs), each byte-compared with
 ``data_parallel=False`` on device 0. It also checks that the
 cascade's inputs arrived spread over the chips, and reports whether the
 cascade consumed (donated) its input buffers; buffer donation engages
@@ -451,7 +451,7 @@ def run_four_chips(work: str, clock: PhaseClock, n: int = FOUR_CHIP_POINTS,
 
     def data_parallel(partition, placements):
         return job(f"dp_{partition}", placements, data_parallel=True,
-                   dispatch="gspmd", spatial_partition=partition)
+                   spatial_partition=partition)
 
     with cascade_placements() as placements, \
             concurrent.futures.ThreadPoolExecutor(1) as pool:

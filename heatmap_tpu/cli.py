@@ -156,14 +156,6 @@ def _add_run_flags(p):
                    "single-device cascade. Blobs are identical either "
                    "way (counts bit-exact; fractional weighted sums up "
                    "to f64 summation order)")
-    p.add_argument("--dp-merge", choices=("replicated", "prefix"),
-                   default="replicated",
-                   help="data-parallel cascade merge: replicated "
-                   "(default; every device re-reduces the gathered "
-                   "partials) or prefix (coarse-prefix all_to_all "
-                   "regroup; each device merges and rolls up only its "
-                   "keyspan range — O(uniques/k) per stage, the shape "
-                   "for unique-heavy data). Blobs identical either way")
     p.add_argument("--dp-min-emissions", type=int, default=None,
                    metavar="N",
                    help="auto-DP engagement threshold (emissions per "
@@ -182,16 +174,6 @@ def _add_run_flags(p):
                    "on real volume; morton forces it; off pins the "
                    "uniform round-robin dispatch. Blobs byte-identical "
                    "in every mode")
-    p.add_argument("--dispatch", choices=("auto", "gspmd", "shard_map"),
-                   default="auto",
-                   help="how the data-parallel cascade is dispatched: "
-                   "gspmd runs the whole cascade (routing, rollup, "
-                   "boundary merge, egress ordering) as ONE compiled "
-                   "program over a NamedSharding mesh with no host "
-                   "round-trips (docs/gspmd.md); shard_map keeps the "
-                   "per-stage host-routed dispatch as a differential-"
-                   "testing oracle. auto (default) picks gspmd wherever "
-                   "a program exists. Blobs byte-identical either way")
     p.add_argument("--fast", action="store_true",
                    help="force the integer-only native-decoder path "
                    "(csv/hmpb sources; dated timespans use the i64 "
@@ -447,10 +429,8 @@ def cmd_run(args) -> int:
             cascade_backend=args.cascade_backend,
             data_parallel={"auto": None, "on": True, "off": False}[
                 args.data_parallel],
-            dp_merge=args.dp_merge,
             dp_min_emissions=args.dp_min_emissions,
             spatial_partition=args.spatial_partition,
-            dispatch=args.dispatch,
         )
     except ValueError as e:
         raise SystemExit(str(e)) from e
@@ -1433,11 +1413,6 @@ def _add_update_flags(p):
                    choices=("auto", "scatter", "partitioned"))
     p.add_argument("--data-parallel", choices=("auto", "on", "off"),
                    default="auto")
-    p.add_argument("--dispatch", choices=("auto", "gspmd", "shard_map"),
-                   default="auto",
-                   help="data-parallel cascade dispatch (docs/gspmd.md); "
-                   "auto picks the one-program gspmd path wherever it "
-                   "exists")
     p.add_argument("--metrics-dir", default=None, metavar="DIR",
                    help="enable the metrics registry and write "
                    "DIR/metrics.prom at command end")
@@ -1497,7 +1472,6 @@ def cmd_update(args) -> int:
                 cascade_backend=args.cascade_backend,
                 data_parallel={"auto": None, "on": True, "off": False}[
                     args.data_parallel],
-                dispatch=args.dispatch,
             )
         except ValueError as e:
             raise SystemExit(str(e)) from e
@@ -1739,11 +1713,6 @@ def _add_ingest_flags(p):
                    choices=("auto", "scatter", "partitioned"))
     p.add_argument("--data-parallel", choices=("auto", "on", "off"),
                    default="auto")
-    p.add_argument("--dispatch", choices=("auto", "gspmd", "shard_map"),
-                   default="auto",
-                   help="data-parallel cascade dispatch (docs/gspmd.md); "
-                   "auto picks the one-program gspmd path wherever it "
-                   "exists")
     p.add_argument("--metrics-dir", default=None, metavar="DIR",
                    help="enable the metrics registry and write "
                    "DIR/metrics.prom at command end")
@@ -1792,7 +1761,6 @@ def cmd_ingest(args) -> int:
             cascade_backend=args.cascade_backend,
             data_parallel={"auto": None, "on": True, "off": False}[
                 args.data_parallel],
-            dispatch=args.dispatch,
             pad_bucketing=args.pad_bucketing,
             pad_bucket_min=args.pad_bucket_min,
         )
@@ -1977,8 +1945,6 @@ def _add_writeplane_flags(p):
                    choices=("auto", "scatter", "partitioned"))
     p.add_argument("--data-parallel", choices=("auto", "on", "off"),
                    default="auto")
-    p.add_argument("--dispatch", choices=("auto", "gspmd", "shard_map"),
-                   default="auto")
     p.add_argument("--metrics-dir", default=None, metavar="DIR",
                    help="enable the metrics registry and write "
                    "DIR/metrics.prom at command end")
@@ -2021,7 +1987,6 @@ def cmd_writeplane(args) -> int:
             cascade_backend=args.cascade_backend,
             data_parallel={"auto": None, "on": True, "off": False}[
                 args.data_parallel],
-            dispatch=args.dispatch,
             pad_bucketing=args.pad_bucketing,
             pad_bucket_min=args.pad_bucket_min,
         )

@@ -35,9 +35,8 @@ EVENT_SCHEMA = {
                   "optional": ("items", "bytes", "backend", "level",
                                "window", "trace_id", "span_id")},
     # Job-level routing decision: how cascade_backend="auto" resolved.
-    # ``dispatch`` records how the mesh formulation resolved ("gspmd"
-    # one-program NamedSharding vs "shard_map" oracle — pipeline/batch
-    # resolved_dispatch), so dispatcher routing stays auditable.
+    # ``dispatch`` names the mesh formulation that runs when the mesh
+    # engages ("gspmd" — pipeline/batch resolved_dispatch).
     "backend_resolved": {"required": ("requested", "resolved"),
                          "optional": ("reason", "weighted", "data_parallel",
                                       "n_emissions", "spatial_partition",
@@ -45,7 +44,7 @@ EVENT_SCHEMA = {
     # Per-call cascade dispatch record (the audit trail behind
     # backend_resolved: what run_cascade actually executed).
     "cascade_dispatch": {"required": ("backend",),
-                         "optional": ("jit", "mesh", "merge", "n_emissions",
+                         "optional": ("jit", "mesh", "n_emissions",
                                       "n_slots", "trace_id", "span_id",
                                       "partition", "dispatch")},
     # The partitioned segment kernel (ops/sparse_partitioned.py), once

@@ -39,15 +39,11 @@ from heatmap_tpu.parallel.sharded import (  # noqa: F401
     bin_points_replicated,
     bin_points_rowsharded,
     pyramid_rowsharded,
-    pyramid_sparse_morton_prefix_sharded,
-    pyramid_sparse_morton_range_sharded,
-    pyramid_sparse_morton_sharded,
     splat_rowsharded,
 )
 from heatmap_tpu.parallel.partition import (  # noqa: F401
     PartitionPlan,
     plan_partition,
-    route_emissions,
 )
 from heatmap_tpu.parallel.multihost import (  # noqa: F401
     StragglerTimeout,

@@ -1,33 +1,28 @@
 """Device-resident GSPMD cascade: global-view NamedSharding programs.
 
-The shard_map kernels in parallel/sharded.py are hand-routed: the host
-pads and routes emissions before the kernel (parallel/partition.
-route_emissions), and per-shard buffer widths are derived from the
-routed segment length. This module re-expresses the same two cascade
-pyramids — uniform data-parallel and Morton-range partitioned — as
-programs that take the emission stream spread over the mesh
-(:func:`point_sharding`), so the WHOLE cascade — emission routing,
-range-local rollup, boundary merge, and canonical egress ordering —
-is one compiled program with no host round-trips between stages:
+The data-parallel cascade's two pyramids — uniform data-parallel and
+Morton-range partitioned — as programs that take the emission stream
+spread over the mesh (:func:`point_sharding`), so the WHOLE cascade —
+emission routing, range-local rollup, boundary merge, and canonical
+egress ordering — is one compiled program with no host round-trips
+between stages:
 
 - the uniform program is global view: every per-shard stage is a
   ``vmap`` over a leading ``(n_shards,)`` axis pinned to the mesh's
-  point axes, reducing contiguous 1/n_shards slices exactly like the
-  shard_map body;
+  point axes, reducing contiguous 1/n_shards slices; the merge and
+  rollup run over the flattened compact partials;
 - the range program routes on-device against a TRACED splits array:
   each lane goes to the shard that owns its detail code through one
   ``all_to_all`` whose static widths come from the plan's exact lane
   counts (partition.route_counts), so each chip holds and scans only
   its own lanes. Traced splits are also what let ``adaptive_capacity``
-  compose with Morton partitioning (the host router is shape-static).
-  Its boundary merge and canonical egress run in the same shard_map
-  body, and its only sort is the detail stage's own.
+  compose with Morton partitioning. Its boundary merge and canonical
+  egress run in the same shard_map body, and its only sort is the
+  detail stage's own.
 
-Byte identity with the shard_map kernels is the contract (pinned by
-tests/test_gspmd.py and the chaos ``dispatch`` phase): counts and
-bounded-integer weighted sums are exact in any summation order, and
-float64 weighted sums accumulate per key in original lane order on
-both paths (stable sorts, and the exchange keeps lane order).
+Byte identity with the single-device cascade is the contract (pinned
+by tests/test_gspmd.py and the chaos ``dispatch`` phase): counts and
+bounded-integer weighted sums are exact in any summation order.
 
 Donation: `donating_jit` adds ``donate_argnums`` where the platform
 supports in-place donation (TPU/GPU) and drops it where it does not
@@ -46,17 +41,17 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh
 
-from heatmap_tpu.ops import pyramid as pyramid_ops
+from heatmap_tpu.ops import (
+    pyramid as pyramid_ops,
+    sparse as sparse_ops,
+    sparse_partitioned,
+)
 from heatmap_tpu.parallel.mesh import (
     DATA_AXIS,
     TILE_AXIS,
+    _shard_axes,
     named_sharding,
     shard_map,
-)
-from heatmap_tpu.parallel.sharded import (
-    _local_detail_stage,
-    _ones_like_weights,
-    _shard_axes,
 )
 
 __all__ = [
@@ -81,7 +76,7 @@ __all__ = [
 def _point_spec(mesh: Mesh):
     """The PartitionSpec leading-axis entry the point-parallel programs
     shard their ``(n_shards, ...)`` layout over — the NamedSharding
-    analog of sharded._shard_axes (tile==1 keeps the single data axis,
+    analog of mesh._shard_axes (tile==1 keeps the single data axis,
     else the leading axis flattens over both)."""
     axes, ndev = _shard_axes(mesh)
     return (axes[0] if len(axes) == 1 else tuple(axes)), ndev
@@ -92,6 +87,56 @@ def point_sharding(mesh: Mesh):
     the mesh's point axes in contiguous per-chip blocks."""
     spec, _ = _point_spec(mesh)
     return named_sharding(mesh, spec)
+
+
+def _ones_like_weights(weights, n, dtype):
+    return jnp.ones((n,), dtype) if weights is None else jnp.asarray(weights, dtype)
+
+
+def _local_detail_stage(backend, counts_only, local_capacity, acc_dtype,
+                        sentinel, weight_bound=None):
+    """The per-shard reduce-by-key of the uniform program's detail
+    level: "scatter" (ops/sparse.py sort + segment reduce) or
+    "partitioned" (sort + the multi-channel MXU segment kernel,
+    ops/sparse_partitioned.py). Both return the same compact
+    (unique[cap], sums[cap], n_unique) contract — sorted uniques,
+    sentinel/zero padding, n_unique past capacity on overflow — so the
+    merge and rollup are backend-agnostic and results stay
+    bit-identical (counts and bounded-integer weighted sums are exact
+    in any summation order)."""
+    if backend == "scatter":
+        def stage(k, w, v):
+            return sparse_ops.aggregate_keys(
+                k, weights=w, valid=v, capacity=local_capacity,
+                acc_dtype=acc_dtype,
+            )
+        return stage
+    if backend != "partitioned":
+        raise ValueError(f"unknown cascade backend {backend!r}")
+
+    def stage(k, w, v):
+        masked = jnp.where(v, k, sentinel)
+        if counts_only:
+            # Unstable sort: equal keys are indistinguishable payloads.
+            u, s, n = sparse_partitioned.aggregate_sorted_keys_partitioned(
+                jnp.sort(masked), local_capacity, sentinel=sentinel,
+            )
+        else:
+            order = jnp.argsort(masked, stable=True)
+            u, s, n = sparse_partitioned.aggregate_sorted_keys_partitioned(
+                masked[order], local_capacity, sentinel=sentinel,
+                sorted_weights=w[order], weight_bound=weight_bound,
+            )
+        # The kernel upcasts keys (and its sentinel pad) to int64; the
+        # stage contract is scatter's — uniques in the INPUT dtype.
+        # The merged rollup derives its pad sentinel from the array
+        # dtype, so an int64 partial from int32 keys would make it
+        # treat int64-max pad lanes as real keys (they no longer equal
+        # the int32-max sentinel). Real keys and the sentinel both fit
+        # the input dtype by construction, so the cast is lossless.
+        return u.astype(k.dtype), s.astype(acc_dtype), n
+
+    return stage
 
 
 def _mapped_stage(stage, mesh: Mesh, spec, backend: str):
@@ -151,7 +196,7 @@ def route_on_device(keys, valid, splits, weights=None, *, code_bits: int,
 
     Each local lane goes to the shard that owns its detail code (the
     low ``code_bits`` bits of its key): ``#{splits <= code}`` against
-    the traced splits, the host router's convention
+    the traced splits, the planner's convention
     (partition.PartitionPlan.shard_of_codes). The chip packs the
     lanes bound for shard ``d`` into row ``d`` of a fixed
     ``pair_capacity``-wide send buffer, in lane order (a running count
@@ -326,23 +371,21 @@ def pyramid_gspmd_uniform(
     weight_bound: int | None = None,
     adaptive: bool = False,
 ):
-    """Global-view uniform-DP sparse pyramid, byte-identical to
-    :func:`parallel.sharded.pyramid_sparse_morton_sharded`.
+    """Global-view uniform-DP sparse pyramid: counts and
+    bounded-integer weighted sums byte-identical to the single-device
+    ``ops.pyramid.pyramid_sparse_morton``.
 
-    Same staging as the shard_map kernel — per-shard detail reduce,
-    merge + rollup over the flattened compact partials — but the shard
+    Each shard reduces its contiguous 1/n_shards slice at the detail
+    zoom (at most ``min(caps[0], n // n_shards)`` uniques), then the
+    merge + rollup run over the flattened compact partials. The shard
     axis is an explicit leading dimension constrained to the mesh's
-    point axes rather than a shard_map body, so the whole pyramid is
-    one partitionable program (jit it together with projection and
-    egress). Per-shard buffer widths reuse the shard_map formulas
-    exactly so the merged partial stream is element-identical.
+    point axes, so the whole pyramid is one partitionable program (jit
+    it together with projection and egress).
 
     ``adaptive`` (EAGER callers only, like ops.pyramid) forwards to the
     merged rollup: deep levels shrink to the real unique counts. The
-    shard_map path cannot take this flag (its widths are baked into
-    the body specs); here the rollup runs on the global view, so the
-    composition is free — and result-neutral, the dropped slots are
-    sentinel padding.
+    rollup runs on the global view, so the composition is free — and
+    result-neutral, the dropped slots are sentinel padding.
     """
     spec, ndev = _point_spec(mesh)
     codes = jnp.asarray(codes)

@@ -22,6 +22,18 @@ def shard_map(body, mesh, in_specs, out_specs, check_vma: bool = True):
                          out_specs=out_specs, check_vma=check_vma)
 
 
+def _shard_axes(mesh: Mesh):
+    """(axis names, total shards) the point-parallel kernels span.
+
+    tile == 1 keeps the single ``data`` axis; tile > 1 flattens points
+    over (data, tile) so a 2D mesh still uses every device — the tile
+    axis only becomes *spatial* in sharded.bin_points_bandsharded.
+    """
+    if mesh.shape[TILE_AXIS] == 1:
+        return (DATA_AXIS,), mesh.shape[DATA_AXIS]
+    return (DATA_AXIS, TILE_AXIS), mesh.shape[DATA_AXIS] * mesh.shape[TILE_AXIS]
+
+
 def named_sharding(mesh, *spec):
     """``NamedSharding(mesh, PartitionSpec(*spec))`` — the annotation the
     GSPMD cascade (parallel/gspmd.py) puts on its global-view arrays."""

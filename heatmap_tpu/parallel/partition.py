@@ -68,8 +68,8 @@ class PartitionPlan:
 
     ``splits`` are sorted detail codes; a code belongs to shard
     ``k = #{splits <= code}`` (a split opens the range to its right —
-    the single ownership convention shared with the router and the
-    kernel). Duplicate splits are legal and denote empty ranges.
+    the single ownership convention shared with ``route_counts`` and
+    the on-device router, gspmd.route_on_device). Duplicate splits are legal and denote empty ranges.
     """
 
     detail_zoom: int
@@ -307,46 +307,3 @@ def route_counts(plan: PartitionPlan, codes, valid=None,
                        pair_capacity=widest_pair,
                        recv_capacity=widest_owner)
 
-
-def route_emissions(plan: PartitionPlan, codes, slots, valid=None,
-                    weights=None, bucket=None):
-    """Scatter emission lanes into per-shard contiguous segments.
-
-    Returns ``(codes, slots, valid, weights, seg_len)`` where each
-    array is ``(n_shards * seg_len,)`` and shard ``k``'s lanes occupy
-    ``[k*seg_len, (k+1)*seg_len)``; pad lanes are ``valid=False`` —
-    the masking path every cascade kernel already drops. Invalid input
-    lanes are dropped here (they carry garbage codes that would skew a
-    shard's segment for no output). ``bucket`` maps the raw max shard
-    count to a padded segment length so per-range shapes hit the
-    bucketed compile cache.
-    """
-    codes = np.asarray(codes, np.int64)
-    slots = np.asarray(slots)
-    v_mask = (np.ones(len(codes), bool) if valid is None
-              else np.asarray(valid, bool))
-    w = None if weights is None else np.asarray(weights)
-    P = plan.n_shards
-
-    src = np.flatnonzero(v_mask)
-    sid = plan.shard_of_codes(codes[src])
-    order = np.argsort(sid, kind="stable")
-    src, sid = src[order], sid[order]
-    counts = np.bincount(sid, minlength=P)
-    seg = max(int(counts.max()) if len(counts) else 0, 1)
-    if bucket is not None:
-        seg = max(int(bucket(seg)), seg)
-    starts = np.concatenate(([0], np.cumsum(counts)))
-    dst = sid * seg + (np.arange(len(src)) - starts[sid])
-
-    out_codes = np.zeros(P * seg, codes.dtype)
-    out_slots = np.zeros(P * seg, slots.dtype)
-    out_valid = np.zeros(P * seg, bool)
-    out_codes[dst] = codes[src]
-    out_slots[dst] = slots[src]
-    out_valid[dst] = True
-    out_w = None
-    if w is not None:
-        out_w = np.zeros(P * seg, w.dtype)
-        out_w[dst] = w[src]
-    return out_codes, out_slots, out_valid, out_w, seg

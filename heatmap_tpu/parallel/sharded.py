@@ -19,17 +19,12 @@ parallelism: an ``all_to_all`` regroups points so each device only
 ever materializes its own raster band — the groupByKey analog for
 rasters too big for one device's HBM.
 
-The two cascade pyramids here (uniform and Morton-range) also exist as
-global-view NamedSharding programs in parallel/gspmd.py — one compiled
-program with on-device routing, byte-identical outputs (pinned by
-tests/test_gspmd.py). This shard_map formulation stays selectable via
-``dispatch="shard_map"`` as the differential-testing oracle.
+The data-parallel cascade's pyramids live in parallel/gspmd.py; no
+cascade path imports this module.
 """
 
 from __future__ import annotations
 
-
-import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
@@ -38,73 +33,15 @@ from heatmap_tpu.ops import (
     histogram,
     pyramid as pyramid_ops,
     sparse as sparse_ops,
-    sparse_partitioned,
 )
-from heatmap_tpu.parallel.mesh import DATA_AXIS, TILE_AXIS, shard_map
+from heatmap_tpu.parallel.gspmd import _ones_like_weights
+from heatmap_tpu.parallel.mesh import (
+    DATA_AXIS,
+    TILE_AXIS,
+    _shard_axes,
+    shard_map,
+)
 from heatmap_tpu.tilemath import mercator
-
-
-def _shard_axes(mesh: Mesh):
-    """(axis names, total shards) the point-parallel kernels span.
-
-    tile == 1 keeps the single ``data`` axis; tile > 1 flattens points
-    over (data, tile) so a 2D mesh still uses every device — the tile
-    axis only becomes *spatial* in bin_points_bandsharded.
-    """
-    if mesh.shape[TILE_AXIS] == 1:
-        return (DATA_AXIS,), mesh.shape[DATA_AXIS]
-    return (DATA_AXIS, TILE_AXIS), mesh.shape[DATA_AXIS] * mesh.shape[TILE_AXIS]
-
-
-def _ones_like_weights(weights, n, dtype):
-    return jnp.ones((n,), dtype) if weights is None else jnp.asarray(weights, dtype)
-
-
-def _local_detail_stage(backend, counts_only, local_capacity, acc_dtype,
-                        sentinel, weight_bound=None):
-    """The per-device reduce-by-key the sharded pyramids run inside
-    their shard_map bodies: "scatter" (ops/sparse.py sort +
-    segment-scatter) or "partitioned" (sort + the multi-channel MXU
-    segment kernel, ops/sparse_partitioned.py). Both return the same
-    compact (unique[cap], sums[cap], n_unique) contract — sorted
-    uniques, sentinel/zero padding, n_unique past capacity on overflow
-    — so the cross-device merge and rollup are backend-agnostic and
-    results stay bit-identical (counts and bounded-integer weighted
-    sums are exact in any summation order)."""
-    if backend == "scatter":
-        def stage(k, w, v):
-            return sparse_ops.aggregate_keys(
-                k, weights=w, valid=v, capacity=local_capacity,
-                acc_dtype=acc_dtype,
-            )
-        return stage
-    if backend != "partitioned":
-        raise ValueError(f"unknown cascade backend {backend!r}")
-
-    def stage(k, w, v):
-        masked = jnp.where(v, k, sentinel)
-        if counts_only:
-            # Unstable sort: equal keys are indistinguishable payloads.
-            u, s, n = sparse_partitioned.aggregate_sorted_keys_partitioned(
-                jnp.sort(masked), local_capacity, sentinel=sentinel,
-            )
-        else:
-            order = jnp.argsort(masked, stable=True)
-            u, s, n = sparse_partitioned.aggregate_sorted_keys_partitioned(
-                masked[order], local_capacity, sentinel=sentinel,
-                sorted_weights=w[order], weight_bound=weight_bound,
-            )
-        # The kernel upcasts keys (and its sentinel pad) to int64; the
-        # stage contract is scatter's — uniques in the INPUT dtype.
-        # Downstream re-reductions derive their pad sentinel from the
-        # array dtype, so an int64 partial from int32 keys would make
-        # the prefix merge's rollup treat int64-max pad lanes as real
-        # keys (they no longer equal the int32-max sentinel). Real
-        # keys and the sentinel both fit the input dtype by
-        # construction, so the cast is lossless.
-        return u.astype(k.dtype), s.astype(acc_dtype), n
-
-    return stage
 
 
 def bin_points_replicated(
@@ -310,474 +247,6 @@ def aggregate_keys_sharded(
     local_overflow = (gn > local_capacity).any()
     mn = jnp.where(local_overflow, jnp.maximum(mn, capacity + 1), mn)
     return mu, ms, mn
-
-
-def pyramid_sparse_morton_sharded(
-    codes,
-    mesh: Mesh,
-    weights=None,
-    valid=None,
-    levels: int = 0,
-    capacity=None,
-    acc_dtype=None,
-    backend: str = "scatter",
-    weight_bound: int | None = None,
-):
-    """Sharded sparse pyramid: merge detail level once, then roll up.
-
-    Each device reduces its shard at detail zoom; one all_gather merges
-    the compact per-device results; the full pyramid then rolls up from
-    the merged (already sorted) uniques via Morton shifts — replicated,
-    since post-merge work is O(levels * capacity), tiny next to binning.
-
-    ``capacity`` may be an int (same for all levels) or a per-level
-    list, as in ops.pyramid.pyramid_sparse_morton — the composite-key
-    cascade passes its zoom-clamped per-level capacities through here
-    (pipeline/cascade.py build_cascade with a mesh). The per-device
-    detail stage is sized by ``min(caps[0], shard rows)``: a shard's
-    distinct keys are a subset of the global distinct keys, so a global
-    capacity that holds the data also holds every shard.
-
-    ``backend`` routes the per-device detail reduction (the hot stage —
-    everything after it is O(capacity)): "scatter" or "partitioned"
-    (see _local_detail_stage; weighted partitioned needs the
-    bounded-integer ``weight_bound`` contract, enforced upstream by
-    pipeline/cascade.py). The merge + rollup stay on the scatter ops
-    either way: they run over compact partials where the MXU kernel
-    has nothing to win, and re-aggregating sums as weights is exactly
-    the shape the partitioned slab bound does not cover.
-    """
-    axes, ndev = _shard_axes(mesh)
-    codes = jnp.asarray(codes)
-    n = codes.shape[0]
-    caps = pyramid_ops._level_caps(capacity, n, levels)
-    local_capacity = max(1, min(caps[0], n // ndev))
-    if acc_dtype is None:
-        acc_dtype = jnp.int32 if weights is None else jnp.float32
-    counts_only = weights is None
-    w = _ones_like_weights(weights, n, acc_dtype)
-    v = jnp.ones((n,), bool) if valid is None else jnp.asarray(valid, bool)
-    sentinel = jnp.iinfo(codes.dtype).max
-    stage = _local_detail_stage(backend, counts_only, local_capacity,
-                                acc_dtype, sentinel,
-                                weight_bound=weight_bound)
-
-    def body(k, w, v):
-        u, s, local_n = stage(k, w, v)
-        return u, s, local_n[None]
-
-    # Same structure as aggregate_keys_sharded: vma-checked sharded
-    # stage -> per-device compact partials, merge + rollup outside as
-    # plain jit ops on the global arrays. The partitioned stage's
-    # pallas_call outputs carry no varying-mesh-axes metadata, so the
-    # vma check only holds for the scatter body (same rationale as
-    # bin_points_replicated); equality vs the single-device cascade is
-    # pinned by tests/test_parallel.py either way.
-    fn = shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(P(axes), P(axes), P(axes)),
-        out_specs=(P(axes), P(axes), P(axes)),
-        check_vma=backend == "scatter",
-    )
-    gu, gs, gn = fn(codes, w, v)
-    out = pyramid_ops.pyramid_sparse_morton(
-        gu,
-        weights=gs,
-        valid=gu != sentinel,
-        levels=levels,
-        capacity=caps,
-        acc_dtype=acc_dtype,
-    )
-    # Propagate per-device overflow into every level's n_unique so the
-    # ops/sparse.py overflow contract holds (see aggregate_keys_sharded).
-    local_overflow = (gn > local_capacity).any()
-    return [
-        (
-            lu,
-            ls,
-            jnp.where(local_overflow, jnp.maximum(ln, caps[lvl] + 1), ln),
-        )
-        for lvl, (lu, ls, ln) in enumerate(out)
-    ]
-
-
-def pyramid_sparse_morton_range_sharded(
-    codes,
-    mesh: Mesh,
-    splits,
-    *,
-    code_bits: int,
-    slot_bound: int,
-    weights=None,
-    valid=None,
-    levels: int = 0,
-    capacity=None,
-    acc_dtype=None,
-    backend: str = "scatter",
-    weight_bound: int | None = None,
-):
-    """Range-sharded sparse pyramid: local rollup, boundary-only merge.
-
-    Inputs are PRE-ROUTED host-side by a Morton partition plan
-    (parallel/partition.route_emissions): shard ``k``'s contiguous
-    block holds only composite keys whose detail Morton code lies in
-    range ``k`` of ``splits`` (``#{splits <= code} == k``). Because the
-    pyramid parent is ``code >> 2`` (order-preserving), each shard's
-    rollup is entirely local except for *boundary tiles* — parents
-    whose children straddle a split code. There are at most
-    ``len(splits)`` such codes per level globally and at most 2 per
-    shard (a shard's local keys live in tiles intersecting its own
-    contiguous range, and the only straddling tiles that can intersect
-    it are the ones covering its two endpoints), so the cross-chip
-    exchange is an all_gather of ``<= 2 * slot_bound`` rows per shard
-    per level instead of full-pyramid partials.
-
-    Boundary merge is patch-then-rollup: at every coarse level each
-    shard extracts its boundary rows, all_gathers them, and the FIRST
-    holder (lowest gathered block that holds the key) replaces its
-    partial with the cross-shard total while every other holder drops
-    its row — each key then lives on exactly one shard again, so the
-    next level's local rollup stays exact by induction. Totals are
-    plain sums of the gathered partials, exact in any order for counts
-    and bounded-integer weighted sums (the same contract every other
-    merge in this file relies on).
-
-    ``splits`` is a TRACED ``(n_shards - 1,)`` int array of detail
-    Morton codes (code part only, no slot bits) so every plan shares
-    one compilation; ``code_bits = 2 * detail_zoom`` and ``slot_bound``
-    (the slot vocabulary size) are static. Final per-level results are
-    compacted to canonical sorted order outside the shard_map (keys are
-    globally disjoint, so a global argsort + truncate reproduces the
-    replicated path's arrays byte-for-byte).
-    """
-    axes, ndev = _shard_axes(mesh)
-    codes = jnp.asarray(codes)
-    n = codes.shape[0]
-    if n % ndev != 0:
-        raise ValueError(
-            f"range-sharded cascade needs n % n_shards == 0, got "
-            f"{n} % {ndev} (the host router pads each segment)")
-    splits = jnp.asarray(splits)
-    if splits.shape != (ndev - 1,):
-        raise ValueError(
-            f"need {ndev - 1} split codes for {ndev} shards, got "
-            f"shape {splits.shape}")
-    caps = pyramid_ops._level_caps(capacity, n, levels)
-    local_capacity = max(1, min(caps[0], n // ndev))
-    lcaps = [max(1, min(caps[lvl], local_capacity))
-             for lvl in range(levels + 1)]
-    # Per-shard boundary rows: <= 2 straddling tiles x slot_bound slots
-    # (docstring argument); clamped to the level's local width.
-    bcaps = [max(1, min(lcaps[lvl], 2 * slot_bound))
-             for lvl in range(levels + 1)]
-    if acc_dtype is None:
-        acc_dtype = jnp.int32 if weights is None else jnp.float32
-    counts_only = weights is None
-    w = _ones_like_weights(weights, n, acc_dtype)
-    v = jnp.ones((n,), bool) if valid is None else jnp.asarray(valid, bool)
-    sentinel = jnp.iinfo(codes.dtype).max
-    stage = _local_detail_stage(backend, counts_only, local_capacity,
-                                acc_dtype, sentinel,
-                                weight_bound=weight_bound)
-
-    def body(k, w, v, spl):
-        me = lax.axis_index(axes[0])
-        for ax in axes[1:]:
-            me = me * mesh.shape[ax] + lax.axis_index(ax)
-        spl = spl.astype(k.dtype)
-        u, s, ln = stage(k, w, v)
-        over = ln > local_capacity
-        # Detail level: routing is by detail code, so no two shards
-        # share a key and the boundary set is empty (an integer split
-        # cannot fall strictly inside a single-code range).
-        outs = [u, s, jnp.sum(u != sentinel)[None]]
-        cur_u, cur_s = u, s
-        for lvl in range(1, levels + 1):
-            parents = jnp.where(cur_u == sentinel, sentinel, cur_u >> 2)
-            pu, ps, pn = sparse_ops.aggregate_sorted_keys(
-                parents, cur_s, lcaps[lvl], sentinel=sentinel)
-            over = over | (pn > lcaps[lvl])
-            # Boundary codes at this level, from the traced splits: the
-            # split's ancestor, unless the split is tile-aligned.
-            blk = (1 << (2 * lvl)) - 1
-            b = jnp.where((spl & blk) != 0, spl >> (2 * lvl), sentinel)
-            code_mask = (1 << (code_bits - 2 * lvl)) - 1
-            is_b = (pu != sentinel) & jnp.any(
-                (pu & code_mask)[:, None] == b[None, :], axis=1)
-            cb = bcaps[lvl]
-            over = over | (jnp.sum(is_b) > cb)
-            # Sort boundary rows to the front (sentinel-masked argsort)
-            # and gather the fixed-width buffers + each block's shard id.
-            bkey = jnp.where(is_b, pu, sentinel)
-            border = jnp.argsort(bkey)[:cb]
-            send_u = bkey[border]
-            send_s = jnp.where(is_b, ps, jnp.zeros((), ps.dtype))[border]
-            g_u = lax.all_gather(send_u, axes)     # (ndev, cb)
-            g_s = lax.all_gather(send_s, axes)
-            g_id = lax.all_gather(me, axes)        # (ndev,)
-
-            def lookup(bu, bs):
-                pos = jnp.clip(jnp.searchsorted(bu, pu), 0, cb - 1)
-                hit = (bu[pos] == pu) & (pu != sentinel)
-                return jnp.where(hit, bs[pos], jnp.zeros((), bs.dtype)), hit
-
-            vals, hits = jax.vmap(lookup)(g_u, g_s)  # (ndev, lcap)
-            total = jnp.sum(vals, axis=0)
-            holder = g_id[jnp.argmax(hits, axis=0)]
-            keep = ~is_b | (holder == me)
-            new_u = jnp.where(keep, pu, sentinel)
-            new_s = jnp.where(keep & is_b, total, ps)
-            new_s = jnp.where(keep, new_s, jnp.zeros((), ps.dtype))
-            reorder = jnp.argsort(new_u)
-            cur_u, cur_s = new_u[reorder], new_s[reorder]
-            outs.extend([cur_u, cur_s, jnp.sum(cur_u != sentinel)[None]])
-        return (*outs, over[None])
-
-    fn = shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(P(axes), P(axes), P(axes), P()),
-        out_specs=(P(axes),) * (3 * (levels + 1) + 1),
-        check_vma=backend == "scatter",
-    )
-    parts = fn(codes, w, v, splits)
-    gover = parts[-1]
-    any_over = gover.any()
-    out = []
-    for lvl in range(levels + 1):
-        gu, gs, gn = parts[3 * lvl], parts[3 * lvl + 1], parts[3 * lvl + 2]
-        cap = caps[lvl]
-        # Keys are globally disjoint post-patch, so a global argsort of
-        # the sentinel-padded shard blocks IS the canonical merged
-        # order (sentinels sort last, their sums are zero).
-        order = jnp.argsort(gu)
-        su, ss = gu[order], gs[order]
-        if su.shape[0] >= cap:
-            su, ss = su[:cap], ss[:cap]
-        else:
-            su = jnp.concatenate(
-                [su, jnp.full((cap - su.shape[0],), sentinel, su.dtype)])
-            ss = jnp.concatenate(
-                [ss, jnp.zeros((cap - ss.shape[0],), ss.dtype)])
-        ln = gn.sum()
-        # Same loud-overflow contract as the other sharded pyramids:
-        # any shard-local overflow already dropped keys, so force the
-        # count past capacity rather than return a clean-looking level.
-        out.append((su, ss,
-                    jnp.where(any_over, jnp.maximum(ln, cap + 1), ln)))
-    return out
-
-
-def pyramid_sparse_morton_prefix_sharded(
-    codes,
-    mesh: Mesh,
-    weights=None,
-    valid=None,
-    levels: int = 0,
-    capacity=None,
-    acc_dtype=None,
-    send_capacity: int | None = None,
-    prefix_levels: int | None = None,
-    backend: str = "scatter",
-    weight_bound: int | None = None,
-):
-    """Sharded sparse pyramid with a coarse-prefix regrouped merge.
-
-    The O(n/k)-per-stage formulation of
-    :func:`pyramid_sparse_morton_sharded` (docs/DESIGN.md §4; the
-    replicated variant re-reduces the gathered partials on EVERY
-    device, O(global uniques) replicated — fine for clustered data,
-    the scaling wall for unique-heavy data). Reference analog: Spark's
-    hash-partitioned reducers never replicate the keyspace
-    (reference heatmap.py:112).
-
-    Stages, all inside one shard_map:
-
-    1. per-device detail reduction to compact (key, sum) partials —
-       unchanged from the replicated variant, routed by ``backend``
-       ("scatter" sort + segment-sum, or "partitioned" for the MXU
-       segment kernel — see pyramid_sparse_morton_sharded; the range
-       merges below stay on the scatter ops, they are O(uniques/k));
-    2. range splitters by regular sampling (the PSRS bound: with k
-       evenly-spaced samples per device, no range holds more than
-       2·n/k of the partials), each splitter rounded DOWN to a
-       multiple of ``4^prefix_levels`` so a key and its first
-       ``prefix_levels`` rollup ancestors (``key >> 2i``) land in the
-       same range — cross-device parents are impossible through those
-       levels;
-    3. one ``lax.all_to_all`` regroups the compact partials to their
-       range owner;
-    4. each device merges (sort + segment-sum) and rolls up its
-       keyspace range through ``prefix_levels`` levels — each stays
-       O(uniques/k) per device;
-    5. per-level results return range-sharded; the host-side
-       compaction concatenates the (disjoint, ascending) range
-       segments with a searchsorted gather — no sort, no re-reduce.
-       Levels past ``prefix_levels`` roll up replicated from the
-       compacted arrays — the same cheap tail the replicated merge
-       runs for EVERY level, kept only where zoom-clamped capacities
-       (or collapsed unique counts) have already made it small.
-
-    ``prefix_levels`` trades locality depth against range balance:
-    rounding a splitter down moves at most the ``4^prefix_levels``
-    distinct keys of one block (times their <= k cross-device copies)
-    into the lower range, so per-range load is bounded by
-    ``2*local_capacity + k*4^prefix_levels``. The default picks the
-    deepest value whose skew term stays within ``local_capacity``
-    (and caps it at ``levels``) — full-depth locality for shallow
-    pyramids, bounded-skew hybrid for the z21 cascade, where a
-    ``4^15`` block could otherwise swallow a whole metro area's keys
-    (measured: the hot-cluster bench overflowed exactly there).
-
-    Results match the replicated merge EXACTLY for counts and
-    integer-valued weighted sums (same sorted uniques, integer
-    addition in any order); fractional weighted sums agree to f64
-    summation-order rounding — the same contract as the replicated
-    variant vs the single-device cascade.
-
-    ``send_capacity`` bounds the per-(source, destination) all_to_all
-    rows. The default (the per-device partial capacity) can NEVER
-    drop entries; tightening it shrinks the exchange and the merge
-    sort toward true O(n/k) but makes extreme skew (one source
-    holding most of one range) overflow. Every overflow — send drop,
-    range-buffer, or local-stage — is detected and propagated into
-    every level's ``n_unique`` per the ops/sparse.py contract, never
-    silent.
-    """
-    axes, ndev = _shard_axes(mesh)
-    codes = jnp.asarray(codes)
-    n = codes.shape[0]
-    caps = pyramid_ops._level_caps(capacity, n, levels)
-    local_capacity = max(1, min(caps[0], n // ndev))
-    if prefix_levels is None:
-        prefix_levels = 0
-        while (prefix_levels < levels
-               and ndev * (4 ** (prefix_levels + 1)) <= local_capacity):
-            prefix_levels += 1
-    prefix_levels = max(0, min(prefix_levels, levels))
-    # PSRS bound + the rounding skew term (one 4^prefix_levels block's
-    # distinct keys, each on up to ndev devices); a range can never
-    # hold more uniques than the whole level either.
-    slack = ndev * (4 ** prefix_levels)
-    range_caps = [min(caps[lvl], 2 * local_capacity + slack)
-                  for lvl in range(prefix_levels + 1)]
-    send_cap = (local_capacity if send_capacity is None
-                else max(1, min(send_capacity, local_capacity)))
-    if acc_dtype is None:
-        acc_dtype = jnp.int32 if weights is None else jnp.float32
-    counts_only = weights is None
-    w = _ones_like_weights(weights, n, acc_dtype)
-    v = jnp.ones((n,), bool) if valid is None else jnp.asarray(valid, bool)
-    sentinel = jnp.iinfo(codes.dtype).max
-    prefix_bits = 2 * prefix_levels
-    stage = _local_detail_stage(backend, counts_only, local_capacity,
-                                acc_dtype, sentinel,
-                                weight_bound=weight_bound)
-
-    def body(k, w, v):
-        u, s, ln = stage(k, w, v)
-        # Regular sampling: ndev evenly-spaced picks from my sorted
-        # valid partials (sentinel when fewer than sampled — empty
-        # shards push their splitters to the top, shrinking their
-        # influence instead of corrupting ranges).
-        pos = (jnp.arange(ndev, dtype=jnp.int32)
-               * jnp.minimum(ln, local_capacity)) // ndev
-        samp = u[jnp.clip(pos, 0, local_capacity - 1)]
-        all_samp = lax.all_gather(samp, axes, tiled=True)
-        spl = jnp.sort(all_samp)[(jnp.arange(ndev - 1) + 1) * ndev]
-        # Round each splitter down to a 4^levels block boundary so a
-        # range owns whole rollup subtrees (sentinel splitters stay
-        # above every real 58-bit key even after rounding).
-        spl = (spl >> prefix_bits) << prefix_bits
-        # Partition my (sorted) partials: dest is non-decreasing, so
-        # per-destination runs are contiguous; sentinel pad lanes get
-        # dest=ndev and fall out of the send buffers via mode="drop".
-        lane_ok = u != sentinel
-        dest = jnp.searchsorted(spl, u, side="right").astype(jnp.int32)
-        dest = jnp.where(lane_ok, dest, ndev)
-        bounds = jnp.searchsorted(
-            dest, jnp.arange(ndev + 1, dtype=jnp.int32), side="left"
-        )
-        starts = bounds[:ndev]
-        per_dest = bounds[1:] - bounds[:ndev]
-        dropped = jnp.maximum(per_dest - send_cap, 0).sum().astype(jnp.int32)
-        slot = (jnp.arange(local_capacity, dtype=jnp.int32)
-                - starts[jnp.clip(dest, 0, ndev - 1)])
-        send_u = jnp.full((ndev, send_cap), sentinel, u.dtype).at[
-            dest, slot].set(u, mode="drop")
-        send_s = jnp.zeros((ndev, send_cap), s.dtype).at[
-            dest, slot].set(s, mode="drop")
-        # The regroup "shuffle": row d goes to range owner d; row j of
-        # the result came from source j (ascending ranges = ascending
-        # device ids, which the host-side concatenation relies on).
-        recv_u = lax.all_to_all(send_u, axes, 0, 0, tiled=True)
-        recv_s = lax.all_to_all(send_s, axes, 0, 0, tiled=True)
-        ru = recv_u.reshape(-1)
-        mu, ms, mn = sparse_ops.aggregate_keys(
-            ru, weights=recv_s.reshape(-1), valid=ru != sentinel,
-            capacity=range_caps[0], acc_dtype=acc_dtype,
-        )
-        outs = [(mu, ms, mn[None])]
-        for lvl in range(1, prefix_levels + 1):
-            parents = jnp.where(mu == sentinel, sentinel, mu >> 2)
-            mu, ms, mn = sparse_ops.aggregate_sorted_keys(
-                parents, ms, range_caps[lvl], sentinel=sentinel
-            )
-            outs.append((mu, ms, mn[None]))
-        return tuple(outs), ln[None], dropped[None]
-
-    level_specs = tuple((P(axes), P(axes), P(axes))
-                        for _ in range(prefix_levels + 1))
-    # check_vma: pallas outputs carry no varying-mesh-axes metadata, so
-    # the check only holds for the scatter detail stage (see
-    # pyramid_sparse_morton_sharded).
-    fn = shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(P(axes), P(axes), P(axes)),
-        out_specs=(level_specs, P(axes), P(axes)),
-        check_vma=backend == "scatter",
-    )
-    level_parts, gln, gdrop = fn(codes, w, v)
-    # Anything lost BEFORE the range merge (local-stage overflow or a
-    # tightened send_capacity dropping entries) poisons every level:
-    # keys are already missing from the merged totals.
-    pre_overflow = (gln > local_capacity).any() | (gdrop > 0).any()
-    out = []
-    any_range_over = pre_overflow
-    for lvl, (gu, gs, gn) in enumerate(level_parts):
-        rc = range_caps[lvl]
-        cap = caps[lvl]
-        any_range_over = any_range_over | (gn > rc).any()
-        cn = jnp.minimum(gn, rc)
-        csum = jnp.cumsum(cn)
-        total = csum[-1]
-        # Concatenate the k disjoint ascending range segments, skipping
-        # each segment's sentinel pad: output slot j maps to (device,
-        # local) via one searchsorted over the k segment offsets.
-        j = jnp.arange(cap, dtype=jnp.int32)
-        dev = jnp.clip(jnp.searchsorted(csum, j, side="right"), 0, ndev - 1)
-        local = j - (csum[dev] - cn[dev])
-        idx = jnp.clip(dev * rc + local, 0, gu.shape[0] - 1)
-        u = jnp.where(j < total, gu[idx], sentinel)
-        s = jnp.where(j < total, gs[idx], jnp.zeros((), gs.dtype))
-        n_l = jnp.where(any_range_over, jnp.maximum(total, cap + 1), total)
-        out.append((u, s, n_l))
-    # Replicated tail: levels past the prefix-local depth roll up from
-    # the compacted (sorted, sentinel-padded) arrays — identical math
-    # to the replicated merge's rollup, paid only where capacities are
-    # already small.
-    u, s, _ = out[-1]
-    for lvl in range(prefix_levels + 1, levels + 1):
-        parents = jnp.where(u == sentinel, sentinel, u >> 2)
-        u, s, n_l = sparse_ops.aggregate_sorted_keys(
-            parents, s, caps[lvl], sentinel=sentinel
-        )
-        n_l = jnp.where(any_range_over,
-                        jnp.maximum(n_l, caps[lvl] + 1), n_l)
-        out.append((u, s, n_l))
-    return out
 
 
 def splat_rowsharded(raster, kernel_1d, mesh: Mesh):

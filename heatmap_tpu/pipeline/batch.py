@@ -81,25 +81,15 @@ class BatchJobConfig:
     #: drives all 8 chips from the same ``run_job`` call on real
     #: workloads, while tiny inputs (and single chips) skip the mesh
     #: dispatch they'd only lose to. True forces the mesh path at any
-    #: size and device count (the sharded kernels are exercised,
-    #: results unchanged); False pins the single-device cascade. Counts and integer-valued weighted
+    #: size and device count (the gspmd programs of parallel/gspmd.py
+    #: run, results unchanged); False pins the single-device cascade.
+    #: Counts and integer-valued weighted
     #: sums are bit-identical either way; fractional weighted sums
     #: agree up to f64 summation-order rounding (see
     #: cascade.build_cascade ``mesh``). Composes with multi-process
     #: runs (run_job_multihost): each process data-parallelizes its
     #: slice over its own local devices.
     data_parallel: bool | None = None
-    #: Cross-device merge for the data-parallel cascade: "replicated"
-    #: (default — all_gather compact partials, merge + roll up on every
-    #: device; O(global uniques) replicated, measured fine for
-    #: clustered data) or "prefix" (coarse-prefix all_to_all regroup —
-    #: each device merges and rolls up only its keyspan range,
-    #: O(uniques/k) per stage; the scaling shape for unique-heavy data
-    #: — docs/DESIGN.md §4, reference heatmap.py:112's hash-partitioned
-    #: reducers). Blobs identical either way (counts and integer
-    #: weighted sums bit-exact; fractional weighted to f64 summation
-    #: order). Ignored off the mesh path.
-    dp_merge: str = "replicated"
     #: Auto-DP engagement threshold override (emission count at which
     #: ``data_parallel=None`` engages the mesh). None uses the module
     #: default ``AUTO_DP_MIN_EMISSIONS``, which is calibrated from a
@@ -125,34 +115,19 @@ class BatchJobConfig:
     #: Morton-range spatial sharding of the data-parallel cascade
     #: (parallel/partition.py): "auto" (default — when the mesh path
     #: engages AND the emission count reaches the auto-DP threshold,
-    #: plan P-1 split codes from sampled quantiles, route each shard a
-    #: contiguous Z-order range host-side, and shrink the cross-chip
-    #: merge to boundary tiles only), "morton" (force range sharding
-    #: whenever a mesh engages, any size), "off" (uniform round-robin
-    #: DP, the historical path). Byte-neutral: counts and
-    #: integer-valued weighted sums are bit-identical to "off"
-    #: (tests/test_partition.py pins blobs across backends); a
-    #: degenerate plan (one range holds ~all sampled mass) falls back
-    #: to uniform DP with a backend_resolved audit event
-    #: (_dp_mesh_for). Composes with pad_bucketing: per-range segments
-    #: pad to bucketed lengths so routed shapes hit the same compile
-    #: cache.
+    #: plan P-1 split codes from sampled quantiles; the gspmd range
+    #: program routes each lane on-device to the shard owning its
+    #: contiguous Z-order range and shrinks the cross-chip merge to
+    #: boundary tiles only), "morton" (force range sharding whenever a
+    #: mesh engages, any size), "off" (the uniform gspmd program).
+    #: Byte-neutral: counts and integer-valued weighted sums are
+    #: bit-identical to "off" (tests/test_partition.py pins blobs
+    #: across backends); a degenerate plan (one range holds ~all
+    #: sampled mass) falls back to the uniform program with a
+    #: backend_resolved audit event (_dp_mesh_for). Composes with
+    #: pad_bucketing and adaptive_capacity: the exchange widths sit on
+    #: a geometric ladder, so jobs of one size share one compile.
     spatial_partition: str = "auto"
-    #: Mesh-cascade dispatch formulation: "auto" (default — "gspmd"
-    #: wherever its programs exist, which today is every mesh shape
-    #: except dp_merge="prefix"), "gspmd" (parallel/gspmd.py — the
-    #: whole cascade as ONE global-view NamedSharding program:
-    #: on-device emission routing against traced splits, range-local
-    #: rollup, boundary merge, and canonical egress ordering, with no
-    #: host round-trips between stages), or "shard_map" (the
-    #: parallel/sharded.py kernels with host-side range routing — kept
-    #: selectable for one release as the differential-testing oracle).
-    #: Byte-identical outputs either way (tests/test_gspmd.py pins
-    #: levels AND served blobs). Ignored off the mesh path. Only
-    #: "gspmd" composes with adaptive_capacity: its traced router and
-    #: global-view rollup accept concrete-count shrinking that the
-    #: shape-static shard_map bodies cannot.
-    dispatch: str = "auto"
 
     def __post_init__(self):
         from heatmap_tpu.pipeline.bucketing import BUCKETING_MODES
@@ -166,12 +141,6 @@ class BatchJobConfig:
         if self.pad_bucket_min < 1:
             raise ValueError(
                 f"pad_bucket_min must be >= 1, got {self.pad_bucket_min}"
-            )
-        if self.dp_merge not in ("replicated", "prefix"):
-            raise ValueError(
-                f"unknown dp_merge {self.dp_merge!r} (valid: "
-                "replicated, prefix) — rejected at config time so a "
-                "typo fails before a multi-hour ingest"
             )
         if self.dp_min_emissions is not None:
             if self.data_parallel is not None:
@@ -231,27 +200,6 @@ class BatchJobConfig:
                     "1024-element chunk) — use the scatter backend "
                     "for larger weights"
                 )
-        if self.dispatch not in ("auto", "gspmd", "shard_map"):
-            raise ValueError(
-                f"unknown dispatch {self.dispatch!r} (valid: auto, "
-                "gspmd, shard_map) — rejected at config time so a typo "
-                "fails before a multi-hour ingest"
-            )
-        if self.dispatch == "gspmd" and self.dp_merge == "prefix":
-            raise ValueError(
-                "dispatch='gspmd' has no prefix-merge program yet; "
-                "dp_merge='prefix' needs dispatch='shard_map' (or "
-                "'auto', which resolves it there)"
-            )
-        if (self.data_parallel and self.adaptive_capacity
-                and self.resolved_dispatch != "gspmd"):
-            raise ValueError(
-                "the shard_map mesh cascade is shape-static; "
-                "adaptive_capacity reads concrete per-level counts "
-                "and does not compose — disable one of them, or use "
-                "dispatch='gspmd' (its global-view rollup accepts "
-                "adaptive shrinking)"
-            )
         if self.spatial_partition not in ("auto", "morton", "off"):
             raise ValueError(
                 f"unknown spatial_partition {self.spatial_partition!r} "
@@ -266,30 +214,13 @@ class BatchJobConfig:
                     "the single-device path — rejected at config time "
                     "so a silently ignored partition cannot ship"
                 )
-            if (self.adaptive_capacity
-                    and self.resolved_dispatch != "gspmd"):
-                # The host router (route_emissions) is shape-static, so
-                # morton + adaptive only composes when routing happens
-                # on-device — the gspmd dispatch. "auto" resolves to
-                # gspmd precisely so this combination Just Works.
-                raise ValueError(
-                    "spatial_partition='morton' with "
-                    "dispatch='shard_map' rides the host-routed "
-                    "shape-static mesh path; adaptive_capacity does "
-                    "not compose there — use dispatch='gspmd' (or "
-                    "'auto'), whose on-device routing accepts it"
-                )
 
     @property
     def resolved_dispatch(self) -> str:
-        """The mesh-dispatch formulation the cascade actually runs:
-        "auto" resolves to the one-program gspmd dispatch wherever its
-        programs exist — today everything except dp_merge="prefix",
-        which keeps the shard_map prefix kernel. Explicit requests are
-        honored as-is (gspmd + prefix is rejected at config time)."""
-        if self.dispatch != "auto":
-            return self.dispatch
-        return "shard_map" if self.dp_merge == "prefix" else "gspmd"
+        """The one mesh formulation of the data-parallel cascade:
+        "gspmd", the programs of parallel/gspmd.py (read-only; the
+        ``dispatch`` field of ``backend_resolved`` events)."""
+        return "gspmd"
 
     @property
     def resolved_cascade_backend(self) -> str:
@@ -419,7 +350,7 @@ def _project_codes_jit(lat, lon, zoom):
 
 #: Auto data-parallel engages only past this many emissions (explicit
 #: ``data_parallel=True`` always engages). Below it the per-device
-#: slices are too small for the shard_map dispatch + all_gather merge
+#: slices are too small for the mesh dispatch + cross-chip merge
 #: to pay for themselves on ANY backend — measured 9x SLOWER on the
 #: 8-device CPU mesh with 150-point bounded chunks (eager per-chunk
 #: dispatch), and a real chip gains nothing from sharding a few
@@ -434,18 +365,12 @@ def _dp_mesh(config: BatchJobConfig):
 
     Capability gate only — the per-call size gate is
     :func:`_dp_mesh_for`. Auto (``data_parallel=None``) engages only
-    past one local device: the mesh path is bit-identical but adds
-    shard_map dispatch that a single chip gains nothing from. Both
-    cascade backends compose with the mesh (the partitioned segment
-    reduction runs inside the shard_map body — parallel/sharded.py);
-    under the shard_map dispatch adaptive capacities route
-    single-device (True + adaptive rejected at config time there),
-    while the gspmd dispatch takes them onto the mesh — its
-    global-view rollup reads concrete counts eagerly.
+    past one local device: the mesh path is bit-identical but adds a
+    mesh dispatch that a single chip gains nothing from. Both cascade
+    backends and adaptive capacities compose with the mesh
+    (parallel/gspmd.py).
     """
     if config.data_parallel is False:
-        return None
-    if config.adaptive_capacity and config.resolved_dispatch != "gspmd":
         return None
     if config.data_parallel is None and jax.local_device_count() < 2:
         return None
@@ -1051,9 +976,7 @@ def _resolve_backend(config: BatchJobConfig, n_emissions: int | None = None,
     ``config.resolved_cascade_backend`` when telemetry is off.
 
     When the mesh engages (``data_parallel``), the event also carries
-    ``dispatch`` — how the formulation knob resolved ("gspmd" vs
-    "shard_map"), so dispatcher routing stays auditable alongside the
-    kernel-backend decision.
+    ``dispatch``: the mesh formulation that runs ("gspmd").
     """
     resolved = config.resolved_cascade_backend
     if not obs.telemetry_enabled():
@@ -1251,7 +1174,6 @@ def _run_job_bounded(source, sink, config: BatchJobConfig,
                 jit=False,
                 backend=resolved_backend,
                 mesh=_dp_mesh_for(dp_mesh, config, len(e_codes)),
-                merge=config.dp_merge,
                 weight_bound=config.weight_bound,
             )
             levels = cascade_mod.decode_levels(level_data, ccfg)
@@ -2142,14 +2064,14 @@ def _run_grouped(lat, lon, group_ids, timestamps, vocab,
     plan = route = None
     if mesh0 is not None and config.spatial_partition != "off":
         from heatmap_tpu.parallel import partition as partition_mod
-        from heatmap_tpu.parallel.sharded import _shard_axes
+        from heatmap_tpu.parallel.mesh import _shard_axes
 
         _, ndev = _shard_axes(mesh0)
         threshold = (AUTO_DP_MIN_EMISSIONS
                      if config.dp_min_emissions is None
                      else config.dp_min_emissions)
         # "auto" plans only at real scale: below the DP threshold the
-        # host-side routing pass would cost more than the boundary
+        # host-side planning pass would cost more than the boundary
         # merge saves (the same never-slow-down rule as auto-DP).
         # "morton" forces the plan whenever a mesh engages.
         if ndev >= 2 and (config.spatial_partition == "morton"
@@ -2162,8 +2084,7 @@ def _run_grouped(lat, lon, group_ids, timestamps, vocab,
                     host_codes, ndev, detail_zoom=config.detail_zoom,
                     valid=host_valid,
                     n_levels=config.cascade_config().n_levels)
-                if (not plan.degenerate
-                        and config.resolved_dispatch == "gspmd"):
+                if not plan.degenerate:
                     # The owner-routed program's static exchange widths,
                     # on a fixed 1.25x ladder so that jobs of one size
                     # compile once.
@@ -2174,34 +2095,16 @@ def _run_grouped(lat, lon, group_ids, timestamps, vocab,
     dp_mesh = _dp_mesh_for(mesh0, config, len(e_codes), plan=plan)
     if plan is not None and (dp_mesh is None or plan.degenerate):
         plan = route = None  # fallback recorded by _dp_mesh_for
-    dispatch = config.resolved_dispatch if dp_mesh is not None else None
-    timer = obs.DispatchTimer(dispatch or "single")
-    if plan is not None and dispatch != "gspmd":
-        # Host-side range routing (shard_map dispatch only — the gspmd
-        # program routes ON-DEVICE against the traced splits, so its
-        # emissions stay unrouted and this whole host scatter
-        # disappears): scatter each emission into its owning shard's
-        # contiguous segment (pad lanes valid=False), bucketing the
-        # segment length so routed shapes reuse the bucketed compile
-        # cache.
-        with tracer.span("cascade.partition_route", items=len(e_codes)):
-            bucket = None
-            if config.pad_bucketing != "exact":
-                def bucket(L):
-                    return bucketing_mod.bucket_size(
-                        L, config.pad_bucketing, config.pad_bucket_min)
-            e_codes, e_slots, e_valid, e_weights, _seg = (
-                partition_mod.route_emissions(
-                    plan, e_codes, e_slots, e_valid, e_weights,
-                    bucket=bucket))
+    timer = obs.DispatchTimer("single" if dp_mesh is None
+                              else config.resolved_dispatch)
     if route is not None:
         obs.record_mesh_route(route)
-    if dispatch == "gspmd":
+    if dp_mesh is not None:
         # The gspmd programs take the stream in contiguous per-chip
         # blocks: pad to whole blocks (pad lanes valid=False) and place
         # each block on its chip, so no chip receives the whole batch.
         from heatmap_tpu.parallel.gspmd import point_sharding
-        from heatmap_tpu.parallel.sharded import _shard_axes
+        from heatmap_tpu.parallel.mesh import _shard_axes
 
         _, ndev = _shard_axes(dp_mesh)
         e_codes, e_slots, e_valid, e_weights = bucketing_mod.pad_emissions(
@@ -2236,15 +2139,11 @@ def _run_grouped(lat, lon, group_ids, timestamps, vocab,
                     backend,
                     None if dp_mesh is None
                     else tuple(sorted(dp_mesh.shape.items())),
-                    config.dp_merge,
                     config.weight_bound,
-                    # Partition term: the range-sharded kernel is a
-                    # distinct trace, but splits are TRACED, so every
-                    # plan of the same shard count shares one compile.
+                    # Partition term: the range program is a distinct
+                    # trace, but splits are TRACED, so every plan of the
+                    # same shard count shares one compile.
                     None if plan is None else ("morton", len(plan.splits)),
-                    # Dispatch term: the gspmd and shard_map programs
-                    # are distinct traces of the same math.
-                    dispatch,
                     None if route is None
                     else (route.pair_capacity, route.recv_capacity),
                 ),
@@ -2267,10 +2166,8 @@ def _run_grouped(lat, lon, group_ids, timestamps, vocab,
             adaptive=config.adaptive_capacity,
             backend=backend,
             mesh=dp_mesh,
-            merge=config.dp_merge,
             weight_bound=config.weight_bound,
             partition_splits=partition_splits,
-            dispatch=dispatch or "shard_map",
             route_capacity=None if route is None
             else (route.pair_capacity, route.recv_capacity),
         )

@@ -99,10 +99,8 @@ def decode_level_keys(level_keys: np.ndarray, detail_zoom: int, level: int):
 def build_cascade(codes, slots, config: CascadeConfig, n_slots: int,
                   weights=None, valid=None, capacity=None, acc_dtype=None,
                   adaptive: bool = False, backend: str = "scatter",
-                  mesh=None, merge: str = "replicated",
-                  weight_bound: int | None = None,
-                  partition_splits=None, dispatch: str = "shard_map",
-                  route_capacity=None):
+                  mesh=None, weight_bound: int | None = None,
+                  partition_splits=None, route_capacity=None):
     """Device-side cascade: per-level (composite key, sum) aggregates.
 
     Args:
@@ -122,80 +120,40 @@ def build_cascade(codes, slots, config: CascadeConfig, n_slots: int,
     contract). The production default is routed by
     BatchJobConfig.resolved_cascade_backend.
 
-    ``mesh``: a jax.sharding.Mesh to data-parallelize the detail-level
-    reduction over (parallel.sharded.pyramid_sparse_morton_sharded):
-    emissions are padded to the shard count and reduced per device,
-    one all_gather merges the compact per-device aggregates, and the
-    rollup runs replicated — composite keys shift exactly like plain
-    Morton codes (the slot bits ride above the code bits), so the
+    ``mesh``: a jax.sharding.Mesh to data-parallelize the cascade over
+    (parallel/gspmd.py, one compiled program over the mesh): without
+    ``partition_splits``, ``pyramid_gspmd_uniform`` — emissions padded
+    to the shard count, each shard reduces its contiguous block at the
+    detail zoom, and the compact partials merge and roll up on the
+    global view. Composite keys shift exactly like plain Morton codes
+    (the slot bits ride above the code bits), so the
     shift-preserves-sort property the single-device cascade relies on
     holds per level unchanged. Counts and integer-valued weighted sums
     are BIT-IDENTICAL to the single-device cascade (same sorted unique
     keys, exact integer addition in any order); fractional weighted
     sums agree up to f64 summation-order rounding — the same contract
     as the bounded path's cross-chunk merge (pipeline/batch.py
-    run_job). Composes with BOTH backends — "partitioned" swaps the
-    per-device detail reduction for the MXU segment kernel inside the
-    shard_map body, same compact (keys, sums, count) contract, so the
-    merge and rollup are untouched and blobs stay byte-equal.
-    ``adaptive`` reads concrete counts and does not compose.
-
-    ``merge`` selects the mesh path's cross-device merge:
-    "replicated" (default — all_gather the compact partials, re-reduce
-    and roll up on every device; O(global uniques) replicated) or
-    "prefix" (coarse-prefix all_to_all regroup — each device merges
-    and rolls up only its keyspace range, O(uniques/k) per stage;
-    parallel.sharded.pyramid_sparse_morton_prefix_sharded). Same
-    results either way (counts/integer weights bit-identical,
-    fractional weighted to f64 summation order).
+    run_job). Composes with BOTH backends — "partitioned" swaps each
+    shard's detail reduction for the MXU segment kernel, same compact
+    (keys, sums, count) contract — and with ``adaptive``.
 
     ``partition_splits``: a TRACED ``(n_shards - 1,)`` int array of
     detail-zoom Morton split codes from a parallel.partition plan.
-    Requires a mesh and emissions PRE-ROUTED host-side into per-shard
-    contiguous range segments (partition.route_emissions); the mesh
-    path then runs the range-sharded pyramid whose cross-chip exchange
-    is boundary tiles only (parallel.sharded.
-    pyramid_sparse_morton_range_sharded) instead of the full-pyramid
-    replicated/prefix merge. Traced — every plan shares one compile.
+    Requires a mesh; the emissions arrive UNROUTED, in contiguous
+    per-shard blocks, and ``pyramid_gspmd_range`` routes each lane
+    ON-DEVICE to the shard owning its code, so the cross-chip merge is
+    boundary tiles only. Traced — every plan of one shard count and
+    one ``route_capacity`` shares one compile.
 
-    ``dispatch`` selects the mesh path's formulation: "shard_map" (the
-    parallel/sharded.py kernels — host-routed range segments, the
-    differential-testing oracle) or "gspmd" (parallel/gspmd.py —
-    global-view NamedSharding programs; ``partition_splits`` then
-    routes ON-DEVICE, so emissions arrive UNROUTED, and
-    ``adaptive`` composes with the mesh). Byte-identical outputs
-    either way (tests/test_gspmd.py).
-
-    ``route_capacity``: the gspmd range program's static exchange
-    widths ``(pair_capacity, recv_capacity)``, from the plan's exact
-    lane counts (parallel.partition.route_counts); required with
-    ``partition_splits`` under ``dispatch="gspmd"``.
+    ``route_capacity``: the range program's static exchange widths
+    ``(pair_capacity, recv_capacity)``, from the plan's exact lane
+    counts (parallel.partition.route_counts); required with
+    ``partition_splits``.
     """
-    if dispatch not in ("shard_map", "gspmd"):
-        raise ValueError(
-            f"unknown cascade dispatch {dispatch!r} "
-            "(valid: shard_map, gspmd)")
-    if merge not in ("replicated", "prefix"):
-        raise ValueError(
-            f"unknown mesh merge {merge!r} (valid: replicated, prefix)"
-        )
     if partition_splits is not None and mesh is None:
         raise ValueError(
             "partition_splits is the mesh path's range plan; it needs "
             "a mesh — plan routing happens in pipeline/batch.py"
-        )
-    if mesh is not None and adaptive and dispatch != "gspmd":
-        raise ValueError(
-            "shard_map mesh cascade is shape-static; "
-            "adaptive_capacity reads concrete per-level counts and "
-            "does not compose — disable one of them, or use "
-            "dispatch='gspmd' (its traced router and global-view "
-            "rollup accept adaptive shrinking)"
-        )
-    if mesh is not None and dispatch == "gspmd" and merge == "prefix":
-        raise ValueError(
-            "the gspmd dispatch has no prefix-merge program yet; use "
-            "dispatch='shard_map' for dp_merge='prefix'"
         )
     if backend == "partitioned":
         # These hold on the mesh path too: every shard runs the same
@@ -247,12 +205,10 @@ def build_cascade(codes, slots, config: CascadeConfig, n_slots: int,
     if mesh is not None:
         return _build_cascade_sharded(
             ck, config, mesh, weights=weights, valid=valid,
-            capacity=capacity, acc_dtype=acc_dtype, merge=merge,
-            backend=backend,
+            capacity=capacity, acc_dtype=acc_dtype, backend=backend,
             weight_bound=weight_bound if weights is not None else None,
             partition_splits=partition_splits, n_slots=n_slots,
-            dispatch=dispatch, adaptive=adaptive,
-            route_capacity=route_capacity,
+            adaptive=adaptive, route_capacity=route_capacity,
         )
     if backend == "partitioned":
         return pyramid_ops.pyramid_sparse_morton_partitioned(
@@ -276,27 +232,20 @@ def build_cascade(codes, slots, config: CascadeConfig, n_slots: int,
 
 def _build_cascade_sharded(ck, config: CascadeConfig, mesh,
                            weights=None, valid=None, capacity=None,
-                           acc_dtype=None, merge: str = "replicated",
-                           backend: str = "scatter",
+                           acc_dtype=None, backend: str = "scatter",
                            weight_bound: int | None = None,
                            partition_splits=None, n_slots: int = 1,
-                           dispatch: str = "shard_map",
                            adaptive: bool = False, route_capacity=None):
-    """Pad composite keys to the mesh shard count and run the sharded
+    """Pad composite keys to the mesh shard count and run the gspmd
     pyramid (see build_cascade's ``mesh`` doc). Pad lanes carry
-    valid=False, the masking path every kernel already drops.
+    valid=False, the masking path every kernel already drops; with
+    ``partition_splits`` the emissions arrive UNROUTED, in contiguous
+    per-shard blocks, and are routed on-device to their owners
+    (``route_capacity`` sizes the exchange)."""
+    from heatmap_tpu.parallel import gspmd as gspmd_kernels
+    from heatmap_tpu.parallel.mesh import _shard_axes
 
-    ``dispatch="gspmd"`` swaps each shard_map kernel for its
-    global-view NamedSharding twin (parallel/gspmd.py): same padding,
-    byte-identical outputs; with ``partition_splits`` the emissions
-    arrive UNROUTED, in contiguous per-shard blocks, and are routed
-    on-device to their owners (``route_capacity`` sizes the exchange).
-    """
-    # Lazy import: parallel.sharded pulls in the pallas histogram stack,
-    # which cascade-only consumers (spark_adapter, tools) never need.
-    from heatmap_tpu.parallel import sharded as sharded_kernels
-
-    _, ndev = sharded_kernels._shard_axes(mesh)
+    _, ndev = _shard_axes(mesh)
     n = int(ck.shape[0])
     if n == 0:
         # Zero-row shards would size the per-device stage at zero
@@ -305,18 +254,6 @@ def _build_cascade_sharded(ck, config: CascadeConfig, mesh,
         return pyramid_ops.pyramid_sparse_morton(
             ck, weights=weights, valid=valid, levels=config.n_levels,
             capacity=capacity, acc_dtype=acc_dtype,
-        )
-    if partition_splits is not None and dispatch != "gspmd":
-        # Emissions arrive pre-routed into per-shard contiguous range
-        # segments of equal length (partition.route_emissions) — no
-        # tail pad here, a pad would shift lanes across segment
-        # boundaries and break the range invariant.
-        return sharded_kernels.pyramid_sparse_morton_range_sharded(
-            ck, mesh, partition_splits,
-            code_bits=2 * config.detail_zoom, slot_bound=n_slots,
-            weights=weights, valid=valid, levels=config.n_levels,
-            capacity=capacity, acc_dtype=acc_dtype, backend=backend,
-            weight_bound=weight_bound,
         )
     pad = (-n) % ndev
     v = (jnp.ones((n,), bool) if valid is None
@@ -330,8 +267,6 @@ def _build_cascade_sharded(ck, config: CascadeConfig, mesh,
                 [weights, jnp.zeros((pad,), weights.dtype)]
             )
     if partition_splits is not None:
-        from heatmap_tpu.parallel import gspmd as gspmd_kernels
-
         if route_capacity is None:
             raise ValueError(
                 "the gspmd range program needs route_capacity "
@@ -345,21 +280,10 @@ def _build_cascade_sharded(ck, config: CascadeConfig, mesh,
             capacity=capacity, acc_dtype=acc_dtype, backend=backend,
             weight_bound=weight_bound, adaptive=adaptive,
         )
-    if dispatch == "gspmd":
-        from heatmap_tpu.parallel import gspmd as gspmd_kernels
-
-        return gspmd_kernels.pyramid_gspmd_uniform(
-            ck, mesh, weights=weights, valid=v, levels=config.n_levels,
-            capacity=capacity, acc_dtype=acc_dtype, backend=backend,
-            weight_bound=weight_bound, adaptive=adaptive,
-        )
-    kernel = (sharded_kernels.pyramid_sparse_morton_prefix_sharded
-              if merge == "prefix"
-              else sharded_kernels.pyramid_sparse_morton_sharded)
-    return kernel(
+    return gspmd_kernels.pyramid_gspmd_uniform(
         ck, mesh, weights=weights, valid=v, levels=config.n_levels,
         capacity=capacity, acc_dtype=acc_dtype, backend=backend,
-        weight_bound=weight_bound,
+        weight_bound=weight_bound, adaptive=adaptive,
     )
 
 
@@ -370,12 +294,11 @@ def _build_cascade_sharded(ck, config: CascadeConfig, mesh,
 _build_cascade_jit = functools.partial(
     jax.jit,
     static_argnames=("config", "n_slots", "capacity", "acc_dtype",
-                     "backend", "mesh", "merge", "weight_bound",
-                     "dispatch", "route_capacity"),
+                     "backend", "mesh", "weight_bound", "route_capacity"),
 )(build_cascade)
 
-#: Lazily-built donating twin of _build_cascade_jit for the gspmd
-#: dispatch: the routed-emission buffers (codes/slots/weights/valid)
+#: Lazily-built donating twin of _build_cascade_jit for the mesh
+#: cascade: the emission buffers (codes/slots/weights/valid)
 #: are donated to the program, letting XLA reuse their device memory
 #: for the pyramid accumulators in-place on TPU/GPU. Built on first
 #: use because donation support depends on the initialized backend
@@ -395,8 +318,8 @@ def _get_donating_cascade_jit():
             donate_argnums=(0, 1),  # codes, slots
             donate_argnames=("weights", "valid"),
             static_argnames=("config", "n_slots", "capacity",
-                             "acc_dtype", "backend", "mesh", "merge",
-                             "weight_bound", "dispatch", "route_capacity"),
+                             "acc_dtype", "backend", "mesh",
+                             "weight_bound", "route_capacity"),
         )
     return _donating_cascade_jit
 
@@ -405,18 +328,16 @@ def run_cascade(codes, slots, config: CascadeConfig, n_slots: int,
                 weights=None, valid=None, capacity=None, acc_dtype=None,
                 adaptive: bool = False, jit: bool = True,
                 backend: str = "scatter", mesh=None,
-                merge: str = "replicated",
                 weight_bound: int | None = None,
-                partition_splits=None, dispatch: str = "shard_map",
-                route_capacity=None):
+                partition_splits=None, route_capacity=None):
     """The production cascade entry: jitted whole, unless ``adaptive``
     (which must read concrete per-level unique counts and therefore
     runs eagerly — see ops.pyramid.pyramid_sparse_morton) or
     ``jit=False`` (callers whose input shapes vary call to call — e.g.
     the bounded chunked path — would recompile the whole graph per
     call and should stay eager). ``mesh`` (hashable, a valid static
-    arg) routes the detail reduction through the data-parallel sharded
-    pyramid — see build_cascade."""
+    arg) routes the cascade through the data-parallel gspmd pyramid —
+    see build_cascade."""
     # Tree-only span around the dispatch: the cascade_dispatch event is
     # emitted inside it, so the audit record carries this span's
     # trace_id/span_id (events.py stamps _TRACE_STAMPED types).
@@ -427,20 +348,19 @@ def run_cascade(codes, slots, config: CascadeConfig, n_slots: int,
             # (shape info is static even on tracers, so this is safe in
             # eager AND pre-jit contexts). backend_resolved in batch.py
             # records the routing *decision*; this records each execution.
-            extra = {"dispatch": dispatch} if mesh is not None else {}
+            extra = {"dispatch": "gspmd"} if mesh is not None else {}
             obs_events.emit(
                 "cascade_dispatch", backend=backend,
                 jit=bool(jit and not adaptive), mesh=mesh is not None,
-                merge=merge, n_emissions=int(codes.shape[0]),
+                n_emissions=int(codes.shape[0]),
                 n_slots=int(n_slots),
                 partition=partition_splits is not None, **extra)
         if adaptive or not jit:
             return build_cascade(
                 codes, slots, config, n_slots, weights=weights, valid=valid,
                 capacity=capacity, acc_dtype=acc_dtype, adaptive=adaptive,
-                backend=backend, mesh=mesh, merge=merge,
-                weight_bound=weight_bound,
-                partition_splits=partition_splits, dispatch=dispatch,
+                backend=backend, mesh=mesh, weight_bound=weight_bound,
+                partition_splits=partition_splits,
                 route_capacity=route_capacity,
             )
         if isinstance(capacity, list):
@@ -450,15 +370,13 @@ def run_cascade(codes, slots, config: CascadeConfig, n_slots: int,
         # jnp producer): donating host numpy inputs would be a silent
         # no-op on TPU plus a "donated buffer not usable" warning.
         jit_entry = _build_cascade_jit
-        if (dispatch == "gspmd" and mesh is not None
-                and isinstance(codes, jax.Array)):
+        if mesh is not None and isinstance(codes, jax.Array):
             jit_entry = _get_donating_cascade_jit()
         return jit_entry(
             codes, slots, config=config, n_slots=n_slots, weights=weights,
             valid=valid, capacity=capacity, acc_dtype=acc_dtype,
-            backend=backend, mesh=mesh, merge=merge,
-            weight_bound=weight_bound,
-            partition_splits=partition_splits, dispatch=dispatch,
+            backend=backend, mesh=mesh, weight_bound=weight_bound,
+            partition_splits=partition_splits,
             route_capacity=route_capacity,
         )
     finally:

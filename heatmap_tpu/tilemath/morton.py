@@ -167,8 +167,9 @@ def morton_range_shards_np(splits, codes) -> np.ndarray:
     A code belongs to shard ``k`` iff exactly ``k`` splits are <= it
     (``searchsorted(side="right")``), i.e. a split code itself opens the
     range to its right. This is THE ownership convention: the planner,
-    the host router, and the range-sharded kernel must all agree on it
-    or boundary tiles get double-counted.
+    the lane counts of partition.route_counts, and the on-device router
+    of the range program must all agree on it or boundary tiles get
+    double-counted.
     """
     return np.searchsorted(
         np.asarray(splits, np.int64), np.asarray(codes, np.int64),

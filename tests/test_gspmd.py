@@ -1,14 +1,16 @@
-"""Device-resident GSPMD cascade (parallel/gspmd.py + the dispatch knob).
+"""Device-resident GSPMD cascade (parallel/gspmd.py): the one
+formulation of the data-parallel cascade.
 
 Four layers under test:
 
 - the global-view NamedSharding programs themselves (uniform DP and
-  Morton-range), gated byte-identical against the shard_map oracle at
-  the kernel level (padded level arrays AND counts);
-- the end-to-end ``dispatch="gspmd"`` route through run_job — every
-  tested shape (weighted, retraction sign=-1, pow2-bucketed,
-  Morton-partitioned, morton + adaptive_capacity, multihost-elastic)
-  must serve blobs byte-identical to ``dispatch="shard_map"``;
+  Morton-range), gated byte-identical against the single-device
+  cascade at the kernel level (level arrays AND counts), and their
+  lowering pinned to the same collectives;
+- the end-to-end mesh route through run_job — every tested shape
+  (weighted, retraction sign=-1, pow2-bucketed, Morton-partitioned,
+  morton + adaptive_capacity, multihost-elastic) must serve blobs
+  byte-identical to ``data_parallel=False``;
 - donation safety: re-using a donated buffer is a typed
   :class:`DonatedBufferError` on every platform, ``donate_argnums`` is
   dropped automatically on CPU, and results are byte-identical either
@@ -33,7 +35,7 @@ import numpy as np
 import pytest
 
 from heatmap_tpu import faults, obs
-from heatmap_tpu.parallel import gspmd, sharded
+from heatmap_tpu.parallel import gspmd
 from heatmap_tpu.parallel.mesh import make_mesh, named_sharding
 from heatmap_tpu.pipeline import BatchJobConfig, feeder, run_job
 from heatmap_tpu.pipeline.batch import run_batch
@@ -99,21 +101,22 @@ def _keys(n, seed, n_slots=20):
 # -- kernel-level byte identity --------------------------------------------
 
 
-def test_gspmd_uniform_matches_shard_map_kernel():
+def test_gspmd_uniform_matches_single_device():
+    from heatmap_tpu.ops import pyramid
+
     mesh = make_mesh()
     ck = _keys(4096, 3)
     w = jnp.asarray(np.random.default_rng(4).integers(1, 9, 4096),
                     jnp.float64)
     valid = jnp.asarray(np.random.default_rng(5).random(4096) > 0.1)
     for weights in (None, w):
+        acc = jnp.float64 if weights is not None else None
         got = gspmd.pyramid_gspmd_uniform(
             ck, mesh, weights=weights, valid=valid, levels=6,
-            capacity=4096,
-            acc_dtype=jnp.float64 if weights is not None else None)
-        want = sharded.pyramid_sparse_morton_sharded(
-            ck, mesh, weights=weights, valid=valid, levels=6,
-            capacity=4096,
-            acc_dtype=jnp.float64 if weights is not None else None)
+            capacity=4096, acc_dtype=acc)
+        want = pyramid.pyramid_sparse_morton(
+            ck, weights=weights, valid=valid, levels=6, capacity=4096,
+            acc_dtype=acc)
         _levels_equal(got, want)
 
 
@@ -231,28 +234,53 @@ def _range_case(case, ndev, n=4096):
     elif case == "empty_shards":
         splits = np.sort(np.concatenate(
             [splits[:2], np.full(ndev - 3, splits[1])]))
+    elif case == "hotspot":
+        # 95% of the lanes in one 64x64-tile block inside range 1 of
+        # even splits: one shard owns nearly the whole stream.
+        splits = np.arange(1, ndev) * (SPACE // ndev)
+        hot = rng.random(n) < 0.95
+        code = np.where(hot, splits[0] + (7 << 12)
+                        + rng.integers(0, 1 << 12, n), code)
+    elif case == "unique_heavy":
+        # Every point its own detail tile.
+        code = rng.choice(SPACE, n, replace=False)
+        splits = np.asarray(partition.plan_partition(
+            code, ndev, detail_zoom=DZ, valid=valid).splits)
+    elif case == "all_invalid":
+        valid[:] = False
     return code, valid, splits
 
 
 RANGE_CASES = ["traces", "one_owner", "one_source", "empty_shards",
                "exactly_full"]
+#: Cases on four devices only: the partitioned backend's detail stage
+#: (interpret mode here), a one-range hotspot, a stream of all-distinct
+#: tiles, a (data, tile) mesh, and a stream with no valid lane.
+MESH4_CASES = ["partitioned", "hotspot", "unique_heavy", "mesh_2d",
+               "all_invalid"]
 
 
-@pytest.mark.parametrize("ndev", [4, 8])
-@pytest.mark.parametrize("case", RANGE_CASES)
+@pytest.mark.parametrize(
+    "case,ndev", [(c, d) for d in (4, 8) for c in RANGE_CASES]
+    + [(c, 4) for c in MESH4_CASES])
 def test_gspmd_range_matches_single_device(case, ndev):
     """The owner-routed range program is byte-identical to the
     single-device cascade on every routing case, counts and integer
-    weights alike; the widths come from the exact counts, bucketed as
-    run_job buckets them ("exactly_full": unbucketed, so the widest
-    owner fills its receive buffer exactly)."""
+    weights alike, so no row is dropped or duplicated; the widths come
+    from the exact counts, bucketed as run_job buckets them
+    ("exactly_full": unbucketed, so the widest owner fills its receive
+    buffer exactly)."""
     from functools import partial
 
     from heatmap_tpu.ops import pyramid
     from heatmap_tpu.parallel import partition
     from heatmap_tpu.pipeline import bucketing
 
-    mesh = make_mesh(devices=jax.devices()[:ndev])
+    if case == "mesh_2d":
+        mesh = make_mesh(data=2, tile=2, devices=jax.devices()[:ndev])
+    else:
+        mesh = make_mesh(devices=jax.devices()[:ndev])
+    backend = "partitioned" if case == "partitioned" else "scatter"
     code, valid, splits = _range_case(case, ndev)
     n = len(code)
     slot = np.random.default_rng(3).integers(0, 20, n)
@@ -271,13 +299,66 @@ def test_gspmd_range_matches_single_device(case, ndev):
             k, mesh, s, code_bits=2 * DZ, slot_bound=20,
             pair_capacity=counts.pair_capacity,
             recv_capacity=counts.recv_capacity, weights=wt, valid=v,
-            levels=6, capacity=n, acc_dtype=acc))(
+            levels=6, capacity=n, acc_dtype=acc, backend=backend,
+            weight_bound=None if wt is None else 8))(
                 ck, jnp.asarray(valid), jnp.asarray(splits), weights)
         want = pyramid.pyramid_sparse_morton(
             ck, weights=weights, valid=jnp.asarray(valid), levels=6,
             capacity=n, acc_dtype=acc)
         _levels_equal(got, want)
         assert int(got[0][2]) == len(np.unique(np.asarray(ck)[valid]))
+
+
+#: Collective kinds in the lowering of the owner-routed count cascade:
+#: the routing and egress all-to-alls, the boundary and per-slot
+#: all-gathers, and the overflow flag's all-reduce. Another kind, or
+#: one fewer, means the mesh program changed.
+ROUTED_COLLECTIVES = ["all-gather", "all-reduce", "all-to-all"]
+
+
+def _collective_kinds(lowered):
+    import re
+
+    return sorted(set(re.findall(
+        r"\b(all-reduce|reduce-scatter|all-to-all|all-gather"
+        r"|collective-permute|collective-broadcast)\b",
+        lowered.as_text(dialect="hlo"))))
+
+
+def test_cascade_lowering_pins_collectives():
+    """_build_cascade_jit on a 2^12-point count job: on one device it
+    lowers with no collective; on four, with a Morton plan, it lowers
+    to the owner-routed program (its all-to-all present) with the
+    collective kinds of ROUTED_COLLECTIVES."""
+    from heatmap_tpu.parallel import partition
+    from heatmap_tpu.parallel.mesh import DATA_AXIS
+    from heatmap_tpu.pipeline import cascade as cascade_mod
+
+    n = 1 << 12
+    ccfg = cascade_mod.CascadeConfig(detail_zoom=DZ, min_detail_zoom=6,
+                                     result_delta=5)
+    rng = np.random.default_rng(0)
+    codes = rng.integers(0, SPACE, n)
+    slots = rng.integers(0, 8, n)
+    valid = rng.random(n) > 0.1
+    jit = cascade_mod._build_cascade_jit
+    one = jit.lower(jnp.asarray(codes), jnp.asarray(slots), config=ccfg,
+                    n_slots=8, valid=jnp.asarray(valid), capacity=n)
+    assert _collective_kinds(one) == []
+    mesh = make_mesh(devices=jax.devices()[:4])
+    plan = partition.plan_partition(codes, 4, detail_zoom=DZ, valid=valid)
+    route = partition.route_counts(plan, codes, valid)
+    rows = named_sharding(mesh, DATA_AXIS)
+
+    def put(a):
+        return jax.device_put(jnp.asarray(a), rows)
+
+    four = jit.lower(
+        put(codes), put(slots), config=ccfg, n_slots=8, valid=put(valid),
+        capacity=n, mesh=mesh,
+        partition_splits=jnp.asarray(plan.splits, jnp.int64),
+        route_capacity=(route.pair_capacity, route.recv_capacity))
+    assert _collective_kinds(four) == ROUTED_COLLECTIVES
 
 
 def test_gspmd_range_one_shard_matches():
@@ -343,8 +424,9 @@ def test_gspmd_range_overflow_is_loud():
 
 
 def _ab(rows, **kw):
-    a = run_job(_ColSource(rows), config=_cfg(dispatch="gspmd", **kw))
-    b = run_job(_ColSource(rows), config=_cfg(dispatch="shard_map", **kw))
+    a = run_job(_ColSource(rows), config=_cfg(**kw))
+    kw.pop("spatial_partition")  # the single-device run has no plan
+    b = run_job(_ColSource(rows), config=_cfg(data_parallel=False, **kw))
     assert a == b and len(a) > 0
     return a
 
@@ -434,33 +516,31 @@ def test_run_job_routed_matches_single_device_and_oracle(ndev, monkeypatch,
 
 
 def test_run_job_gspmd_morton_adaptive_composes():
-    """The lifted rejection: morton + adaptive_capacity under gspmd
-    runs, and its blobs equal BOTH the shard_map uniform-DP oracle and
-    the non-adaptive gspmd run (adaptive is result-neutral)."""
+    """morton + adaptive_capacity runs on the mesh, and its blobs equal
+    BOTH the single-device cascade and the non-adaptive range run
+    (adaptive is result-neutral)."""
     rows = _rows(n=800, seed=9)
     adaptive = run_job(_ColSource(rows), config=_cfg(
-        dispatch="gspmd", spatial_partition="morton",
-        adaptive_capacity=True))
+        spatial_partition="morton", adaptive_capacity=True))
     plain = run_job(_ColSource(rows), config=_cfg(
-        dispatch="gspmd", spatial_partition="morton"))
-    oracle = run_job(_ColSource(rows), config=_cfg(
-        dispatch="shard_map", spatial_partition="off"))
-    assert adaptive == plain == oracle and len(adaptive) > 0
+        spatial_partition="morton"))
+    single = run_job(_ColSource(rows), config=_cfg(data_parallel=False))
+    assert adaptive == plain == single and len(adaptive) > 0
 
 
 @pytest.mark.slow
 def test_retraction_delta_gspmd_byte_identical(tmp_path):
     """sign=-1 negates finalized levels AFTER the cascade; the gspmd
-    route must produce identical artifact files."""
+    route must produce the single-device run's artifact files."""
     from heatmap_tpu.delta.compute import compute_delta
 
     rows = _rows(n=1000, seed=21)
     dirs = {}
-    for name in ("gspmd", "shard_map"):
+    for name, dp in (("gspmd", True), ("single", False)):
         out = str(tmp_path / name)
-        compute_delta(_ColSource(rows), out,
-                      _cfg(dispatch=name, spatial_partition="morton"),
-                      sign=-1)
+        cfg = (_cfg(spatial_partition="morton") if dp
+               else _cfg(data_parallel=False))
+        compute_delta(_ColSource(rows), out, cfg, sign=-1)
         dirs[name] = out
 
     def blob(d):
@@ -468,7 +548,7 @@ def test_retraction_delta_gspmd_byte_identical(tmp_path):
                 for f in sorted(os.listdir(d))
                 if os.path.isfile(os.path.join(d, f))}
 
-    assert blob(dirs["gspmd"]) == blob(dirs["shard_map"])
+    assert blob(dirs["gspmd"]) == blob(dirs["single"])
 
 
 @pytest.mark.slow
@@ -478,9 +558,9 @@ def test_run_job_elastic_gspmd_byte_identical(tmp_path):
     from heatmap_tpu.parallel import run_job_elastic
 
     out = {}
-    for name in ("gspmd", "shard_map"):
+    for name, dp in (("gspmd", True), ("single", False)):
         cfg = BatchJobConfig(detail_zoom=10, min_detail_zoom=8,
-                             result_delta=2, dispatch=name)
+                             result_delta=2, data_parallel=dp)
         d = str(tmp_path / name)
         run_job_elastic(SyntheticSource(n=900, seed=7),
                         LevelArraysSink(d), cfg, batch_size=150,
@@ -489,31 +569,10 @@ def test_run_job_elastic_gspmd_byte_identical(tmp_path):
         out[name] = {f: open(os.path.join(d, f), "rb").read()
                      for f in sorted(os.listdir(d))
                      if os.path.isfile(os.path.join(d, f))}
-    assert out["gspmd"] == out["shard_map"]
+    assert out["gspmd"] == out["single"]
 
 
 # -- config surface --------------------------------------------------------
-
-
-def test_dispatch_config_surface():
-    with pytest.raises(ValueError, match="dispatch"):
-        BatchJobConfig(dispatch="pjit")
-    with pytest.raises(ValueError, match="prefix"):
-        BatchJobConfig(dispatch="gspmd", data_parallel=True,
-                       dp_merge="prefix")
-    # auto resolves to gspmd except where no program exists (prefix).
-    assert BatchJobConfig().resolved_dispatch == "gspmd"
-    assert BatchJobConfig(data_parallel=True, dp_merge="prefix")\
-        .resolved_dispatch == "shard_map"
-    # morton + adaptive composes under gspmd (auto included) and stays
-    # rejected under the shard_map oracle.
-    BatchJobConfig(spatial_partition="morton", data_parallel=True,
-                   adaptive_capacity=True)
-    BatchJobConfig(spatial_partition="morton", data_parallel=True,
-                   adaptive_capacity=True, dispatch="gspmd")
-    with pytest.raises(ValueError, match="adaptive"):
-        BatchJobConfig(spatial_partition="morton", data_parallel=True,
-                       adaptive_capacity=True, dispatch="shard_map")
 
 
 def test_backend_resolved_event_carries_dispatch(tmp_path):
@@ -622,8 +681,7 @@ def test_run_cascade_gspmd_marks_device_inputs():
 
     def run():
         return cascade_mod.run_cascade(
-            codes, slots, ccfg, n_slots=4, capacity=n, mesh=mesh,
-            dispatch="gspmd")
+            codes, slots, ccfg, n_slots=4, capacity=n, mesh=mesh)
 
     cascade_mod.decode_levels(run(), ccfg)
     try:

@@ -275,7 +275,7 @@ def test_routed_range_cascade_compiles_for_v5e_2x2(topo, one_chip,
             capacity=n, backend="partitioned", mesh=mesh,
             partition_splits=jax.ShapeDtypeStruct(
                 (3,), jnp.int64, sharding=named_sharding(mesh)),
-            dispatch="gspmd", route_capacity=route)
+            route_capacity=route)
         assert "all-to-all" in compiled.as_text()
     finally:
         jax.clear_caches()

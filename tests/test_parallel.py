@@ -8,7 +8,6 @@ import pytest
 from heatmap_tpu.ops import (
     bin_points_window,
     pyramid_from_raster,
-    pyramid_sparse_morton,
     aggregate_keys,
     window_from_bounds,
 )
@@ -19,9 +18,8 @@ from heatmap_tpu.parallel import (
     make_mesh,
     pad_to_multiple,
     pyramid_rowsharded,
-    pyramid_sparse_morton_sharded,
 )
-from heatmap_tpu.tilemath import mercator, morton
+from heatmap_tpu.tilemath import mercator
 
 
 @pytest.fixture(scope="module")
@@ -133,219 +131,6 @@ def test_aggregate_keys_sharded_matches_local(mesh):
     assert n == int(ln)
     np.testing.assert_array_equal(np.asarray(gu[:n]), np.asarray(lu[:n]))
     np.testing.assert_allclose(np.asarray(gs[:n]), np.asarray(ls[:n]), rtol=1e-5)
-
-
-@pytest.mark.slow
-def test_pyramid_sparse_sharded_matches_local(mesh):
-    lats, lons = _points(seed=6)
-    zoom, levels = 12, 5
-    (pla, plo), valid = pad_to_multiple([lats, lons], 8)
-    row, col, pvalid = mercator.project_points(pla, plo, zoom)
-    codes = morton.morton_encode(row, col, dtype=jnp.int32, zoom=zoom)
-    v = jnp.asarray(valid) & pvalid
-
-    got = pyramid_sparse_morton_sharded(
-        codes, mesh, valid=v, levels=levels, capacity=16384
-    )
-    want = pyramid_sparse_morton(codes, valid=v, levels=levels, capacity=len(pla))
-    assert len(got) == len(want)
-    for (gu, gs, gn), (wu, ws, wn) in zip(got, want):
-        n = int(wn)
-        assert int(gn) == n
-        np.testing.assert_array_equal(np.asarray(gu[:n]), np.asarray(wu[:n]))
-        np.testing.assert_array_equal(np.asarray(gs[:n]), np.asarray(ws[:n]))
-
-
-@pytest.mark.slow
-def test_pyramid_sparse_sharded_partitioned_matches_local(mesh):
-    """DP x partitioned composition: the MXU segment reduction runs
-    INSIDE each device's shard_map body; counts are exact integers in
-    any summation order, so the bar is bit-equality against the
-    single-device scatter pyramid — not allclose."""
-    lats, lons = _points(seed=6)
-    zoom, levels = 12, 5
-    (pla, plo), valid = pad_to_multiple([lats, lons], 8)
-    row, col, pvalid = mercator.project_points(pla, plo, zoom)
-    codes = morton.morton_encode(row, col, dtype=jnp.int32, zoom=zoom)
-    v = jnp.asarray(valid) & pvalid
-
-    got = pyramid_sparse_morton_sharded(
-        codes, mesh, valid=v, levels=levels, capacity=16384,
-        backend="partitioned",
-    )
-    want = pyramid_sparse_morton(codes, valid=v, levels=levels,
-                                 capacity=len(pla))
-    assert len(got) == len(want)
-    for (gu, gs, gn), (wu, ws, wn) in zip(got, want):
-        n = int(wn)
-        assert int(gn) == n
-        np.testing.assert_array_equal(np.asarray(gu[:n]), np.asarray(wu[:n]))
-        np.testing.assert_array_equal(np.asarray(gs[:n]), np.asarray(ws[:n]))
-
-
-@pytest.mark.slow
-def test_pyramid_sparse_sharded_partitioned_weighted_bit_exact(mesh):
-    """Bounded-integer weights through the sharded partitioned detail
-    stage: integer f64 sums are order-free, so the sharded result is
-    bit-identical to the local scatter pyramid."""
-    rng = np.random.default_rng(23)
-    n = 8 * 1024
-    codes = jnp.asarray(rng.integers(0, 4000, n), jnp.int32)
-    w = jnp.asarray(rng.integers(0, 100, n), jnp.float64)
-    got = pyramid_sparse_morton_sharded(
-        codes, mesh, weights=w, levels=3, capacity=4096,
-        acc_dtype=jnp.float64, backend="partitioned", weight_bound=100,
-    )
-    want = pyramid_sparse_morton(codes, weights=w, levels=3, capacity=n,
-                                 acc_dtype=jnp.float64)
-    assert len(got) == len(want)
-    for (gu, gs, gn), (wu, ws, wn) in zip(got, want):
-        k = int(wn)
-        assert int(gn) == k
-        np.testing.assert_array_equal(np.asarray(gu[:k]), np.asarray(wu[:k]))
-        np.testing.assert_array_equal(np.asarray(gs[:k]), np.asarray(ws[:k]))
-
-
-# -- coarse-prefix regrouped merge (O(uniques/k) per stage) ----------------
-
-
-def _prefix_kernel():
-    from heatmap_tpu.parallel import pyramid_sparse_morton_prefix_sharded
-
-    return pyramid_sparse_morton_prefix_sharded
-
-
-def _assert_levels_equal(got, want, exact_sums=True):
-    assert len(got) == len(want)
-    for (gu, gs, gn), (wu, ws, wn) in zip(got, want):
-        n = int(wn)
-        assert int(gn) == n
-        np.testing.assert_array_equal(np.asarray(gu[:n]), np.asarray(wu[:n]))
-        if exact_sums:
-            np.testing.assert_array_equal(
-                np.asarray(gs[:n]), np.asarray(ws[:n])
-            )
-        else:
-            np.testing.assert_allclose(
-                np.asarray(gs[:n]), np.asarray(ws[:n]), rtol=1e-12
-            )
-
-
-@pytest.mark.slow
-def test_pyramid_prefix_sharded_matches_local(mesh):
-    """Counts: the prefix-regrouped merge is bit-identical to the
-    single-device pyramid (and therefore to the replicated merge, which
-    has the same contract)."""
-    lats, lons = _points(seed=16)
-    zoom, levels = 12, 5
-    (pla, plo), valid = pad_to_multiple([lats, lons], 8)
-    row, col, pvalid = mercator.project_points(pla, plo, zoom)
-    codes = morton.morton_encode(row, col, dtype=jnp.int32, zoom=zoom)
-    v = jnp.asarray(valid) & pvalid
-
-    got = _prefix_kernel()(codes, mesh, valid=v, levels=levels,
-                           capacity=16384)
-    want = pyramid_sparse_morton(codes, valid=v, levels=levels,
-                                 capacity=len(pla))
-    _assert_levels_equal(got, want)
-
-
-@pytest.mark.slow
-def test_pyramid_prefix_sharded_partitioned_matches_local(mesh):
-    """The partitioned detail stage under the coarse-prefix regrouped
-    merge: same bit-equality bar as the replicated merge — the backend
-    choice changes only each device's local reduction, never what
-    crosses the collective."""
-    lats, lons = _points(seed=16)
-    zoom, levels = 12, 5
-    (pla, plo), valid = pad_to_multiple([lats, lons], 8)
-    row, col, pvalid = mercator.project_points(pla, plo, zoom)
-    codes = morton.morton_encode(row, col, dtype=jnp.int32, zoom=zoom)
-    v = jnp.asarray(valid) & pvalid
-
-    got = _prefix_kernel()(codes, mesh, valid=v, levels=levels,
-                           capacity=16384, backend="partitioned")
-    want = pyramid_sparse_morton(codes, valid=v, levels=levels,
-                                 capacity=len(pla))
-    _assert_levels_equal(got, want)
-
-
-@pytest.mark.slow
-def test_pyramid_prefix_sharded_unique_heavy(mesh):
-    """The regime the kernel exists for: uniques ~ points (every key
-    distinct). Results must still match bit-for-bit, with per-level
-    capacities tight enough that the REPLICATED keyspace would not even
-    fit in a per-device range buffer of the old shape."""
-    n = 8 * 2048
-    codes = jnp.asarray(np.random.default_rng(17).permutation(n),
-                        jnp.int32)
-    levels = 4
-    got = _prefix_kernel()(codes, mesh, levels=levels, capacity=n)
-    want = pyramid_sparse_morton(codes, levels=levels, capacity=n)
-    _assert_levels_equal(got, want)
-
-
-@pytest.mark.slow
-def test_pyramid_prefix_sharded_weighted(mesh):
-    """Integer-valued f64 weights are bit-exact; fractional weighted
-    sums agree to f64 summation-order rounding (the documented
-    contract)."""
-    rng = np.random.default_rng(18)
-    n = 8 * 1024
-    codes = jnp.asarray(rng.integers(0, 4000, n), jnp.int32)
-    wi = jnp.asarray(rng.integers(1, 100, n), jnp.float64)
-    got = _prefix_kernel()(codes, mesh, weights=wi, levels=3,
-                           capacity=4096, acc_dtype=jnp.float64)
-    want = pyramid_sparse_morton(codes, weights=wi, levels=3, capacity=n,
-                                 acc_dtype=jnp.float64)
-    _assert_levels_equal(got, want)
-
-    wf = jnp.asarray(rng.uniform(0, 1, n), jnp.float64)
-    got = _prefix_kernel()(codes, mesh, weights=wf, levels=3,
-                           capacity=4096, acc_dtype=jnp.float64)
-    want = pyramid_sparse_morton(codes, weights=wf, levels=3, capacity=n,
-                                 acc_dtype=jnp.float64)
-    _assert_levels_equal(got, want, exact_sums=False)
-
-
-@pytest.mark.slow
-def test_pyramid_prefix_sharded_skew_and_overflow(mesh):
-    """All data under ONE coarse 4^levels block (prefix rounding can't
-    split it): one device owns everything. With full send capacity the
-    result is still exact; with a send capacity too small for the skew
-    the loss is LOUD (n_unique > capacity at every level), never a
-    silently wrong sum."""
-    rng = np.random.default_rng(19)
-    n = 8 * 512
-    levels = 3
-    # Keys within a single 4^3=64-aligned block.
-    codes = jnp.asarray(1024 + rng.integers(0, 64, n), jnp.int32)
-    got = _prefix_kernel()(codes, mesh, levels=levels, capacity=1024)
-    want = pyramid_sparse_morton(codes, levels=levels, capacity=n)
-    _assert_levels_equal(got, want)
-
-    # Unique-heavy AND skew-concentrated: per-(source,dest) traffic is
-    # ~the whole shard, so a tiny send cap must overflow loudly.
-    wide = jnp.asarray(rng.permutation(64 * n)[:n] % (1 << 20), jnp.int32)
-    tight = _prefix_kernel()(wide, mesh, levels=levels, capacity=n,
-                             send_capacity=4)
-    for u, s, cnt in tight:
-        assert int(cnt) > u.shape[0]
-
-
-@pytest.mark.slow
-def test_pyramid_prefix_sharded_2d_mesh():
-    """The flattened (data, tile) axes drive the same kernel."""
-    m = make_mesh(data=4, tile=2)
-    lats, lons = _points(seed=20)
-    (pla, plo), valid = pad_to_multiple([lats, lons], 8)
-    row, col, pvalid = mercator.project_points(pla, plo, 11)
-    codes = morton.morton_encode(row, col, dtype=jnp.int32, zoom=11)
-    v = jnp.asarray(valid) & pvalid
-    got = _prefix_kernel()(codes, m, valid=v, levels=4, capacity=8192)
-    want = pyramid_sparse_morton(codes, valid=v, levels=4,
-                                 capacity=len(pla))
-    _assert_levels_equal(got, want)
 
 
 def test_sharded_kernels_under_jit(mesh):
@@ -616,39 +401,6 @@ def test_collective_placement_pinned_in_hlo(mesh, mesh2d):
         lambda a, b: bin_points_bandsharded(a, b, win, mesh2d)[0],
         lat, lon,
     ) == ["all-reduce", "all-to-all"]
-
-
-def test_prefix_merge_collectives_pinned_in_hlo(mesh):
-    """Structural pin for the coarse-prefix merge: the compiled module
-    must contain the all-to-all regroup (the kernel's entire point — a
-    regression to the replicated formulation would drop it), and every
-    collective operand must stay compact (O(ndev * local_capacity)) —
-    the n-sized key stream never rides a collective."""
-    import re
-
-    n, cap = 8 * 8192, 256
-    codes = jnp.zeros(n, jnp.int64)
-    compiled = jax.jit(
-        lambda k: _prefix_kernel()(k, mesh, levels=3, capacity=cap)[0]
-    ).lower(codes).compile()
-    txt = compiled.as_text()
-    assert " all-to-all" in txt
-    ops = ("all-reduce", "reduce-scatter", "all-to-all", "all-gather",
-           "collective-permute")
-    sizes = []
-    for line in txt.splitlines():
-        if not any(f" {op}(" in line or f" {op}-" in line
-                   for op in ops):
-            continue
-        for dims in re.findall(r"\[([\d,]+)\]", line):
-            sizes.append(
-                int(np.prod([int(d) for d in dims.split(",") if d]))
-            )
-    assert sizes, "expected collectives in the prefix merge"
-    # local_capacity = min(cap, n//8) = 256; the biggest legitimate
-    # collective is the (ndev, send_cap) = 8*256 = 2048-lane exchange.
-    # Any n-derived operand is >= n/ndev = 8192.
-    assert max(sizes) < n // 8, (max(sizes), sorted(set(sizes)))
 
 
 def test_sharded_aggregation_collectives_stay_compact(mesh):

@@ -1,11 +1,11 @@
 """Locality-aware Morton-range sharding (parallel/partition.py).
 
-Three layers under test: the planner (deterministic quantile splits,
-skew-resistant re-splitting, boundary-tile enumeration), the host-side
-router (multiset-preserving scatter into per-shard segments), and the
+Two layers under test: the planner (deterministic quantile splits,
+skew-resistant re-splitting, boundary-tile enumeration) and the
 end-to-end gate — a spatially partitioned job's blobs are byte-identical
-to the uniform round-robin dispatch on every tested shape, including
-weighted, retraction, and elastic-failover jobs.
+to the uniform program's on every tested shape, including weighted,
+retraction, and elastic-failover jobs. The range program's own
+routing cases are in tests/test_gspmd.py.
 """
 
 import os
@@ -18,7 +18,6 @@ from heatmap_tpu.parallel import (
     PartitionPlan,
     plan_partition,
     plan_shards,
-    route_emissions,
     run_job_elastic,
 )
 from heatmap_tpu.pipeline import BatchJobConfig, run_job
@@ -155,47 +154,6 @@ def test_boundary_codes_match_brute_force():
         assert all(len(plan.boundary_codes(v)) <= 7 for v in range(1, 7))
 
 
-def test_route_emissions_round_trip():
-    rng = np.random.default_rng(23)
-    n = 4_096
-    codes = rng.integers(0, SPACE, n)
-    slots = rng.integers(0, 5, n).astype(np.int32)
-    valid = rng.random(n) > 0.1
-    w = rng.integers(1, 9, n).astype(np.float64)
-    plan = plan_partition(codes, 8, detail_zoom=DZ)
-    rc, rs, rv, rw, seg = route_emissions(plan, codes, slots, valid=valid,
-                                          weights=w)
-    assert rc.shape == (8 * seg,)
-    # Multiset preservation: valid lanes survive exactly once.
-    want = sorted(zip(codes[valid], slots[valid], w[valid]))
-    got = sorted(zip(rc[rv], rs[rv], rw[rv]))
-    assert got == want
-    # Segment ownership: every valid lane sits in its shard's segment.
-    sid = plan.shard_of_codes(rc[rv])
-    assert np.array_equal(sid, np.flatnonzero(rv) // seg)
-    # Bucketed padding: seg honors the bucket map.
-    *_, seg2 = route_emissions(plan, codes, slots, valid=valid,
-                               bucket=lambda x: 1 << int(np.ceil(
-                                   np.log2(max(x, 1)))))
-    assert seg2 >= seg and seg2 & (seg2 - 1) == 0
-
-
-def test_route_emissions_empty_ranges():
-    """Duplicate splits denote empty ranges: their segments stay fully
-    padded and the round trip still preserves the multiset."""
-    splits = (100, 100, 100)
-    plan = PartitionPlan(detail_zoom=DZ, n_shards=4, splits=splits,
-                         sampled_points=1, balance_factor=1.25,
-                         shard_mass=(0.5, 0.0, 0.0, 0.5), resplits=0,
-                         fingerprint="t")
-    codes = np.asarray([5, 50, 99, 100, 101, SPACE - 1], np.int64)
-    slots = np.zeros(6, np.int32)
-    rc, rs, rv, _, seg = route_emissions(plan, codes, slots)
-    assert sorted(rc[rv].tolist()) == sorted(codes.tolist())
-    # Shards 1 and 2 (between duplicate splits) hold nothing.
-    assert not rv[1 * seg:3 * seg].any()
-
-
 # -- observability ---------------------------------------------------------
 
 
@@ -256,14 +214,9 @@ def test_spatial_partition_config_rejections():
         BatchJobConfig(spatial_partition="hilbert")
     with pytest.raises(ValueError, match="data_parallel"):
         BatchJobConfig(spatial_partition="morton", data_parallel=False)
-    # morton + adaptive_capacity now composes (the gspmd dispatch
-    # routes on-device against traced splits); only the shard_map
-    # oracle — whose routing is host-side and shape-coupled — still
-    # rejects it at config time.
-    with pytest.raises(ValueError, match="adaptive"):
-        BatchJobConfig(spatial_partition="morton", data_parallel=True,
-                       adaptive_capacity=True, dispatch="shard_map")
-    # The composing modes construct fine.
+    # The composing modes construct fine, morton + adaptive_capacity
+    # included (the range program routes on-device against traced
+    # splits).
     BatchJobConfig(spatial_partition="off")
     BatchJobConfig(spatial_partition="morton", data_parallel=True,
                    pad_bucketing="pow2")

@@ -982,22 +982,17 @@ def test_dp_mesh_auto_routing():
     assert mesh is not None
     assert _dp_mesh(_dp_cfg()) is not None  # True
     assert _dp_mesh(_dp_cfg(data_parallel=False)) is None
-    # The partitioned cascade composes with DP (the per-device detail
-    # reduction swaps kernels inside the shard_map body), so it no
-    # longer forces the single-device route.
+    # The partitioned cascade composes with DP (each shard's detail
+    # reduction swaps kernels), so it does not force the
+    # single-device route.
     assert _dp_mesh(
         _dp_cfg(data_parallel=None, cascade_backend="partitioned")
     ) is not None
-    # adaptive_capacity composes with the mesh under the gspmd
-    # dispatch (the default auto resolution); only the shard_map
-    # oracle still routes it single-device.
+    # adaptive_capacity composes with the mesh, auto and explicit.
     assert _dp_mesh(
         _dp_cfg(data_parallel=None, adaptive_capacity=True)
     ) is not None
-    assert _dp_mesh(
-        _dp_cfg(data_parallel=None, adaptive_capacity=True,
-                dispatch="shard_map")
-    ) is None
+    assert _dp_mesh(_dp_cfg(adaptive_capacity=True)) is not None
     # The size gate: auto stays single-device below the threshold
     # (tiny shards lose to the dispatch), engages at it; explicit True
     # engages at any size.
@@ -1007,17 +1002,16 @@ def test_dp_mesh_auto_routing():
 
 
 def test_dp_config_rejections():
-    """data_parallel=True with a non-composing knob fails at config
-    time, not mid-job; the partitioned cascade now composes and is
-    accepted."""
+    """data_parallel=True composes with the partitioned cascade and
+    with adaptive capacities; the mesh has one formulation, gspmd, and
+    no option selects another."""
     cfg = _dp_cfg(data_parallel=True, cascade_backend="partitioned")
     assert cfg.resolved_cascade_backend == "partitioned"
-    # adaptive + DP is accepted under the gspmd dispatch (default auto
-    # resolution); the shard_map oracle still rejects at config time.
-    _dp_cfg(data_parallel=True, adaptive_capacity=True)
-    with pytest.raises(ValueError, match="adaptive"):
-        _dp_cfg(data_parallel=True, adaptive_capacity=True,
-                dispatch="shard_map")
+    assert _dp_cfg(data_parallel=True,
+                   adaptive_capacity=True).resolved_dispatch == "gspmd"
+    for knob in ("dispatch", "dp_merge"):
+        with pytest.raises(TypeError, match=knob):
+            _dp_cfg(**{knob: "gspmd"})
 
 
 def test_cascade_backend_auto_resolution(monkeypatch):
@@ -1082,33 +1076,9 @@ def test_run_job_data_parallel_byte_identical(amplify):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("amplify", [False, True])
-def test_run_job_dp_prefix_merge_byte_identical(amplify):
-    """The coarse-prefix regrouped merge emits
-    blobs byte-identical to BOTH the replicated-merge DP job and the
-    single-device cascade, in both compat modes — same bar as the
-    replicated route."""
-    from heatmap_tpu.pipeline import run_job
-
-    rows = _rows(n=2500, seed=42)
-    prefix = run_job(
-        _ColSource(rows),
-        config=_dp_cfg(amplify_all=amplify, dp_merge="prefix"),
-    )
-    replicated = run_job(
-        _ColSource(rows), config=_dp_cfg(amplify_all=amplify)
-    )
-    single = run_job(
-        _ColSource(rows),
-        config=_dp_cfg(amplify_all=amplify, data_parallel=False),
-    )
-    assert prefix == replicated == single and len(prefix) > 0
-
-
-@pytest.mark.slow
 def test_run_job_dp_partitioned_cascade_byte_identical():
     """DP x partitioned composition at the blob level: the MXU segment
-    reduction inside each device's shard_map body must emit blobs
+    reduction of each shard's detail stage must emit blobs
     byte-identical to BOTH the DP scatter cascade and the single-device
     partitioned cascade. Counts are exact integers in any summation
     order, so the bar is equality — the same bar the scatter DP route
@@ -1124,63 +1094,6 @@ def test_run_job_dp_partitioned_cascade_byte_identical():
                      config=_dp_cfg(cascade_backend="partitioned",
                                     data_parallel=False))
     assert dp_part == dp_scat == single and len(dp_part) > 0
-
-
-@pytest.mark.slow
-def test_run_job_dp_prefix_merge_partitioned_byte_identical():
-    """The partitioned cascade under the coarse-prefix regrouped merge:
-    the backend choice changes only each device's local reduction, so
-    blobs stay byte-identical to the single-device job."""
-    from heatmap_tpu.pipeline import run_job
-
-    rows = _rows(n=2000, seed=9)
-    prefix = run_job(_ColSource(rows),
-                     config=_dp_cfg(cascade_backend="partitioned",
-                                    dp_merge="prefix"))
-    single = run_job(_ColSource(rows),
-                     config=_dp_cfg(cascade_backend="partitioned",
-                                    data_parallel=False))
-    assert prefix == single and len(prefix) > 0
-
-
-@pytest.mark.slow
-def test_run_job_dp_prefix_merge_weighted_integer_bit_identical():
-    """Integer weighted sums through the prefix merge stay bit-exact
-    (integer f64 addition is order-free; the regroup only changes the
-    order)."""
-    from heatmap_tpu.pipeline import run_job
-
-    rng = np.random.default_rng(15)
-    rows = _rows(n=1500, seed=15)
-    for r in rows:
-        r["value"] = float(rng.integers(1, 12))
-    prefix = run_job(_ColSource(rows),
-                     config=_dp_cfg(weighted=True, dp_merge="prefix"))
-    single = run_job(_ColSource(rows),
-                     config=_dp_cfg(weighted=True, data_parallel=False))
-    assert prefix == single and len(prefix) > 0
-
-
-@pytest.mark.slow
-def test_run_job_dp_prefix_merge_bounded_byte_identical():
-    """The prefix merge composes with the bounded chunked path exactly
-    like the replicated merge does."""
-    from heatmap_tpu.pipeline import run_job
-
-    rows = _rows(n=2000, seed=9)
-    prefix = run_job(_ColSource(rows),
-                     config=_dp_cfg(dp_merge="prefix"),
-                     batch_size=128, max_points_in_flight=300)
-    single = run_job(_ColSource(rows),
-                     config=_dp_cfg(data_parallel=False),
-                     batch_size=128, max_points_in_flight=300)
-    assert prefix == single and len(prefix) > 0
-
-
-def test_dp_merge_config_rejection():
-    """A dp_merge typo fails at config time, before ingest."""
-    with pytest.raises(ValueError, match="dp_merge"):
-        BatchJobConfig(dp_merge="sharded")
 
 
 def test_run_job_data_parallel_matches_oracle():
@@ -1272,10 +1185,11 @@ def test_dp_cascade_overflow_detected():
 
 
 def test_build_cascade_mesh_rejects_noncomposing():
-    """mesh + adaptive still raises at the cascade layer (covers
-    callers that bypass BatchJobConfig); mesh + partitioned now
-    composes — the segment reduction runs inside the shard_map body —
-    and must match the sharded scatter cascade exactly."""
+    """mesh + adaptive on the partitioned backend still raises at the
+    cascade layer (covers callers that bypass BatchJobConfig); mesh +
+    partitioned composes — each shard's detail reduction runs the
+    segment kernel — and so does mesh + adaptive on scatter: both must
+    match the mesh scatter cascade exactly."""
     from heatmap_tpu.parallel.mesh import make_mesh
     from heatmap_tpu.pipeline import cascade as cascade_mod
     import jax
@@ -1287,19 +1201,23 @@ def test_build_cascade_mesh_rejects_noncomposing():
     mesh = make_mesh(devices=jax.devices())
     with pytest.raises(ValueError, match="adaptive"):
         cascade_mod.build_cascade(codes, slots, cfg, n_slots=1,
-                                  adaptive=True, mesh=mesh)
+                                  adaptive=True, backend="partitioned",
+                                  mesh=mesh)
     part = cascade_mod.build_cascade(codes, slots, cfg, n_slots=1,
                                      backend="partitioned", mesh=mesh)
+    adaptive = cascade_mod.build_cascade(codes, slots, cfg, n_slots=1,
+                                         adaptive=True, mesh=mesh)
     scat = cascade_mod.build_cascade(codes, slots, cfg, n_slots=1,
                                      backend="scatter", mesh=mesh)
-    assert len(part) == len(scat)
-    for (pu, ps, pn), (su, ss, sn) in zip(part, scat):
-        n = int(sn)
-        assert int(pn) == n
-        np.testing.assert_array_equal(np.asarray(pu)[:n],
-                                      np.asarray(su)[:n])
-        np.testing.assert_array_equal(np.asarray(ps)[:n],
-                                      np.asarray(ss)[:n])
+    for got in (part, adaptive):
+        assert len(got) == len(scat)
+        for (pu, ps, pn), (su, ss, sn) in zip(got, scat):
+            n = int(sn)
+            assert int(pn) == n
+            np.testing.assert_array_equal(np.asarray(pu)[:n],
+                                          np.asarray(su)[:n])
+            np.testing.assert_array_equal(np.asarray(ps)[:n],
+                                          np.asarray(ss)[:n])
 
 
 # -- auto-spill safety rails -----------------------------------------------

@@ -25,11 +25,6 @@ Reads every bench artifact the repo's tooling writes —
   padding mode, sustained points/sec (higher), ingest->servable p99
   lag ms (lower), and the feeder's transfer-overlap share
   (``ingest:feed_overlap_pct[...]``, higher, noise-floored at 50%);
-- ``BENCH_dispatch.json`` (tools/bench_job.py --dispatch-sweep):
-  gspmd vs shard_map host-dispatch overhead share per dataset
-  (``dispatch:overhead_pct[ds,mode]``, lower) and the gspmd leg's
-  end-to-end wall seconds (lower; rows that failed the byte gate are
-  never folded);
 - ``BENCH_writeplane.json`` (tools/bench_writeplane.py): per writer
   count, multi-writer drain points/sec (``writeplane:pts_per_s[N]``,
   higher) and enqueue->servable p50 lag seconds
@@ -225,26 +220,6 @@ def snapshot_metrics(root: str) -> dict:
                                            (int, float)):
                 out["partition:skew_ratio[zipf]"] = (
                     float(row["skew_ratio"]), False)
-    doc = _load(os.path.join(root, "BENCH_dispatch.json"))
-    if isinstance(doc, dict):
-        # Device-resident dispatch A/B (bench_job --dispatch-sweep):
-        # the host share of a cascade dispatch must not creep back up
-        # for either program (the gspmd leg is the product, the
-        # shard_map leg anchors what the oracle costs), nor may the
-        # gspmd wall time regress; rows that failed the byte gate are
-        # never folded.
-        for row in doc.get("results", []):
-            ds = row.get("dataset")
-            if ds is None or not row.get("byte_identical"):
-                continue
-            for mode in ("gspmd", "shard_map"):
-                pct = (row.get("overhead_pct") or {}).get(mode)
-                if isinstance(pct, (int, float)):
-                    out[f"dispatch:overhead_pct[{ds},{mode}]"] = (
-                        float(pct), False)
-            wall = (row.get("wall_s") or {}).get("gspmd")
-            if isinstance(wall, (int, float)):
-                out[f"dispatch:wall_s[{ds}]"] = (float(wall), False)
     doc = _load(os.path.join(root, "BENCH_writeplane.json"))
     if isinstance(doc, dict):
         # Partitioned write plane (bench_writeplane): per writer count,

@@ -117,15 +117,15 @@ def partition_sweep(args) -> int:
     Two point sets — uniform and a Zipf-clustered mixture whose
     clusters are wide enough to hold distinct detail codes (a single
     heavy code is irreducible mass no planner can split) — each run
-    through the sharded cascade with ``partition_splits`` off and on.
-    The record carries measured wall seconds, the plan's skew ratio,
-    and the MODELED per-pyramid merge volume: uniform DP gathers every
-    shard's full per-level partial buffers, the Morton path gathers
-    only the boundary-tile buffers (``bcap = min(lcap, 2*n_slots)``
-    keys per shard per coarse level, level 0 exchanging nothing) — the
-    same arithmetic parallel/sharded.py sizes its buffers with. Bytes
-    are 16 per key slot (int64 key + 8-byte accumulator). The byte
-    gate rides along: both dispatches must produce identical level
+    through the mesh cascade with ``partition_splits`` off and on (the
+    uniform and range programs of parallel/gspmd.py). The record
+    carries measured wall seconds, the plan's skew ratio, and the
+    MODELED per-pyramid merge volume: uniform DP merges every shard's
+    full per-level partial buffers, the Morton path gathers only the
+    boundary candidates (``min(lcap, 2*n_slots)`` keys per shard per
+    coarse level, level 0 exchanging nothing — gspmd.patch_boundary).
+    Bytes are 16 per key slot (int64 key + 8-byte accumulator). The
+    byte gate rides along: both programs must produce identical level
     arrays or the row is marked failed.
     """
     if args.cpu:
@@ -140,8 +140,8 @@ def partition_sweep(args) -> int:
     jax.config.update("jax_enable_x64", True)
     import jax.numpy as jnp
 
-    from heatmap_tpu.parallel import make_mesh, route_emissions
-    from heatmap_tpu.parallel.partition import plan_partition
+    from heatmap_tpu.parallel import make_mesh
+    from heatmap_tpu.parallel.partition import plan_partition, route_counts
     from heatmap_tpu.pipeline.batch import project_detail_codes
     from heatmap_tpu.pipeline.cascade import CascadeConfig, run_cascade
 
@@ -201,37 +201,32 @@ def partition_sweep(args) -> int:
         plan = plan_partition(codes, ndev, detail_zoom=dz, valid=valid,
                               n_levels=levels)
         slots = np.zeros(n, np.int32)
-        rc, rs, rv, _, seg = route_emissions(plan, codes, slots,
-                                             valid=valid)
-        d_codes = jnp.asarray(codes)
-        d_valid = jnp.asarray(valid)
-        d_rc, d_rs, d_rv = (jnp.asarray(rc), jnp.asarray(rs),
-                            jnp.asarray(rv))
+        route = route_counts(plan, codes, valid)
         splits = jnp.asarray(plan.splits, jnp.int64)
-        d_slots = jnp.zeros(n, jnp.int32)
 
+        # Host inputs: the mesh cascade donates device-resident inputs,
+        # and each timed call needs its own.
         def run_off():
-            return run_cascade(d_codes, d_slots, cfg, 1, valid=d_valid,
+            return run_cascade(codes, slots, cfg, 1, valid=valid,
                                capacity=n, mesh=mesh)
 
         def run_morton():
-            return run_cascade(d_rc, d_rs, cfg, 1, valid=d_rv,
+            return run_cascade(codes, slots, cfg, 1, valid=valid,
                                capacity=n, mesh=mesh,
-                               partition_splits=splits)
+                               partition_splits=splits,
+                               route_capacity=(route.pair_capacity,
+                                               route.recv_capacity))
 
         identical = levels_equal(run_off(), run_morton())
         wall_off = timed(run_off, args.sweep_reps)
         wall_morton = timed(run_morton, args.sweep_reps)
 
-        # Buffer sizing, mirrored from pyramid_sparse_morton_range_
-        # sharded: every shard's per-level partial buffer vs only the
-        # boundary-tile buffers (n_slots=1 here).
-        routed_n = len(rc)
-        local_capacity = max(1, min(n, routed_n // ndev))
-        lcaps = [max(1, min(n, local_capacity)) for _ in range(levels + 1)]
-        bcaps = [max(1, min(lc, 2 * 1)) for lc in lcaps]
-        uniform_bytes = sum(ndev * lc * 16 for lc in lcaps)
-        morton_bytes = sum(ndev * bc * 16 for bc in bcaps[1:])
+        # Buffer sizing: every shard's per-level partial buffer vs only
+        # each range shard's boundary candidates (n_slots=1 here).
+        lcap = max(1, n // ndev)
+        bcap = max(1, min(route.recv_capacity, 2 * 1))
+        uniform_bytes = (levels + 1) * ndev * lcap * 16
+        morton_bytes = levels * ndev * bcap * 16
         rows.append({
             "dataset": name,
             "n_points": n,
@@ -264,146 +259,6 @@ def partition_sweep(args) -> int:
     return 0 if all(r["byte_identical"] for r in rows) else 1
 
 
-def dispatch_sweep(args) -> int:
-    """gspmd vs shard_map dispatch A/B (device-resident cascade).
-
-    Runs the same point sets END TO END (run_job -> level-array sink)
-    under both dispatch programs — the one-program gspmd pjit path and
-    the shard_map oracle — for uniform DP (uniform points) and
-    Morton-range sharding (Zipf-clustered points). Each leg's
-    host-vs-device split comes from the dispatch timer
-    (``obs.DISPATCH_OVERHEAD`` + the ``cascade.dispatch.*`` stages):
-    ``overhead_pct`` is the host share of one dispatch — the routing,
-    padding, and argument-prep work the gspmd program moves on device.
-    The byte gate rides along: both dispatches must produce identical
-    level-array files or the row is marked failed (bench_gate never
-    folds a failed row, and reads the artifact as ``dispatch:*``
-    series).
-    """
-    if args.cpu:
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + " --xla_force_host_platform_device_count=8")
-    import jax
-
-    if args.cpu:
-        jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_enable_x64", True)
-
-    from heatmap_tpu import obs
-    from heatmap_tpu.delta import ColumnsSource
-    from heatmap_tpu.io.sinks import LevelArraysSink
-    from heatmap_tpu.pipeline import BatchJobConfig, run_job
-
-    n = args.sweep_n
-    rng = np.random.default_rng(17)
-
-    def zipf_points(m):
-        n_c = 32
-        ranks = np.arange(1, n_c + 1, dtype=np.float64)
-        p = (1.0 / ranks) / np.sum(1.0 / ranks)
-        centers_lat = rng.uniform(-55.0, 55.0, n_c)
-        centers_lon = rng.uniform(-170.0, 170.0, n_c)
-        k = int(m * 0.8)
-        c = rng.choice(n_c, size=k, p=p)
-        lat = np.concatenate([centers_lat[c] + rng.normal(0, 0.3, k),
-                              rng.uniform(-55.0, 55.0, m - k)])
-        lon = np.concatenate([centers_lon[c] + rng.normal(0, 0.3, k),
-                              rng.uniform(-170.0, 170.0, m - k)])
-        return lat, lon
-
-    # Each row pairs a point shape with the partitioner it exercises:
-    # uniform points -> uniform DP, Zipf clusters -> Morton ranges.
-    cells = {
-        "uniform": ((rng.uniform(-55.0, 55.0, n),
-                     rng.uniform(-170.0, 170.0, n)), "off"),
-        "morton": (zipf_points(n), "morton"),
-    }
-
-    def levels_files(path):
-        out = {}
-        for name in sorted(os.listdir(path)):
-            full = os.path.join(path, name)
-            if os.path.isfile(full):
-                with open(full, "rb") as f:
-                    out[name] = f.read()
-        return out
-
-    obs.enable_metrics(True)
-    reg = obs.get_registry()
-    ndev = len(jax.devices())
-    tmpdir = tempfile.mkdtemp(prefix="benchdispatch-")
-    rows = []
-    try:
-        for name, ((lat, lon), partition) in cells.items():
-            cols = {"latitude": lat, "longitude": lon,
-                    "user_id": ["all"] * n}
-            wall, host, dev, pct, n_disp, gate = {}, {}, {}, {}, {}, {}
-            for mode in ("shard_map", "gspmd"):
-                cfg = BatchJobConfig(detail_zoom=16, min_detail_zoom=10,
-                                     result_delta=2, data_parallel=True,
-                                     dispatch=mode,
-                                     spatial_partition=partition)
-                out_dir = os.path.join(tmpdir, f"{name}-{mode}")
-
-                def one_run(d, cfg=cfg, cols=cols):
-                    run_job(ColumnsSource(cols), LevelArraysSink(d),
-                            config=cfg, batch_size=max(1, n // 4))
-
-                one_run(out_dir)  # warmup compiles + the byte-gate run
-                gate[mode] = levels_files(out_dir)
-                reg.reset()  # timed reps only in the folded samples
-                t0 = time.perf_counter()
-                for _ in range(args.sweep_reps):
-                    one_run(os.path.join(tmpdir, f"{name}-{mode}-rep"))
-                wall[mode] = ((time.perf_counter() - t0)
-                              / args.sweep_reps)
-                counts, total, count_n = obs.DISPATCH_OVERHEAD.samples()[
-                    (mode,)]
-                host[mode], n_disp[mode] = total, int(count_n)
-                dev[mode] = obs.STAGE_SECONDS.samples()[
-                    ("cascade.dispatch.device",)][1]
-                pct[mode] = round(
-                    100.0 * host[mode] / max(host[mode] + dev[mode],
-                                             1e-12), 2)
-            identical = (sorted(gate["gspmd"]) == sorted(gate["shard_map"])
-                         and all(gate["gspmd"][k] == gate["shard_map"][k]
-                                 for k in gate["gspmd"]))
-            rows.append({
-                "dataset": name,
-                "n_points": n,
-                "spatial_partition": partition,
-                "dispatches_timed": n_disp,
-                "wall_s": {m: round(w, 4) for m, w in wall.items()},
-                "host_s": {m: round(h, 4) for m, h in host.items()},
-                "device_s": {m: round(d, 4) for m, d in dev.items()},
-                "overhead_pct": pct,
-                "overhead_reduction_pct": round(
-                    pct["shard_map"] - pct["gspmd"], 2),
-                "byte_identical": bool(identical),
-            })
-            print(json.dumps(rows[-1]), flush=True)
-    finally:
-        import shutil
-
-        shutil.rmtree(tmpdir, ignore_errors=True)
-
-    doc = {
-        "bench": "dispatch",
-        "device": jax.devices()[0].platform,
-        "ndev": ndev,
-        "detail_zoom": 16,
-        "reps": args.sweep_reps,
-        "results": rows,
-    }
-    with open(args.dispatch_sweep, "w") as f:
-        json.dump(doc, f, indent=2)
-        f.write("\n")
-    print(json.dumps({"wrote": args.dispatch_sweep}), flush=True)
-    return 0 if all(r["byte_identical"] for r in rows) else 1
-
-
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=1 << 24)
@@ -426,25 +281,14 @@ def main() -> int:
                     "plan skew, modeled merge bytes, byte gate "
                     "(bench_gate reads the artifact as partition:* "
                     "series)")
-    ap.add_argument("--dispatch-sweep", nargs="?",
-                    const="BENCH_dispatch.json", default=None,
-                    metavar="OUT.json",
-                    help="gspmd vs shard_map dispatch A/B, end to end: "
-                    "wall time, host/device split per dispatch "
-                    "(overhead_pct), byte gate (bench_gate reads the "
-                    "artifact as dispatch:* series)")
     ap.add_argument("--sweep-n", type=int, default=1 << 20,
-                    help="points per partition/dispatch-sweep dataset")
+                    help="points per partition-sweep dataset")
     ap.add_argument("--sweep-reps", type=int, default=3,
-                    help="timed repetitions per partition/dispatch-"
-                    "sweep leg")
+                    help="timed repetitions per partition-sweep leg")
     args = ap.parse_args()
 
     if args.partition_sweep:
         return partition_sweep(args)
-
-    if args.dispatch_sweep:
-        return dispatch_sweep(args)
 
     import jax
 

@@ -162,15 +162,6 @@ got_dp = run_job_multihost(src, config=dp_cfg, batch_size=batch,
                            egress="gather")
 checks["dp_gather_equals_oracle"] = blobs_equal(got_dp, want)
 
-# 1f) per-host DP with the coarse-prefix regrouped merge (the
-# O(uniques/k) route): local all_to_all range regroup inside each
-# process, cross-process gather unchanged — same oracle bar.
-pfx_cfg = BatchJobConfig(detail_zoom=11, min_detail_zoom=8,
-                         data_parallel=True, dp_merge="prefix")
-got_pfx = run_job_multihost(src, config=pfx_cfg, batch_size=batch,
-                            egress="gather")
-checks["dp_prefix_gather_equals_oracle"] = blobs_equal(got_pfx, want)
-
 # 2) sharded blob egress over the real all_to_all; per-host JSONL.
 # open_sink(per_process_sink_spec(...)) is exactly the CLI's path —
 # the tool must exercise the production spec parser, not re-parse.
